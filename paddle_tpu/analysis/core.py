@@ -133,7 +133,7 @@ class ParsedModule:
         """The free-form reason tail of the noqa on `line`: None when
         the line carries no noqa at all, "" when it carries a bare or
         reasonless one. Rules that *mandate* reasoned suppressions
-        (COLLECTIVE-MESH's check_rep=False contract) distinguish the
+        (COLLECTIVE-MESH's check_vma=False contract) distinguish the
         two: a reasonless noqa is itself the finding."""
         self.noqa  # force the tokenize pass
         if line not in (self._noqa or {}):

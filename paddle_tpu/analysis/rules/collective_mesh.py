@@ -1,5 +1,5 @@
 """COLLECTIVE-MESH — collectives must name a real mesh axis, every
-``check_rep=False`` must say why, and ``ppermute`` rings must be sized
+``check_vma=False`` must say why, and ``ppermute`` rings must be sized
 from the mesh.
 
 Three contracts from the tensor-parallel work (PR 9 + ISSUE 18), all
@@ -9,7 +9,7 @@ about ``shard_map``:
      shard_map-wrapped function runs on the axis the *wrap site's* mesh
      declares. A typo'd or stale axis name is the PR 5 swallowed-axis
      class all over again — it surfaces as a wrong *value*, not an
-     error, once ``check_rep`` is off. The EQuARX/T3 roadmap items will
+     error, once ``check_vma`` is off. The EQuARX/T3 roadmap items will
      multiply these sites, so the rule checks every collective whose
      axis operand *resolves to a string constant* (module-level
      constants like ``TP_AXIS = "tp"`` resolve, through from-imports
@@ -18,9 +18,9 @@ about ``shard_map``:
      constructors. Axis names that come in as function parameters
      (spmd_pipeline, moe) resolve to nothing and are skipped —
      conservative silence, not a guess.
-  2. **check_rep=False.** Disabling replication checking is sometimes
+  2. **check_vma=False.** Disabling varying-axes checking is sometimes
      required (PR 9's wrappers return per-shard outputs) but never
-     free: every ``check_rep=False`` must carry
+     free: every ``check_vma=False`` must carry
      ``# noqa: COLLECTIVE-MESH — <reason>`` *with a reason* on its
      line. A reasonless noqa is itself the finding — the rule inspects
      the noqa's reason tail directly and bypasses the normal
@@ -38,7 +38,7 @@ about ``shard_map``:
      check.
 
 Scoped to modules that call shard_map at all; modules with no
-resolvable mesh axes get only the check_rep audit and the ppermute
+resolvable mesh axes get only the check_vma audit and the ppermute
 ring check (the literal-table hazard needs no mesh resolution).
 """
 import ast
@@ -98,7 +98,7 @@ def _is_literal_perm(node: ast.expr) -> bool:
 class CollectiveMeshRule(Rule):
     name = "COLLECTIVE-MESH"
     description = ("shard_map collectives whose axis name is not "
-                   "declared by the module's mesh, and check_rep=False "
+                   "declared by the module's mesh, and check_vma=False "
                    "without a reasoned noqa")
 
     def check(self, module: ParsedModule) -> Iterator[Finding]:
@@ -207,7 +207,7 @@ class CollectiveMeshRule(Rule):
                             f"meshes declare "
                             f"{sorted(mesh_axes)} — a stale axis name "
                             f"is the PR 5 swallowed-axis class: wrong "
-                            f"values, no error, once check_rep is off")))
+                            f"values, no error, once check_vma is off")))
             if chain[-1] == "ppermute":
                 perm = _perm_operand(node)
                 if perm is not None and _is_literal_perm(perm):
@@ -220,13 +220,13 @@ class CollectiveMeshRule(Rule):
                         f"size: `parallel.mesh.ring_perm(axis_size)`")))
         yield from self.findings(module, hits)
 
-        # check_rep=False audit: bypasses inline suppression — a
+        # check_vma=False audit: bypasses inline suppression — a
         # reasonless `# noqa: COLLECTIVE-MESH` is exactly the bug
         occ: dict = {}
         for site in sorted(shard_sites, key=lambda n: (n.lineno,
                                                        n.col_offset)):
             for kw in site.keywords:
-                if kw.arg != "check_rep":
+                if kw.arg != "check_vma":
                     continue
                 if not (isinstance(kw.value, ast.Constant)
                         and kw.value.value is False):
@@ -238,7 +238,7 @@ class CollectiveMeshRule(Rule):
                 what = ("carries a reasonless `# noqa`" if reason == ""
                         else "has no `# noqa`")
                 message = (
-                    f"shard_map(check_rep=False) {what} — disabling "
+                    f"shard_map(check_vma=False) {what} — disabling "
                     f"replication checking hides axis mistakes (the "
                     f"PR 9 contract); justify it in place: "
                     f"`# noqa: COLLECTIVE-MESH — <why per-shard "

@@ -74,10 +74,7 @@ def _axis_in_scope(axis_name: str) -> bool:
                 f"version's private-API layout",
                 RuntimeWarning, stacklevel=2)
     try:
-        axis_size = getattr(jax.lax, "axis_size", None)     # jax >= 0.5
-        if axis_size is None:                               # jax 0.4.x:
-            axis_size = jax.core.axis_frame                 # returns the size
-        axis_size(axis_name)
+        jax.lax.axis_size(axis_name)
         return True
     except (NameError, KeyError, TypeError, ValueError):
         return False
@@ -109,10 +106,7 @@ def _axis_nranks(g: Group) -> int:
     the default group's nranks reflects the process world, which can differ
     from the mesh axis a shard_map region binds."""
     try:
-        axis_size = getattr(jax.lax, "axis_size", None)     # jax >= 0.5
-        if axis_size is None:                               # jax 0.4.x:
-            axis_size = jax.core.axis_frame                 # returns the size
-        return int(axis_size(g.axis_name))
+        return int(jax.lax.axis_size(g.axis_name))
     except (NameError, KeyError, TypeError, ValueError):
         return g.nranks
 

@@ -14,17 +14,6 @@ import jax
 _STATE = {"initialized": False, "rank": None, "world_size": None}
 
 
-def _jax_dist_initialized() -> bool:
-    fn = getattr(jax.distributed, "is_initialized", None)  # jax >= 0.5
-    if fn is not None:
-        return fn()
-    # jax 0.4.x has no public probe; the client attribute on the global
-    # distributed state is what is_initialized() reads in later releases.
-    state = getattr(getattr(jax, "_src", None), "distributed", None)
-    state = getattr(state, "global_state", None)
-    return getattr(state, "client", None) is not None
-
-
 def init_parallel_env():
     """paddle.distributed.init_parallel_env analog.
 
@@ -35,7 +24,7 @@ def init_parallel_env():
         return
     endpoints = os.environ.get("PADDLE_TRAINER_ENDPOINTS", "")
     n_nodes = len(endpoints.split(",")) if endpoints else 1
-    if n_nodes > 1 and not _jax_dist_initialized():
+    if n_nodes > 1 and not jax.distributed.is_initialized():
         # must run before any backend init — the client-state check only
         # inspects the distributed client, unlike jax.process_count() which
         # would itself initialize the backends. Genuine failures (bad

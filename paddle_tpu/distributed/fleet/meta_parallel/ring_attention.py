@@ -30,10 +30,7 @@ def _unwrap(x):
 
 
 def _axis_size(name):
-    fn = getattr(jax.lax, "axis_size", None)        # jax >= 0.5
-    if fn is None:
-        fn = jax.core.axis_frame                    # jax 0.4.x: returns size
-    return int(fn(name))
+    return int(jax.lax.axis_size(name))
 
 
 def ring_flash_attention(q, k, v, group=None, causal: bool = False,

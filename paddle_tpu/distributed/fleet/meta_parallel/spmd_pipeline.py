@@ -30,21 +30,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:                                    # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import lax, shard_map as _shard_map
 
 __all__ = ["spmd_pipeline", "make_spmd_pipeline_fn"]
-
-
-def _pcast_varying(x, axis_name):
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:       # jax 0.4.x: no varying-axes tracking — identity
-        return x
-    return pcast(x, (axis_name,), to="varying")
 
 
 def spmd_pipeline(stage_fn, stage_params, x_mb, *, num_stages: int,
@@ -87,8 +75,8 @@ def spmd_pipeline(stage_fn, stage_params, x_mb, *, num_stages: int,
     # mark the zero-init carries as pp-varying: the scan body makes them
     # vary over the pp axis (ppermute/stage compute) and shard_map's
     # varying-axes check requires carry-in == carry-out
-    state0 = _pcast_varying(jnp.zeros_like(x_mb[0]), axis_name)
-    out0 = _pcast_varying(jnp.zeros_like(x_mb), axis_name)
+    state0 = lax.pcast(jnp.zeros_like(x_mb[0]), (axis_name,), to="varying")
+    out0 = lax.pcast(jnp.zeros_like(x_mb), (axis_name,), to="varying")
     (_, outputs), _ = lax.scan(tick, (state0, out0), jnp.arange(ticks))
     # broadcast the last stage's collected outputs to every stage
     return lax.psum(jnp.where(s == num_stages - 1, outputs, 0.0),

@@ -16,11 +16,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:                                    # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from ....core.tensor import Tensor
 from .... import nn

@@ -309,8 +309,8 @@ def ring_pipeline(items: Sequence, transport, reduce, consume) -> None:
 
 
 # --------------------------------------------- Megatron tp region boundaries
-# custom_vjp pairs instead of differentiating raw collectives: jax 0.4.x
-# shard_map(check_rep=False) has no transpose story for `psum` that
+# custom_vjp pairs instead of differentiating raw collectives:
+# shard_map(check_vma=False) has no transpose story for `psum` that
 # matches the replicated-input/partial-grad semantics Megatron needs, and
 # the custom rules keep the backward reduction on the SAME fixed shard
 # order as the forward.

@@ -42,7 +42,7 @@ tp axis; the dp machinery slices each shard's TP-LOCAL flat view, so
 dp x tp composes on one mesh with no special cases. Loss functions
 crossing tp regions must use `mesh.copy_to_tp_region` /
 `mesh.reduce_from_tp_region` (differentiating raw collectives under
-`shard_map(check_rep=False)` is undefined on jax 0.4.x).
+`shard_map(check_vma=False)` has no defined transpose).
 
 **Limits** (validated loudly at construction): elementwise optimizers
 only (Lamb's trust ratio and LBFGS's history are whole-tensor
@@ -100,12 +100,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:                                   # newer jax exports it at top level
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:                    # jax 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ..nn import Layer
 from .mesh import (
@@ -941,7 +937,7 @@ class ZeroTrainStep:
             body, mesh=self.mesh,
             in_specs=(pspec, sspec, bspec, P(), P()),
             out_specs=out_specs,
-            check_rep=False,  # noqa: COLLECTIVE-MESH — the ordered fixed-shard-order collectives and the (dp,tp,chunk) state outputs are per-shard by design; 0.4.x rep tracking can't see through custom_vjp boundaries
+            check_vma=False,  # noqa: COLLECTIVE-MESH — the ordered fixed-shard-order collectives and the (dp,tp,chunk) state outputs are per-shard by design; varying-axes tracking can't see through the custom_vjp boundaries
             ))
 
     def __call__(self, params, opt_state, batch, lr, t):
@@ -1012,7 +1008,7 @@ class ZeroTrainStep:
             def allreduce(x):
                 return _shard_map(
                     reduce_one, mesh=mesh, in_specs=P(), out_specs=P(),
-                    check_rep=False,  # noqa: COLLECTIVE-MESH — probe psum of a replicated buffer; rep tracking adds latency to the very overhead being measured
+                    check_vma=False,  # noqa: COLLECTIVE-MESH — probe psum of a replicated buffer; rep tracking adds latency to the very overhead being measured
                     )(x)
             fn = jax.jit(allreduce)
             self._probes[(rows, width)] = fn
@@ -1111,11 +1107,11 @@ class ZeroTrainStep:
 
             rs = jax.jit(_shard_map(
                 rs_body, mesh=mesh, in_specs=P(), out_specs=P(DP_AXIS),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — probe scatter of a replicated buffer; rep tracking adds latency to the very overhead being measured
+                check_vma=False,  # noqa: COLLECTIVE-MESH — probe scatter of a replicated buffer; rep tracking adds latency to the very overhead being measured
                 ))
             ag = jax.jit(_shard_map(
                 ag_body, mesh=mesh, in_specs=P(DP_AXIS), out_specs=P(),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — probe gather; the all_gather output is replicated by construction
+                check_vma=False,  # noqa: COLLECTIVE-MESH — probe gather; the all_gather output is replicated by construction
                 ))
             fns = (rs, ag)
             self._probes[key] = fns
@@ -1235,7 +1231,7 @@ class ZeroTrainStep:
         def timed(body):
             fn = jax.jit(_shard_map(
                 body, mesh=mesh, in_specs=P(), out_specs=P(),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — schedule probe over the ring collectives; per-shard by design
+                check_vma=False,  # noqa: COLLECTIVE-MESH — schedule probe over the ring collectives; per-shard by design
                 ))
             x = jnp.float32(1.0)
             fn(x).block_until_ready()          # compile + warm

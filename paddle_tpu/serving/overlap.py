@@ -57,12 +57,8 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:                                   # newer jax exports it at top level
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:                    # jax 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ..core.tensor import Tensor
 from ..models import gpt as _gpt
@@ -430,7 +426,7 @@ def overlap_probe_fn(mesh, hidden: int, chunks: int):
 
     return _shard_map(
         body, mesh=mesh, in_specs=P(), out_specs=P(),
-        check_rep=False,  # noqa: COLLECTIVE-MESH — probe reduces a replicated buffer over the fixed-order ring; 0.4.x rep tracking cannot see through the ppermute accumulation
+        check_vma=False,  # noqa: COLLECTIVE-MESH — probe reduces a replicated buffer over the fixed-order ring; varying-axes tracking cannot see that the ppermute accumulation ends replicated
         )
 
 
@@ -478,7 +474,7 @@ def measure_overlap_fraction(mesh, tp_size: int, hidden: int, chunks: int,
     def timed(body) -> float:
         fn = jax.jit(_shard_map(
             body, mesh=mesh, in_specs=P(), out_specs=P(),
-            check_rep=False,  # noqa: COLLECTIVE-MESH — probe over a replicated buffer; rep tracking adds latency to the very wall being measured
+            check_vma=False,  # noqa: COLLECTIVE-MESH — probe over a replicated buffer; rep tracking adds latency to the very wall being measured
             ))
         fn(x).block_until_ready()          # compile + first dispatch
         fn(x).block_until_ready()          # warm-up: steady-state queue

@@ -22,7 +22,7 @@ engine/scheduler/allocator wiring uses to drop that assumption:
   raises", runs the engine, and asserts the survivors' token streams are
   identical to a fault-free run.
 
-Fault taxonomy (ISSUE 8) — every fault the serving stack can observe
+Fault classes (ISSUE 8) — every fault the serving stack can observe
 falls in exactly one of three classes, escalating in blast radius:
 
 - **transient** — the exception carries `transient=True` (every
@@ -137,7 +137,7 @@ def is_fatal(exc: BaseException) -> bool:
 def describe_fault(exc: BaseException) -> Dict[str, object]:
     """Small JSON-able classification of a fault for telemetry payloads
     (flight-recorder events, post-mortem bundles): exception type name
-    plus its position in the transient/persistent/fatal taxonomy."""
+    plus its position in the transient/persistent/fatal classes."""
     return {
         "exc": type(exc).__name__,
         "transient": is_transient(exc),

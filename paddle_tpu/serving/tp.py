@@ -60,12 +60,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:                                   # newer jax exports it at top level
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:                    # jax 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 # the unified mesh substrate (ISSUE 16): device ordering and mesh
 # construction are shared with the training engines in
@@ -356,7 +352,7 @@ class TPContext:
         everything else replicated. The sampled token and key state are
         computed from the replicated logits on EVERY shard, so the
         `P()` outputs are genuinely identical across devices
-        (check_rep=False: 0.4.x can't prove replication through the
+        (check_vma=False: the checker can't prove replication through the
         PRNG ops, but the final psum makes it so by construction)."""
         param_specs, mesh = self.param_specs, self.mesh
 
@@ -367,7 +363,7 @@ class TPContext:
                 in_specs=(param_specs, self._repl_like(buffers), P(),
                           pool_specs) + tuple(P() for _ in rest),
                 out_specs=(P(), P(), pool_specs),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
+                check_vma=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
                 )(params, buffers, ids, pools, *rest)
         return wrapped
 
@@ -385,7 +381,7 @@ class TPContext:
                 in_specs=(param_specs, self._repl_like(buffers), P(),
                           pool_specs) + tuple(P() for _ in rest),
                 out_specs=(P(), pool_specs, P(), P(), P(), P()),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
+                check_vma=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
                 )(params, buffers, tokens, pools, *rest)
         return wrapped
 
@@ -406,7 +402,7 @@ class TPContext:
                 in_specs=(param_specs, self._repl_like(buffers), P(),
                           pool_specs) + tuple(P() for _ in rest),
                 out_specs=(P(), pool_specs, P()),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
+                check_vma=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
                 )(params, buffers, flat_ids, pools, *rest)
         return wrapped
 
@@ -426,7 +422,7 @@ class TPContext:
                 in_specs=(param_specs, self._repl_like(buffers), P(),
                           pool_specs) + tuple(P() for _ in rest),
                 out_specs=(P(), pool_specs, P(), P(), P(), P(), P()),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
+                check_vma=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
                 )(params, buffers, tokens, pools, *rest)
         return wrapped
 
@@ -444,7 +440,7 @@ class TPContext:
                 in_specs=(param_specs, self._repl_like(buffers), P(),
                           pool_specs) + tuple(P() for _ in rest),
                 out_specs=(P(), pool_specs, P(), P()),
-                check_rep=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
+                check_vma=False,  # noqa: COLLECTIVE-MESH — pool outputs are per-shard by design (kv-head-sharded pages); rep checking would reject the contract
                 )(params, buffers, flat_ids, pools, *rest)
         return wrapped
 
@@ -489,7 +485,7 @@ class TPContext:
             def allreduce(x):
                 return _shard_map(reduce_one,
                                   mesh=mesh, in_specs=P(), out_specs=P(),
-                                  check_rep=False,  # noqa: COLLECTIVE-MESH — probe psum of a replicated buffer; rep tracking adds latency to the very overhead being measured
+                                  check_vma=False,  # noqa: COLLECTIVE-MESH — probe psum of a replicated buffer; rep tracking adds latency to the very overhead being measured
                                   )(x)
             fn = jax.jit(allreduce)
             self._probes[rows] = fn

@@ -340,13 +340,13 @@ class TestCollectiveMesh:
                                 rules=[analysis.get_rule("COLLECTIVE-MESH")])
         assert [(f.path, f.line) for f in fs] == [("pkg/net.py", 8)]
 
-    def test_check_rep_false_without_noqa_fires(self):
+    def test_check_vma_false_without_noqa_fires(self):
         fs = run("""
             import jax
             from jax import shard_map as _sm
             def build(mesh, fn):
                 return _sm(fn, mesh=mesh, in_specs=None, out_specs=None,
-                           check_rep=False)
+                           check_vma=False)
         """, rule="COLLECTIVE-MESH")
         assert [f.line for f in fs] == [6]
         assert "no `# noqa`" in fs[0].message
@@ -357,7 +357,7 @@ class TestCollectiveMesh:
             from jax import shard_map as _sm
             def build(mesh, fn):
                 return _sm(fn, mesh=mesh, in_specs=None, out_specs=None,
-                           check_rep=False)  # noqa: COLLECTIVE-MESH
+                           check_vma=False)  # noqa: COLLECTIVE-MESH
         """, rule="COLLECTIVE-MESH")
         assert [f.line for f in fs] == [6]
         assert "reasonless" in fs[0].message
@@ -368,7 +368,7 @@ class TestCollectiveMesh:
             from jax import shard_map as _sm
             def build(mesh, fn):
                 return _sm(fn, mesh=mesh, in_specs=None, out_specs=None,
-                           check_rep=False)  # noqa: COLLECTIVE-MESH — per-shard outputs by contract
+                           check_vma=False)  # noqa: COLLECTIVE-MESH — per-shard outputs by contract
         """, rule="COLLECTIVE-MESH")
         assert fs == []
 
@@ -412,7 +412,7 @@ class TestCollectiveMesh:
 
     def test_allgather_stale_axis_fires(self):
         # the all-gather half of the exchange against an axis the mesh
-        # never declared: wrong values, no error, once check_rep is off
+        # never declared: wrong values, no error, once check_vma is off
         fs = run("""
             import jax
             from jax.experimental.shard_map import shard_map

@@ -276,7 +276,7 @@ class TestFlightRecorder:
         rec.clear()
         assert len(rec) == 0 and rec.total_recorded == 1
 
-    def test_describe_fault_taxonomy(self):
+    def test_describe_fault_classes(self):
         from paddle_tpu.serving.resilience import InjectedFault
         d = describe_fault(InjectedFault("dispatch", 0, transient=True))
         assert d == {"exc": "InjectedFault", "transient": True,

@@ -20,19 +20,14 @@ SEP = 4
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with the vma/rep checker off, on any jax.
+    """jax.shard_map with the varying-axes checker off.
 
     Interpret-mode pallas expands to dynamic_slices mixing varying and
     constant operands, which the checker rejects (jax suggests exactly
-    this workaround); 0.4.x spells the knob check_rep, >=0.5 check_vma.
+    this workaround).
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:                                  # jax >= 0.5
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm   # jax 0.4.x
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _mesh():

@@ -85,10 +85,7 @@ class TestMeshSubstrate:
     def test_ordered_psum_scatter_matches_sliced_sum(self):
         """reduce-scatter shard i == slice i of the ordered all-reduce,
         bit-for-bit — the identity ZeRO-2's parity rests on."""
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = build_mesh(((DP_AXIS, 4),))
         x = _rng.randn(4, 64).astype("float32")
@@ -104,7 +101,7 @@ class TestMeshSubstrate:
         got, want = jax.jit(shard_map(
             body, mesh=mesh, in_specs=P(DP_AXIS),
             out_specs=(P(DP_AXIS), P(DP_AXIS)),
-            check_rep=False,  # noqa: COLLECTIVE-MESH — test fixture gathers per-shard views on purpose
+            check_vma=False,  # noqa: COLLECTIVE-MESH — test fixture gathers per-shard views on purpose
             ))(x)
         assert np.array_equal(np.asarray(got), np.asarray(want))
 

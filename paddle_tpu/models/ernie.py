@@ -15,9 +15,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
+
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import functional as F
+from ..profiler import scopes
 
 
 @dataclasses.dataclass
@@ -71,13 +74,22 @@ class ErnieSelfAttention(nn.Layer):
         b, s, h = x.shape
         # sdpa's layout contract is (b, s, heads, hd); the fused path
         # (Pallas flash on TPU) handles the additive float mask in-kernel
-        qkv = self.qkv(x).reshape([b, s, 3, self.num_heads, self.head_dim])
-        qkv = qkv.transpose([2, 0, 1, 3, 4])  # 3,b,s,heads,hd
-        q, k, v = qkv[0], qkv[1], qkv[2]
+        with jax.named_scope(scopes.ATTENTION):
+            qkv = self.qkv(x).reshape(
+                [b, s, 3, self.num_heads, self.head_dim])
+            qkv = qkv.transpose([2, 0, 1, 3, 4])  # 3,b,s,heads,hd
+            q, k, v = qkv[0], qkv[1], qkv[2]
+        # under no scope: a flash kernel's events are named after the
+        # path in front of its pallas_call, transformations and all
+        # (`jvp(jit(_flash_attention_data))`), and a scope around the
+        # call moves the `jvp(...)` onto the scope. The kernels get a
+        # name= of their own, and this call its scope, in one step with
+        # the metric that reads the present name (ROADMAP.md)
         ctx = F.scaled_dot_product_attention(
             q, k, v, attn_mask=attn_mask,
             dropout_p=self.dropout_p if self.training else 0.0)
-        return self.out(ctx.reshape([b, s, h]))
+        with jax.named_scope(scopes.ATTENTION):
+            return self.out(ctx.reshape([b, s, h]))
 
 
 class ErnieLayer(nn.Layer):
@@ -95,9 +107,11 @@ class ErnieLayer(nn.Layer):
     def forward(self, x, attn_mask=None):
         # post-LN (BERT convention)
         a = self.attention(x, attn_mask)
-        x = self.attn_norm(x + self.dropout(a))
-        f = self.ffn_out(F.gelu(self.ffn_in(x)))
-        return self.ffn_norm(x + self.dropout(f))
+        with jax.named_scope(scopes.ATTENTION):
+            x = self.attn_norm(x + self.dropout(a))
+        with jax.named_scope(scopes.FFN):
+            f = self.ffn_out(F.gelu(self.ffn_in(x)))
+            return self.ffn_norm(x + self.dropout(f))
 
 
 class ErnieEmbeddings(nn.Layer):
@@ -162,7 +176,8 @@ class ErnieModel(nn.Layer):
             # [b, s] 1/0 mask -> additive [b,1,1,s]
             attention_mask = ((1.0 - attention_mask.astype("float32"))
                               * -1e4).unsqueeze(1).unsqueeze(1)
-        x = self.embeddings(input_ids, token_type_ids, position_ids)
+        with jax.named_scope(scopes.EMBED):
+            x = self.embeddings(input_ids, token_type_ids, position_ids)
         if self.config.recompute and self.training:
             from ..distributed.fleet.recompute import recompute
 
@@ -197,26 +212,30 @@ class ErnieForPretraining(nn.Layer):
                 attention_mask=None, masked_lm_labels=None):
         seq, pooled = self.ernie(input_ids, token_type_ids, position_ids,
                                  attention_mask)
+        with jax.named_scope(scopes.MLM_HEAD_LOSS):
+            out = self._mlm_head(seq, masked_lm_labels)
+        return out, self.nsp(pooled)
+
+    def _mlm_head(self, seq, masked_lm_labels):
+        """The MLM transform and the tied vocabulary head: the loss where
+        labels are given, else the logits."""
         h = self.mlm_norm(F.gelu(self.transform(seq)))
         word_emb = self.ernie.embeddings.word_embeddings.weight
-        if masked_lm_labels is not None:
-            if self.config.fused_mlm_loss:
-                # tied-weight LM head + CE in one chunked pass — the f32
-                # (b*s, vocab) logits tensor never exists
-                from .. import incubate
+        if masked_lm_labels is None:
+            return h.matmul(word_emb, transpose_y=True) + self.mlm_bias
+        if self.config.fused_mlm_loss:
+            # tied-weight LM head + CE in one chunked pass — the f32
+            # (b*s, vocab) logits tensor never exists
+            from .. import incubate
 
-                mlm_loss = incubate.nn.functional.fused_linear_cross_entropy(
-                    h.reshape([-1, self.config.hidden_size]), word_emb,
-                    self.mlm_bias, masked_lm_labels.reshape([-1]),
-                    ignore_index=-100, transpose_y=True)
-            else:
-                logits = h.matmul(word_emb, transpose_y=True) + self.mlm_bias
-                mlm_loss = F.cross_entropy(
-                    logits.reshape([-1, self.config.vocab_size]),
-                    masked_lm_labels.reshape([-1]), ignore_index=-100)
-            return mlm_loss, self.nsp(pooled)
+            return incubate.nn.functional.fused_linear_cross_entropy(
+                h.reshape([-1, self.config.hidden_size]), word_emb,
+                self.mlm_bias, masked_lm_labels.reshape([-1]),
+                ignore_index=-100, transpose_y=True)
         logits = h.matmul(word_emb, transpose_y=True) + self.mlm_bias
-        return logits, self.nsp(pooled)
+        return F.cross_entropy(
+            logits.reshape([-1, self.config.vocab_size]),
+            masked_lm_labels.reshape([-1]), ignore_index=-100)
 
     def loss(self, logits, nsp_logits, mlm_labels, nsp_labels=None,
              ignore_index=-100):

@@ -10,8 +10,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
+
 from .. import nn
 from ..nn import functional as F
+from ..profiler import scopes
 
 
 @dataclasses.dataclass
@@ -64,9 +67,11 @@ class GPTAttention(nn.Layer):
     def forward(self, x, cache=None, start_pos=0):
         b, s, h = x.shape
         # scaled_dot_product_attention's layout contract is (b, s, heads, hd)
-        qkv = self.qkv(x).reshape([b, s, 3, self.num_heads, self.head_dim])
-        qkv = qkv.transpose([2, 0, 1, 3, 4])  # 3,b,s,nh,hd
-        q, k, v = qkv[0], qkv[1], qkv[2]
+        with jax.named_scope(scopes.ATTN_QKV):
+            qkv = self.qkv(x).reshape(
+                [b, s, 3, self.num_heads, self.head_dim])
+            qkv = qkv.transpose([2, 0, 1, 3, 4])  # 3,b,s,nh,hd
+            q, k, v = qkv[0], qkv[1], qkv[2]
         if cache is not None:  # KV-cache decode (inference only)
             return self.attend(q, k, v, b, s, cache, start_pos)
         ctx = F.scaled_dot_product_attention(
@@ -88,8 +93,10 @@ class GPTAttention(nn.Layer):
         # parallelism this module runs with num_heads/tp local heads,
         # so ctx is narrower than the input (and b may be a symbolic
         # -1 under to_static, ruling out a -1 here)
-        return self.out(
-            ctx.reshape([b, s, self.num_heads * self.head_dim])), new_cache
+        with jax.named_scope(scopes.ATTN_OUT):
+            out = self.out(
+                ctx.reshape([b, s, self.num_heads * self.head_dim]))
+        return out, new_cache
 
 
 def _resolve_tp_overlap(x):
@@ -132,9 +139,14 @@ class GPTBlock(nn.Layer):
             x = x + self.dropout(
                 self.ffn_out(F.gelu(self.ffn_in(self.ln2(x)))))
             return x
-        attn, new_cache = self.attn(self.ln1(x), cache, start_pos)
-        x = x + self.dropout(attn)
-        x = x + self.dropout(self.ffn_out(F.gelu(self.ffn_in(self.ln2(x)))))
+        with jax.named_scope(scopes.ATTN_QKV):
+            normed = self.ln1(x)
+        attn, new_cache = self.attn(normed, cache, start_pos)
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = x + self.dropout(attn)
+        with jax.named_scope(scopes.MLP):
+            x = x + self.dropout(
+                self.ffn_out(F.gelu(self.ffn_in(self.ln2(x)))))
         return x, new_cache
 
 
@@ -187,7 +199,8 @@ class GPTModel(nn.Layer):
                 else:
                     position_ids = Tensor(
                         (jnp.arange(s, dtype=jnp.int32) + sp)[None])
-        x = self.dropout(self.wte(input_ids) + self.wpe(position_ids))
+        with jax.named_scope(scopes.EMBED):
+            x = self.dropout(self.wte(input_ids) + self.wpe(position_ids))
         if caches is None:
             for blk in self.blocks:
                 x = blk(x)
@@ -199,7 +212,8 @@ class GPTModel(nn.Layer):
         for blk, cache in zip(self.blocks, caches):
             x, nc = blk(x, cache, start_pos)
             new_caches.append(nc)
-        return self.ln_f(_resolve_tp_overlap(x)), new_caches
+        with jax.named_scope(scopes.LM_HEAD):
+            return self.ln_f(_resolve_tp_overlap(x)), new_caches
 
 
 class GPTEmbeddingPipe(nn.Layer):
@@ -290,7 +304,9 @@ class GPTForCausalLM(nn.Layer):
                 return self.loss(logits, labels)
             return logits
         h, new_caches = self.gpt(input_ids, position_ids, caches, start_pos)
-        return h.matmul(self.gpt.wte.weight, transpose_y=True), new_caches
+        with jax.named_scope(scopes.LM_HEAD):
+            logits = h.matmul(self.gpt.wte.weight, transpose_y=True)
+        return logits, new_caches
 
     def generate(self, input_ids, **kwargs):
         from .generation import generate

@@ -104,6 +104,7 @@ from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..nn import Layer
+from ..profiler import scopes
 from .mesh import (
     DP_AXIS, TP_AXIS, build_mesh, collected_shard_sum, device_order,
     local_shape, ordered_psum, ordered_psum_scatter, ring_collect,
@@ -336,15 +337,19 @@ def _replicated_update(ctx, params, grads, state, lr, t, scale=None):
     trace is unchanged). Under bf16 (`scale` set) the all-reduced
     scaled grad is unscaled into fp32 before the master-weight
     update."""
-    if scale is None:
-        inv = jnp.float32(1.0 / ctx.dp)
-        g = {k: ordered_psum(grads[k], DP_AXIS) * inv for k in grads}
-    else:
-        g = {k: _unscale_shard(ctx, ordered_psum(grads[k], DP_AXIS), scale)
-             for k in grads}
+    with jax.named_scope(scopes.GRAD_REDUCE):
+        if scale is None:
+            inv = jnp.float32(1.0 / ctx.dp)
+            g = {k: ordered_psum(grads[k], DP_AXIS) * inv for k in grads}
+        else:
+            g = {k: _unscale_shard(ctx, ordered_psum(grads[k], DP_AXIS),
+                                   scale)
+                 for k in grads}
     # functional_step indexes state by param name, so the reserved
     # scaler entry (when present) is naturally out of its reach
-    new_p, new_s = ctx.optimizer.functional_step(params, g, state, lr, t)
+    with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+        new_p, new_s = ctx.optimizer.functional_step(params, g, state,
+                                                     lr, t)
     aux = None
     if ctx._telemetry is not None:
         # g is replicated across dp (already all-reduced): no dp
@@ -375,31 +380,37 @@ def _sharded_update(ctx, params, grads, state, lr, t, scale=None):
     for k in names:
         chunk = ctx._chunks[k]
         padded = ctx.dp * chunk
-        if ctx.stage >= 2:
-            gs = ordered_psum_scatter(_pad_flat(grads[k], padded), DP_AXIS)
-            gs = gs * inv if scale is None else _unscale_shard(
-                ctx, gs, scale)
-        elif scale is None:
-            gfull = ordered_psum(grads[k], DP_AXIS) * inv
-            gs = jax.lax.dynamic_slice(_pad_flat(gfull, padded),
-                                       (i * chunk,), (chunk,))
-        else:
-            gfull = ordered_psum(grads[k], DP_AXIS)
-            gs = _unscale_shard(
-                ctx, jax.lax.dynamic_slice(_pad_flat(gfull, padded),
-                                           (i * chunk,), (chunk,)), scale)
-        sliced_p[k] = jax.lax.dynamic_slice(_pad_flat(params[k], padded),
-                                            (i * chunk,), (chunk,))
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            if ctx.stage >= 2:
+                gs = ordered_psum_scatter(_pad_flat(grads[k], padded),
+                                          DP_AXIS)
+                gs = gs * inv if scale is None else _unscale_shard(
+                    ctx, gs, scale)
+            elif scale is None:
+                gfull = ordered_psum(grads[k], DP_AXIS) * inv
+                gs = jax.lax.dynamic_slice(_pad_flat(gfull, padded),
+                                           (i * chunk,), (chunk,))
+            else:
+                gfull = ordered_psum(grads[k], DP_AXIS)
+                gs = _unscale_shard(
+                    ctx, jax.lax.dynamic_slice(_pad_flat(gfull, padded),
+                                               (i * chunk,), (chunk,)),
+                    scale)
+        with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+            sliced_p[k] = jax.lax.dynamic_slice(
+                _pad_flat(params[k], padded), (i * chunk,), (chunk,))
         sliced_g[k] = gs
         # state leaves arrive as this shard's (1, 1, chunk) block
         local_state[k] = {slot: v.reshape(-1)
                           for slot, v in state[k].items()}
-    new_slices, new_state = ctx.optimizer.functional_step(
-        sliced_p, sliced_g, local_state, lr, t)
     new_params = {}
-    for k in names:
-        full = jax.lax.all_gather(new_slices[k], DP_AXIS).reshape(-1)
-        new_params[k] = full[:ctx._loc_sizes[k]].reshape(ctx._loc_shapes[k])
+    with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+        new_slices, new_state = ctx.optimizer.functional_step(
+            sliced_p, sliced_g, local_state, lr, t)
+        for k in names:
+            full = jax.lax.all_gather(new_slices[k], DP_AXIS).reshape(-1)
+            new_params[k] = full[:ctx._loc_sizes[k]].reshape(
+                ctx._loc_shapes[k])
     aux = None
     if ctx._telemetry is not None:
         aux = ctx._trmod.grad_leaf_stats(ctx, sliced_g, dp_reduce=True)
@@ -452,24 +463,28 @@ def _bucketed_update(ctx, params, grads, state, lr, t, scale=None):
     sliced_p, sliced_g, local_state = {}, {}, {}
     for bucket in ctx._buckets:
         width = bucket["width"]
-        flat = _pack_bucket(ctx, bucket, grads)
-        if ctx.stage >= 2:
-            shard = ordered_psum_scatter(flat, DP_AXIS)
-        else:
-            full = ordered_psum(flat, DP_AXIS)
-            shard = jax.lax.dynamic_slice(full, (i * width,), (width,))
-        shard = shard * inv if scale is None else _unscale_shard(
-            ctx, shard, scale)
-        _slice_local(ctx, params, state, bucket, i, sliced_p, sliced_g,
-                     local_state, shard)
-    new_slices, new_state = ctx.optimizer.functional_step(
-        sliced_p, sliced_g, local_state, lr, t)
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            flat = _pack_bucket(ctx, bucket, grads)
+            if ctx.stage >= 2:
+                shard = ordered_psum_scatter(flat, DP_AXIS)
+            else:
+                full = ordered_psum(flat, DP_AXIS)
+                shard = jax.lax.dynamic_slice(full, (i * width,), (width,))
+            shard = shard * inv if scale is None else _unscale_shard(
+                ctx, shard, scale)
+        with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+            _slice_local(ctx, params, state, bucket, i, sliced_p, sliced_g,
+                         local_state, shard)
     new_params = {}
-    for bucket in ctx._buckets:
-        cat = jnp.concatenate([new_slices[k] for k in bucket["names"]]) \
-            if len(bucket["names"]) > 1 else new_slices[bucket["names"][0]]
-        gathered = jax.lax.all_gather(cat, DP_AXIS)        # (dp, width)
-        _unpack_gathered(ctx, bucket, gathered, new_params)
+    with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+        new_slices, new_state = ctx.optimizer.functional_step(
+            sliced_p, sliced_g, local_state, lr, t)
+        for bucket in ctx._buckets:
+            members = bucket["names"]
+            cat = (jnp.concatenate([new_slices[k] for k in members])
+                   if len(members) > 1 else new_slices[members[0]])
+            gathered = jax.lax.all_gather(cat, DP_AXIS)    # (dp, width)
+            _unpack_gathered(ctx, bucket, gathered, new_params)
     aux = None
     if ctx._telemetry is not None:
         aux = ctx._trmod.grad_leaf_stats(
@@ -502,9 +517,11 @@ def _overlapped_update(ctx, params, grads, state, lr, t, scale=None):
     new_state: Dict = {}
     stat_slices: Dict = {}
 
+    @jax.named_scope(scopes.GRAD_REDUCE)
     def transport(bucket):
         return ring_collect(_pack_bucket(ctx, bucket, grads), DP_AXIS, n)
 
+    @jax.named_scope(scopes.GRAD_REDUCE)
     def reduce(moved):
         if ctx.stage >= 2:
             return collected_shard_sum(moved, DP_AXIS)
@@ -514,6 +531,7 @@ def _overlapped_update(ctx, params, grads, state, lr, t, scale=None):
         width = moved.shape[1] // n
         return jax.lax.dynamic_slice(full, (i * width,), (width,))
 
+    @jax.named_scope(scopes.OPTIMIZER_UPDATE)
     def consume(j, shard):
         bucket = buckets[j]
         shard = shard * jnp.float32(1.0 / n) if scale is None \
@@ -536,8 +554,9 @@ def _overlapped_update(ctx, params, grads, state, lr, t, scale=None):
 
     ring_pipeline(buckets, transport, reduce, consume)
     new_params: Dict = {}
-    for j, bucket in enumerate(buckets):
-        _unpack_gathered(ctx, bucket, gathered[j], new_params)
+    with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+        for j, bucket in enumerate(buckets):
+            _unpack_gathered(ctx, bucket, gathered[j], new_params)
     aux = None
     if ctx._telemetry is not None:
         aux = ctx._trmod.grad_leaf_stats(
@@ -890,23 +909,28 @@ class ZeroTrainStep:
             # every stage (and every bucket/overlap schedule) compiles
             # the identical backward.
             loss, grads = jax.lax.optimization_barrier((loss, grads))
-            loss = ordered_psum(loss, DP_AXIS) * inv_dp
             finite = None
-            if scaled:
-                # skip signal BEFORE any reduction mixes shards
-                finite = _grad_nonfinite(ctx, grads) == jnp.float32(0.0)
+            with jax.named_scope(scopes.GRAD_REDUCE):
+                loss = ordered_psum(loss, DP_AXIS) * inv_dp
+                if scaled:
+                    # skip signal BEFORE any reduction mixes shards
+                    finite = (_grad_nonfinite(ctx, grads)
+                              == jnp.float32(0.0))
+            # the update functions put their own work under grad_reduce
+            # and optimizer_update
             new_p, new_s, aux = update_fn(ctx, params, grads, state,
                                           lr, t, scale=scale)
             extras = None
             if scaled:
                 # nonfinite step: revert params AND state wholesale (the
                 # update ran on garbage), then let the scaler back off
-                new_p = {k: jnp.where(finite, v, params[k])
-                         for k, v in new_p.items()}
-                new_s = {k: {slot: jnp.where(finite, v, state[k][slot])
-                             for slot, v in acc.items()}
-                         for k, acc in new_s.items()}
-                new_scaler = _scaler_next(ctx, scaler, finite)
+                with jax.named_scope(scopes.OPTIMIZER_UPDATE):
+                    new_p = {k: jnp.where(finite, v, params[k])
+                             for k, v in new_p.items()}
+                    new_s = {k: {slot: jnp.where(finite, v, state[k][slot])
+                                 for slot, v in acc.items()}
+                             for k, acc in new_s.items()}
+                    new_scaler = _scaler_next(ctx, scaler, finite)
                 new_s[_SCALER_KEY] = new_scaler
                 extras = (new_scaler["scale"],
                           jnp.float32(1.0)
@@ -934,7 +958,7 @@ class ZeroTrainStep:
         out_specs = ((P(), pspec, sspec) if self._telemetry is None
                      else (P(), pspec, sspec, P()))
         self._step = jax.jit(_shard_map(
-            body, mesh=self.mesh,
+            scopes.named(body, "zero_train_step"), mesh=self.mesh,
             in_specs=(pspec, sspec, bspec, P(), P()),
             out_specs=out_specs,
             check_vma=False,  # noqa: COLLECTIVE-MESH — the ordered fixed-shard-order collectives and the (dp,tp,chunk) state outputs are per-shard by design; varying-axes tracking can't see through the custom_vjp boundaries

@@ -19,6 +19,9 @@ import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
+# resolved once, here: `RecordEvent.begin` is on the serving hot path
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = [
     "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
     "add_host_span",
@@ -130,12 +133,16 @@ class RecordEvent:
     """Context manager / start-stop host span (paddle.profiler.RecordEvent).
 
     Usable as `with RecordEvent('fwd'): ...` or begin()/end(). Also enters a
-    jax.profiler TraceAnnotation so the span shows inside device traces.
+    jax.profiler TraceAnnotation so the span shows inside device traces;
+    keyword `attrs` (ints and strings the host already has) ride on that
+    annotation and arrive in the trace as the event's stats.
     """
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    def __init__(self, name: str, event_type: str = "UserDefined",
+                 **attrs):
         self.name = name
         self.event_type = event_type
+        self.attrs = attrs
         self._start: Optional[float] = None
         self._annotation = None
 
@@ -147,14 +154,8 @@ class RecordEvent:
         else:
             self._native_t0 = None
         self._start = time.perf_counter()
-        try:
-            import jax.profiler as jp
-
-            self._annotation = jp.TraceAnnotation(self.name)
-            self._annotation.__enter__()
-        except Exception:  # noqa: BLE001 — device annotation is optional;
-            # the host-side span still records either way
-            self._annotation = None
+        self._annotation = _TraceAnnotation(self.name, **self.attrs)
+        self._annotation.__enter__()
         return self
 
     def end(self):

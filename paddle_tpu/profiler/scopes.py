@@ -1,0 +1,56 @@
+"""The names the device trace is read by, in one place.
+
+Every unit of device work that a metric or an operator reads is named
+where the program creates it: a Pallas kernel by its `name=`, a layer
+boundary of the two hot steps by a `jax.named_scope` of one of the
+strings below, a jitted executable by its `__name__`. A scope changes
+an operation's `op_name` metadata and nothing else: the compiled
+program is the same, the trace says where its time went.
+`PERF.md` section 3 quotes these lists; `chipbench/scopes.py` keeps
+the benchmark's own copy (a test holds the two together).
+"""
+from __future__ import annotations
+
+# serving: models/gpt.py cache path, serving/attention.py, serving/engine.py
+EMBED = "embed"
+ATTN_QKV = "attn_qkv"
+KV_WRITE = "kv_write"
+PAGED_ATTENTION = "paged_attention"
+PREFILL_ATTENTION = "prefill_attention"
+ATTN_OUT = "attn_out"
+MLP = "mlp"
+LM_HEAD = "lm_head"
+SAMPLING = "sampling"
+
+# training: models/ernie.py and the fused ops it calls, parallel/zero.py
+ATTENTION = "attention"
+FFN = "ffn"
+MLM_HEAD_LOSS = "mlm_head_loss"
+GRAD_REDUCE = "grad_reduce"
+OPTIMIZER_UPDATE = "optimizer_update"
+
+SERVE_SCOPES = (EMBED, ATTN_QKV, KV_WRITE, PAGED_ATTENTION,
+                PREFILL_ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLING)
+TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
+                OPTIMIZER_UPDATE)
+
+# Pallas kernels of serving/attention.py: the trace names their events
+# `%paged_decode.N` / `%paged_ragged.N` whatever encloses the call
+PAGED_DECODE_KERNEL = "paged_decode"
+PAGED_RAGGED_KERNEL = "paged_ragged"
+
+# jitted executables: the trace's `XLA Modules` line reads
+# `jit_<name>`; a family is its first word
+EXECUTABLES = ("prefill", "prefill_offset", "prefill_chunk", "decode_block",
+               "ragged_block", "spec_decode_block", "spec_ragged_block",
+               "zero_train_step")
+
+
+def named(fn, name: str):
+    """`fn` under the name its jitted executable is to carry, one of
+    `EXECUTABLES`."""
+    if name not in EXECUTABLES:
+        raise ValueError(f"{name!r} is not in scopes.EXECUTABLES: add it "
+                         "there, where PERF.md and the tests read the list")
+    fn.__name__ = fn.__qualname__ = name
+    return fn

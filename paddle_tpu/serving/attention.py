@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..profiler import scopes
 from .kv_cache import NULL_PAGE, PagedLayerCache, overflow_position
 
 __all__ = ["paged_attend", "paged_decode_attention",
@@ -139,81 +140,89 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
     b, s = q.shape[0], q.shape[1]
     max_pages = page_table.shape[1]
 
-    kd_raw = k._data if hasattr(k, "_data") else k
-    vd_raw = v._data if hasattr(v, "_data") else v
-    if cache.quantized:
-        # quantized pools: fresh K/V is quantized ONCE here, at page-write
-        # time, so every later read — decode, chunked prefill, ragged,
-        # prefix-cache reuse — sees the identical bytes (lazy import: an
-        # fp32/bf16 cache never reaches this branch)
-        from .quant import quantize_tokens
-        spec = _pool_quant_spec(kp.dtype)
-        kd, k_sc = quantize_tokens(kd_raw, spec)
-        vd, v_sc = quantize_tokens(vd_raw, spec)
-    else:
-        kd = kd_raw.astype(kp.dtype)
-        vd = vd_raw.astype(vp.dtype)
-    pos = _positions(start_pos, b, s)                # (b, s)
-    page_idx = pos // ps
-    if cache.row_ids is not None:
-        # flat ragged batch (b == 1, s == T): token t writes through the
-        # page table ROW it belongs to, not batch row 0
-        pt_rows = page_table[cache.row_ids]          # (T, maxP)
-        entries = jnp.take_along_axis(
-            pt_rows, jnp.clip(page_idx[0], 0, max_pages - 1)[:, None],
-            axis=1)[:, 0][None]                      # (1, T)
-    else:
-        entries = jnp.take_along_axis(
-            page_table, jnp.clip(page_idx, 0, max_pages - 1), axis=1)
-    # padding rows whose position overflows the table (suffix prefill:
-    # offset + bucket may exceed max_pages * page_size) must land in the
-    # null page — clipping the index instead would alias them onto the
-    # sequence's REAL last page and corrupt it
-    entries = jnp.where(page_idx >= max_pages, NULL_PAGE, entries)
-    slots = pos % ps
-    kp = _write_pages(kp, kd.reshape(b * s, *kd.shape[2:]),
-                      entries.reshape(-1), slots.reshape(-1))
-    vp = _write_pages(vp, vd.reshape(b * s, *vd.shape[2:]),
-                      entries.reshape(-1), slots.reshape(-1))
-    ks_pool, vs_pool = cache.k_scale, cache.v_scale
-    if cache.quantized:
-        # the scale slab is scattered with the SAME entries/slots as the
-        # data slab — the null-page/overflow routing above covers both
-        ks_pool = _write_pages(ks_pool, k_sc.reshape(b * s, -1, 1),
-                               entries.reshape(-1), slots.reshape(-1))
-        vs_pool = _write_pages(vs_pool, v_sc.reshape(b * s, -1, 1),
-                               entries.reshape(-1), slots.reshape(-1))
-    new_cache = PagedLayerCache(kp, vp, page_table, cache.row_ids,
-                                k_scale=ks_pool, v_scale=vs_pool)
+    with jax.named_scope(scopes.KV_WRITE):
+        kd_raw = k._data if hasattr(k, "_data") else k
+        vd_raw = v._data if hasattr(v, "_data") else v
+        if cache.quantized:
+            # quantized pools: fresh K/V is quantized ONCE here, at page-write
+            # time, so every later read — decode, chunked prefill, ragged,
+            # prefix-cache reuse — sees the identical bytes (lazy import: an
+            # fp32/bf16 cache never reaches this branch)
+            from .quant import quantize_tokens
+            spec = _pool_quant_spec(kp.dtype)
+            kd, k_sc = quantize_tokens(kd_raw, spec)
+            vd, v_sc = quantize_tokens(vd_raw, spec)
+        else:
+            kd = kd_raw.astype(kp.dtype)
+            vd = vd_raw.astype(vp.dtype)
+        pos = _positions(start_pos, b, s)                # (b, s)
+        page_idx = pos // ps
+        if cache.row_ids is not None:
+            # flat ragged batch (b == 1, s == T): token t writes through the
+            # page table ROW it belongs to, not batch row 0
+            pt_rows = page_table[cache.row_ids]          # (T, maxP)
+            entries = jnp.take_along_axis(
+                pt_rows, jnp.clip(page_idx[0], 0, max_pages - 1)[:, None],
+                axis=1)[:, 0][None]                      # (1, T)
+        else:
+            entries = jnp.take_along_axis(
+                page_table, jnp.clip(page_idx, 0, max_pages - 1), axis=1)
+        # padding rows whose position overflows the table (suffix prefill:
+        # offset + bucket may exceed max_pages * page_size) must land in the
+        # null page — clipping the index instead would alias them onto the
+        # sequence's REAL last page and corrupt it
+        entries = jnp.where(page_idx >= max_pages, NULL_PAGE, entries)
+        slots = pos % ps
+        kp = _write_pages(kp, kd.reshape(b * s, *kd.shape[2:]),
+                          entries.reshape(-1), slots.reshape(-1))
+        vp = _write_pages(vp, vd.reshape(b * s, *vd.shape[2:]),
+                          entries.reshape(-1), slots.reshape(-1))
+        ks_pool, vs_pool = cache.k_scale, cache.v_scale
+        if cache.quantized:
+            # the scale slab is scattered with the SAME entries/slots as the
+            # data slab — the null-page/overflow routing above covers both
+            ks_pool = _write_pages(ks_pool, k_sc.reshape(b * s, -1, 1),
+                                   entries.reshape(-1), slots.reshape(-1))
+            vs_pool = _write_pages(vs_pool, v_sc.reshape(b * s, -1, 1),
+                                   entries.reshape(-1), slots.reshape(-1))
+        new_cache = PagedLayerCache(kp, vp, page_table, cache.row_ids,
+                                    k_scale=ks_pool, v_scale=vs_pool)
 
     raw_start = start_pos._data if hasattr(start_pos, "_data") else start_pos
     static_zero = isinstance(raw_start, int) and raw_start == 0
-    if cache.row_ids is not None:
-        ctx = ragged_paged_attention(q, new_cache, pos, rep, bias=bias)
-    elif s == 1:
-        ctx = paged_decode_attention(q, new_cache, pos[:, 0], rep,
-                                     bias=bias)
-    elif static_zero and not cache.quantized:
-        _count_dispatch("prefill")
-        ctx = _prefill_attention(q, kd, vd, pos, rep, bias=bias)
-    elif static_zero:
-        # quantized pools route EVERY multi-token prefill through the
-        # paged gather: the exact path would read the un-quantized fresh
-        # K/V and diverge from what chunked/prefix/migration legs read
-        # back from the pool — within a quantized mode, all paths must
-        # see the same quantized bytes
-        _count_dispatch("prefill_paged_quant")
-        ctx = _prefill_attention_paged(q, new_cache, pos, rep, bias=bias)
-    else:
-        # prefill at a TRACED (or nonzero) offset: earlier K/V lives
-        # only in the pool's pages, so attend through the page table.
-        # Both offset prefills land here — a prefix-cache suffix prefill
-        # AND every chunk of a chunked prefill (its offset is traced, so
-        # even a first chunk at offset 0 takes this path; that is what
-        # lets one chunked executable serve every chunk of every prompt)
-        _count_dispatch("prefill_paged_quant" if cache.quantized
-                        else "prefill_paged")
-        ctx = _prefill_attention_paged(q, new_cache, pos, rep, bias=bias)
+    # the exact prefill attends this step's own K/V block; every other
+    # branch reaches K/V through the page table: pool views, pads, the
+    # relayout, the kernel, the output slice
+    exact_prefill = (cache.row_ids is None and s != 1 and static_zero
+                     and not cache.quantized)
+    with jax.named_scope(scopes.PREFILL_ATTENTION if exact_prefill
+                         else scopes.PAGED_ATTENTION):
+        if cache.row_ids is not None:
+            ctx = ragged_paged_attention(q, new_cache, pos, rep, bias=bias)
+        elif s == 1:
+            ctx = paged_decode_attention(q, new_cache, pos[:, 0], rep,
+                                         bias=bias)
+        elif static_zero and not cache.quantized:
+            _count_dispatch("prefill")
+            ctx = _prefill_attention(q, kd, vd, pos, rep, bias=bias)
+        elif static_zero:
+            # quantized pools route EVERY multi-token prefill through the
+            # paged gather: the exact path would read the un-quantized fresh
+            # K/V and diverge from what chunked/prefix/migration legs read
+            # back from the pool — within a quantized mode, all paths must
+            # see the same quantized bytes
+            _count_dispatch("prefill_paged_quant")
+            ctx = _prefill_attention_paged(q, new_cache, pos, rep, bias=bias)
+        else:
+            # prefill at a TRACED (or nonzero) offset: earlier K/V lives
+            # only in the pool's pages, so attend through the page table.
+            # Both offset prefills land here — a prefix-cache suffix prefill
+            # AND every chunk of a chunked prefill (its offset is traced, so
+            # even a first chunk at offset 0 takes this path; that is what
+            # lets one chunked executable serve every chunk of every prompt)
+            _count_dispatch("prefill_paged_quant" if cache.quantized
+                            else "prefill_paged")
+            ctx = _prefill_attention_paged(q, new_cache, pos, rep, bias=bias)
     return ctx, new_cache
 
 
@@ -581,6 +590,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g_p, d_p), q.dtype),
         interpret=interpret,
+        name=scopes.PAGED_DECODE_KERNEL,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
     return out[:, :, :rep, :hd].reshape(b, 1, heads, hd)
 
@@ -710,6 +720,7 @@ def _ragged_paged_pallas(q, k_pool, v_pool, page_table, pos, row_ids,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, g_p, d_p), q.dtype),
         interpret=interpret,
+        name=scopes.PAGED_RAGGED_KERNEL,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
       row_ids.astype(jnp.int32), *operands)
     return out[:, :, :rep, :hd].reshape(1, t, heads, hd)

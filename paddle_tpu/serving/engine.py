@@ -73,7 +73,7 @@ from ..observability import Histogram, LifecycleTracker, MetricsRegistry
 from ..observability.flight_recorder import (
     build_postmortem as _build_bundle, dump_postmortem as _dump_bundle)
 from ..observability.slo import SloTracker
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, scopes
 from .attention import advance_positions
 from .kv_cache import (PagedKVCache, PagedLayerCache, overflow_position,
                        pages_for, pools_from_views, views_from_pools)
@@ -105,6 +105,7 @@ def _default_buckets(max_seq_len: int) -> Tuple[int, ...]:
     return tuple(buckets)
 
 
+@jax.named_scope(scopes.SAMPLING)
 def _sample_batch(logits, keys, temps, top_ks, top_ps):
     """Per-row sampling with TRACED knobs (the batch mixes requests with
     different sampling params). Mirrors generation._sample row-wise:
@@ -132,6 +133,7 @@ def _sample_batch(logits, keys, temps, top_ks, top_ps):
     return jnp.where(temps == 0.0, greedy, sampled)
 
 
+@jax.named_scope(scopes.SAMPLING)
 def _split_rows(key_data):
     """One split per row, entirely on device: key_data (b, 2) uint32 ->
     (new key_data, sample keys). Bit-identical to the host-side
@@ -183,6 +185,25 @@ class ServingObs:
         self.decode_seconds = c(
             "serving_decode_seconds_total",
             "decode wall time (async-overlap deduplicated)")
+        # batch occupancy and queue wait, counted where the engine
+        # dispatches and admits, from host integers and clocks only:
+        # live over dispatched is the share of the decode batch the
+        # device computes for a request that still wants tokens
+        self.decode_rows_live = c(
+            "serving_decode_rows_live_total",
+            "decode-block rows with token budget left at dispatch")
+        self.decode_rows_dispatched = c(
+            "serving_decode_rows_dispatched_total",
+            "decode-block rows the device computes (the power-of-two "
+            "row count of each dispatched block)")
+        self.admissions = c(
+            "serving_admissions_total",
+            "requests admitted from the waiting queue (a requeued "
+            "request counts again)")
+        self.queue_wait_seconds = c(
+            "serving_queue_wait_seconds_total",
+            "seconds from arrival (or from the last requeue) to "
+            "admission, summed over admissions")
         self.compile_miss = {
             fam: c("serving_jit_compile_misses_total",
                    "distinct executables per step family "
@@ -333,11 +354,16 @@ class ServingObs:
         self.lifecycle.point(req.request_id, "enqueued", req.arrival_t)
 
     def admitted(self, req) -> None:
-        self.lifecycle.point(req.request_id, "admitted")
+        now = time.perf_counter()
+        since = req.arrival_t if req.requeue_t is None else req.requeue_t
+        self.admissions.inc()
+        self.queue_wait_seconds.inc(max(now - since, 0.0))
+        self.lifecycle.point(req.request_id, "admitted", now)
 
     def preempted(self, req) -> None:
         self.preemptions.inc()
         now = time.perf_counter()
+        req.requeue_t = now
         self.lifecycle.point(req.request_id, "preempted", now)
         self.lifecycle.point(req.request_id, "requeued", now)
 
@@ -1124,7 +1150,8 @@ class ServingEngine:
 
             if tp is not None:
                 prefill = tp.wrap_prefill_exec(prefill)
-            self._jit_cache[key] = jax.jit(prefill, donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(prefill, "prefill"), donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _prefill_offset_jit(self, bucket: int):
@@ -1155,7 +1182,8 @@ class ServingEngine:
 
             if tp is not None:
                 prefill = tp.wrap_prefill_exec(prefill)
-            self._jit_cache[key] = jax.jit(prefill, donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(prefill, "prefill_offset"), donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _emit(self, req: Request, token: int, now: float
@@ -1219,7 +1247,8 @@ class ServingEngine:
         if self._recorder is not None:
             self._recorder.record("dispatch", family=family,
                                   rid=req.request_id, tokens=len(suffix))
-        with RecordEvent("serving.prefill"):
+        with RecordEvent("serving.prefill", bucket=bucket,
+                         prompt_tokens=len(suffix), rid=req.request_id):
             token, err = self._guarded_call("dispatch", dispatch)
         if token is None:
             # isolate THIS request; any pending decode block belongs to
@@ -1289,7 +1318,8 @@ class ServingEngine:
 
             if tp is not None:
                 prefill = tp.wrap_prefill_exec(prefill)
-            self._jit_cache[key] = jax.jit(prefill, donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(prefill, "prefill_chunk"), donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _chunk_prefill(self, task) -> List[Tuple[int, int]]:
@@ -1465,8 +1495,9 @@ class ServingEngine:
 
             if tp is not None:
                 ragged_block = tp.wrap_ragged_exec(ragged_block)
-            self._jit_cache[key] = jax.jit(ragged_block,
-                                           donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(ragged_block, "ragged_block"),
+                donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _ragged_step(self, decision) -> List[Tuple[int, int]]:
@@ -1669,8 +1700,9 @@ class ServingEngine:
 
             if tp is not None:
                 decode_block = tp.wrap_decode_exec(decode_block)
-            self._jit_cache[key] = jax.jit(decode_block,
-                                           donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(decode_block, "decode_block"),
+                donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _decode_rows(self, n: int) -> int:
@@ -1758,6 +1790,7 @@ class ServingEngine:
         for req in reqs:
             cap = req.max_new_tokens - len(req.generated) - req.inflight
             incr.append(max(min(h, cap), 0))
+        live = sum(1 for n in incr if n > 0)
 
         def dispatch():
             out = self._decode_block_jit(h)(
@@ -1770,7 +1803,8 @@ class ServingEngine:
         if self._recorder is not None:
             self._recorder.record("dispatch", family="decode",
                                   rows=len(reqs), horizon=h)
-        with RecordEvent("serving.decode_block"):
+        with RecordEvent("serving.decode_block", rows=live,
+                         rows_dispatched=b, horizon=h):
             out, err = self._guarded_call("dispatch", dispatch)
         if out is None:
             # a decode dispatch implicates the whole batch. Drain the
@@ -1791,6 +1825,8 @@ class ServingEngine:
             self._obs.step_phase["dispatch"].observe(t1 - t0)
             self._obs.decode_steps.inc()
             self._obs.dispatches.inc()
+            self._obs.decode_rows_live.inc(live)
+            self._obs.decode_rows_dispatched.inc(b)
             if self._last_decode_dispatch_t is not None:
                 # dispatch-to-dispatch gap while requests were running:
                 # whatever kept the engine away from decode (a prefill,
@@ -1837,7 +1873,8 @@ class ServingEngine:
                 page_size=self.page_size)
             if tp is not None:
                 fn = tp.wrap_spec_exec(fn)
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(fn, "spec_decode_block"), donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _spec_ragged_jit(self, t_bucket: int):
@@ -1857,7 +1894,8 @@ class ServingEngine:
                 page_size=self.page_size)
             if tp is not None:
                 fn = tp.wrap_spec_ragged_exec(fn)
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(3,))
+            self._jit_cache[key] = jax.jit(
+                scopes.named(fn, "spec_ragged_block"), donate_argnums=(3,))
         return self._jit_cache[key]
 
     def _spec_decode(self, reqs: Sequence[Request]) -> List[Tuple[int, int]]:
@@ -1915,6 +1953,7 @@ class ServingEngine:
         for req in reqs:
             cap = req.max_new_tokens - len(req.generated) - req.inflight
             incr.append(max(min(cap_tokens, cap), 0))
+        live = sum(1 for n in incr if n > 0)
 
         def dispatch():
             out = self._spec_block_jit(h)(
@@ -1946,6 +1985,8 @@ class ServingEngine:
             self._obs.step_phase["dispatch"].observe(t1 - t0)
             self._obs.decode_steps.inc()
             self._obs.dispatches.inc()
+            self._obs.decode_rows_live.inc(live)
+            self._obs.decode_rows_dispatched.inc(b)
             if self._last_decode_dispatch_t is not None:
                 self._obs.decode_stall.observe(
                     max(t0 - self._last_decode_dispatch_t, 0.0))

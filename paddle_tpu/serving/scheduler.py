@@ -152,6 +152,9 @@ class Request:
 
     # metrics (perf_counter timestamps, filled by the engine)
     arrival_t: float = dataclasses.field(default_factory=time.perf_counter)
+    # when the request last went back to the waiting queue (preemption);
+    # queue wait counts from here, from arrival_t while it is None
+    requeue_t: Optional[float] = None
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     # host-visible time of the most recent emitted token (feeds the

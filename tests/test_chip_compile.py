@@ -1,20 +1,27 @@
 """The main path's Pallas kernels compiled at real widths for a described
 (not attached) TPU v5e — the third rehearsal of a chip run, kept as a
-test. A compile that passes is a compile: nothing here runs on a chip.
+test — and the names the device trace is read by (kernels, scopes,
+executables), read from the compiler's own text of the two hot steps
+at tiny widths. A compile that passes is a compile: nothing here runs
+on a chip.
 
 Only one process may hold libtpu, so the topology is described inside a
 fixture of this one file and never at import; under xdist only the
 worker that is handed this file loads the library.
 """
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas_kernels as pk
+from paddle_tpu.profiler import scopes
 from paddle_tpu.serving import attention as paged
 
 # libtpu otherwise spends minutes asking a metadata server that is not
@@ -142,3 +149,208 @@ def test_compiles_for_v5e(topo, case):
         wanted = "tpu_custom_call"
     compiled = jax.jit(fn).lower(*args).compile()
     assert wanted in compiled.as_text()
+
+
+# ------------------------------------------ the names the trace is read by
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def kernel_paths():
+    """Traces here see the CPU and would take the reference paths: steer
+    them onto the kernels, as the chip would."""
+    was = pk._on_tpu
+    pk._on_tpu = lambda: True
+    try:
+        yield
+    finally:
+        pk._on_tpu = was
+
+
+def _custom_calls(text: str) -> set:
+    """Own names, without their numbers, of the program's custom calls:
+    what the trace's `XLA Ops` line calls a kernel's events."""
+    return set(re.findall(r"%([A-Za-z_]+)[.\d]* = [^\n]*custom-call", text))
+
+
+def _scoped(text: str, scope: str) -> list:
+    """The `op_name`s that have `scope` as a path component, bare or
+    inside `jvp(...)` / `transpose(jvp(...))`."""
+    part = re.compile(r"(?:^|/)(?:transpose\()?(?:jvp\()?" + scope
+                      + r"\)*(?:/|$)")
+    return [n for n in re.findall(r'op_name="([^"]*)"', text)
+            if part.search(n)]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """A two-layer GPT behind a default engine, heads of 128 so that the
+    paged kernel's gates open; built on the CPU, nothing is run."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine, SpecConfig
+
+    cfg = GPTConfig(vocab_size=1024, hidden_size=256, num_hidden_layers=2,
+                    num_attention_heads=2, intermediate_size=512,
+                    max_position_embeddings=256)
+    model = GPTForCausalLM(cfg).bfloat16()
+    model.eval()
+    kw = dict(page_size=16, max_batch_size=4, max_seq_len=256,
+              kv_dtype="bf16")
+    return {"plain": ServingEngine(model, **kw),
+            "spec": ServingEngine(model, spec_config=SpecConfig(), **kw)}
+
+
+@pytest.fixture(scope="module")
+def serve_hlo(topo, kernel_paths, engine):
+    """The compiler's text of the decode block and of a bucketed prefill
+    for one described chip."""
+    eng = engine["plain"]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    def knobs(b):
+        return (sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
+                sds((b,), jnp.int32), sds((b,), jnp.float32))
+
+    state = (abstract(eng.params), abstract(eng.buffers))
+    pools, pages = abstract(eng.cache.pools), eng.max_pages_per_seq
+    decode = eng._decode_block_jit(8).lower(
+        *state, sds((4,), jnp.int32), pools, sds((4, pages), jnp.int32),
+        sds((4,), jnp.int32), *knobs(4), sds((4,), jnp.int32),
+        sds((4,), jnp.int32)).compile().as_text()
+    prefill = eng._prefill_jit(128).lower(
+        *state, sds((1, 128), jnp.int32), pools, sds((1, pages), jnp.int32),
+        sds((), jnp.int32), *knobs(1)).compile().as_text()
+    return {"decode_block": decode, "prefill": prefill}
+
+
+@pytest.fixture(scope="module")
+def train_hlo(topo, kernel_paths):
+    """The compiler's text of a one-layer ERNIE's `ZeroTrainStep`
+    (forward, gradient, bf16-over-fp32 Adam) for one described chip:
+    state placed on the CPU, the step built over a mesh of the described
+    device and lowered from shapes."""
+    import paddle_tpu as paddle
+    from jax.sharding import Mesh
+    from paddle_tpu.jit.functional import call_functional, extract_state
+    from paddle_tpu.models import ErnieConfig, ErnieForPretraining
+    from paddle_tpu.parallel import ZeroTrainStep
+    from paddle_tpu.parallel.mesh import DP_AXIS, TP_AXIS
+
+    model = ErnieForPretraining(ErnieConfig(
+        vocab_size=1024, hidden_size=128, num_hidden_layers=1,
+        num_attention_heads=2, intermediate_size=256,
+        max_position_embeddings=128, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0, fused_mlm_loss=True))
+    model.train()
+    _, buffers = extract_state(model)
+    key = jax.random.key(0)
+
+    def loss_fn(params, ids, labels):
+        (loss, _nsp), _ = call_functional(
+            model, params, buffers, (ids, None, None, None, labels),
+            rng_key=key, training=True)
+        return loss.astype(jnp.float32)
+
+    opt = paddle.optimizer.Adam(learning_rate=1e-4,
+                                parameters=model.parameters())
+    step = ZeroTrainStep(model, opt, loss_fn, stage=2, dp=1,
+                         param_dtype="bf16")
+    params, state = step.init_state()
+    step.mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                     (DP_AXIS, TP_AXIS))
+    step._build(2)
+
+    def abstract(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda a, spec: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(step.mesh, spec)),
+            tree, specs)
+
+    def scalar(dtype):
+        return jax.ShapeDtypeStruct((), dtype,
+                                    sharding=NamedSharding(step.mesh, P()))
+
+    batch = (jax.ShapeDtypeStruct(
+        (8, 128), jnp.int32,
+        sharding=NamedSharding(step.mesh, P(DP_AXIS))),) * 2
+    return step._step.lower(
+        abstract(params, {k: step._spec[k] for k in params}),
+        abstract(state, {k: dict(v) for k, v in step._state_spec.items()}),
+        batch, scalar(jnp.float32), scalar(jnp.int32)).compile().as_text()
+
+
+def test_paged_decode_kernel_is_named_where_it_is_created(serve_hlo):
+    calls = _custom_calls(serve_hlo["decode_block"])
+    assert scopes.PAGED_DECODE_KERNEL in calls
+    assert not any("closed_call" in c for c in calls)
+
+
+def test_paged_ragged_kernel_is_named_where_it_is_created(topo):
+    fn, shapes = _ragged_paged()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    text = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes]).compile().as_text()
+    assert scopes.PAGED_RAGGED_KERNEL in _custom_calls(text)
+
+
+def test_flash_keeps_the_names_its_metric_reads(train_hlo):
+    """`flash_roofline.train` matches and counts the flash kernels by
+    the names the compiler derives from the path in front of their
+    `pallas_call`: a scope around the call would rename them."""
+    calls = _custom_calls(train_hlo)
+    assert "jvp_jit__flash_attention_data__" in calls
+    assert "transpose_jvp_jit__flash_attention_data___" in calls
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           "flash_roofline.train.json")) as f:
+        args = json.load(f)["args"]
+    flash = [c for c in calls if args["match"] in c]
+    units = [c for c in flash if args["count"] in c
+             and args["skip"] not in c]
+    assert len(units) == 1 and len(flash) == 2
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill"])
+def test_serve_executables_are_named(serve_hlo, program):
+    assert serve_hlo[program].startswith(f"HloModule jit_{program},")
+
+
+def test_train_executable_is_named(train_hlo):
+    assert train_hlo.startswith("HloModule jit_zero_train_step,")
+
+
+@pytest.mark.parametrize("name,build", [
+    ("prefill", lambda e: e["plain"]._prefill_jit(128)),
+    ("prefill_offset", lambda e: e["plain"]._prefill_offset_jit(128)),
+    ("prefill_chunk", lambda e: e["plain"]._chunked_prefill_jit()),
+    ("decode_block", lambda e: e["plain"]._decode_block_jit(8)),
+    ("ragged_block", lambda e: e["plain"]._ragged_jit(64)),
+    ("spec_decode_block", lambda e: e["spec"]._spec_block_jit(8)),
+    ("spec_ragged_block", lambda e: e["spec"]._spec_ragged_jit(64)),
+])
+def test_every_engine_executable_says_what_it_is(engine, name, build):
+    # a jitted function's module is `jit_<__name__>`
+    assert build(engine).__name__ == name
+
+
+@pytest.mark.parametrize("scope", scopes.SERVE_SCOPES)
+def test_serve_scope_reaches_the_compiled_step(serve_hlo, scope):
+    program = ("prefill" if scope == scopes.PREFILL_ATTENTION
+               else "decode_block")
+    assert _scoped(serve_hlo[program], scope)
+
+
+@pytest.mark.parametrize("scope", scopes.TRAIN_SCOPES)
+def test_train_scope_reaches_the_compiled_step(train_hlo, scope):
+    names = _scoped(train_hlo, scope)
+    assert names
+    if scope not in (scopes.GRAD_REDUCE, scopes.OPTIMIZER_UPDATE):
+        # forward and backward both carry the layer's scope
+        assert any("transpose(jvp(" + scope in n for n in names)

@@ -9,10 +9,18 @@ Two paths, mirroring ops/pallas_kernels.py's selection policy:
 - a pure-jnp reference path (gather pages via the page table, mask by
   per-row length, reuse F.scaled_dot_product_attention) — numerically the
   twin of the static-cache `attend_with_cache`, runs everywhere;
-- a Pallas decode kernel gated on backend: grid (batch, kv_head, page),
-  the page table rides in SMEM via scalar prefetch and the BlockSpec index
-  map gathers one (page_size, head_dim) K/V tile per step straight from
-  the pool (no host-side gather), online-softmax accumulation in VMEM.
+- a Pallas decode kernel gated on backend: grid (batch, block of kv
+  heads); the pools stay in HBM and the page table rides in SMEM via
+  scalar prefetch. Each grid step walks ITS ROW'S OWN pages in a loop of
+  `cdiv(pos + 1, block)` compute blocks of about 128 tokens (none for a
+  parked row): a block's pages come into VMEM by async copies, one
+  strided copy a page for all the heads of the step, double-buffered so
+  the next block is in flight while this one is computed (no host-side
+  gather), online softmax with fp32 maximum, sum and accumulator. Heads
+  a step and pages a block follow the shapes and dtype it is handed
+  (`_decode_tiling`); there is no option. The ragged kernel still has the
+  older grid (token, kv_head, page), one (page_size, head_dim) tile a
+  step.
 
 Both steps stay inside ONE jitted call per decode (T3's single-dispatch
 rule, arxiv 2401.16677): the write, the gather and the softmax never
@@ -33,6 +41,7 @@ run.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -466,51 +475,132 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                         ps, scale, n_pages, quantized=False):
-    """Grid (batch, kv_head, page): one (page_size, head_dim) K/V tile per
-    step, gathered by the BlockSpec index map from the scalar-prefetched
-    page table; online softmax in fp32 VMEM scratch (flash structure).
-    Pages wholly past the row's position are skipped splash-style.
+# tokens of K/V one compute block of the decode kernel holds per head:
+# large enough that a block's matmuls and its handful of copies outweigh
+# the loop's fixed cost, small enough that a row's last, partly masked
+# block wastes little (on the v5e, 64, 128 and 256 read within 8% of each
+# other at 16 heads a step, 128 best; PERF.md section 6, PR 28)
+_DECODE_BLOCK_TOKENS = 128
+# VMEM the decode kernel's two-slot K/V buffers (and a quantized pool's
+# scale rows) may take, well inside the 16 MiB a v5e kernel gets by default
+_DECODE_VMEM_BYTES = 4 * 1024 * 1024
 
-    Quantized pools add two (page_size, 1) fp32 scale tiles gathered by
-    the same index map; K/V tiles dequantize in-register (one cast + one
-    lane-broadcast multiply per tile) before the unchanged flash loop —
-    the unquantized trace is byte-identical to before."""
+
+def _decode_tiling(kvh: int, ps: int, d_p: int, max_pages: int,
+                   itemsize: int, quantized: bool) -> tuple:
+    """(kv heads per grid step, pages per compute block) of the decode
+    kernel, from the shapes it is handed: a block of about
+    `_DECODE_BLOCK_TOKENS` tokens whose columns fill whole 128-lane
+    tiles, and the most heads (a divisor of `kvh`, so 1 always does)
+    whose double-buffered K and V blocks fit `_DECODE_VMEM_BYTES`. One
+    strided copy brings a page for all the heads of a step, so more
+    heads a step means fewer, larger copies."""
+    lane = 128 // math.gcd(ps, 128)
+    ppb = max(lane, _DECODE_BLOCK_TOKENS // ps // lane * lane)
+    ppb = min(ppb, _round_up(max_pages, lane))
+    per_head = 2 * 2 * ppb * ps * d_p * itemsize
+    if quantized:
+        # K and V scale rows of the whole table, fp32, one sublane of
+        # eight used, double-buffered by the pipeline
+        per_head += 2 * 2 * 8 * _round_up(max_pages, ppb) * ps * 4
+    hb = max(h for h in range(1, kvh + 1)
+             if kvh % h == 0 and (h == 1
+                                  or h * per_head <= _DECODE_VMEM_BYTES))
+    return hb, ppb
+
+
+def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+                         ps, ppb, hb, scale, n_pages, quantized=False):
+    """Grid (batch, block of kv heads). The pools stay in HBM; the row's
+    own pages are walked by a loop of `cdiv(pos + 1, ppb * ps)` compute
+    blocks (0 for a row parked at the table's capacity, `n_pages * ps`).
+    A block's `ppb` pages are brought into one of two VMEM slots by async
+    copies addressed from the scalar-prefetched page table, one strided
+    copy a page for all `hb` heads, and block i + 1 is in flight while
+    block i is computed: online softmax with fp32 running maximum, sum
+    and accumulator (flash structure), batched over the heads of the step.
+
+    Quantized pools: Mosaic cannot slice an HBM ref whose minor dimension
+    is 1, so the (ps, 1) scale slabs cannot be copied page by page; the
+    row's scales arrive gathered, a (hb, 1, L) fp32 block with the tokens
+    on the lanes, and each tile is dequantized against its slab where the
+    tokens are on the lanes too: K's scale multiplies the score columns,
+    V's the probabilities, both in fp32 (q . (k * ks) == (q . k) * ks)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = rest
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, k_buf, v_buf, sems = rest
 
     b_ = pl.program_id(0)
-    pi = pl.program_id(2)
+    h0 = pl.program_id(1) * hb
     pos = pos_ref[b_]
+    bk = ppb * ps
+    g_p, d_p = q_ref.shape[2], q_ref.shape[3]
+    # a parked row (finished, or batch padding) sits AT the capacity and
+    # walks nothing: nobody reads its output. Whole blocks need no mask;
+    # the row's last block does unless the row ends exactly on its edge
+    length = jnp.where(pos < n_pages * ps, pos + 1, 0)
+    n_blocks = (length + bk - 1) // bk
+    n_full = length // bk
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def block_copies(i, slot):
+        """The copies of compute block `i` into `slot`. Waiting needs a
+        descriptor's shapes and semaphore only, so `i=None` (the waits)
+        addresses page 0 and reads no table entry."""
+        out = []
+        for j in range(ppb):
+            page = 0 if i is None else pt_ref[b_, i * ppb + j]
+            for n, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                out.append(pltpu.make_async_copy(
+                    hbm.at[pl.ds(h0, hb), page], buf.at[slot, :, j],
+                    sems.at[n, slot]))
+        return out
 
-    def _compute():
+    def start(i, slot):
+        for c in block_copies(i, slot):
+            c.start()
+
+    def block(i, carry, last):
+        """One compute block of the flash update. Only a row's `last`,
+        partly filled block has columns past `pos`: there the scores are
+        masked and the K/V behind them (the page's stale slots, null
+        pages, whatever they hold: parked rows leave NaN in the null
+        page) is kept out of the sums."""
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        if not last:
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start(i + 1, 1 - slot)
+
+        for c in block_copies(None, slot):
+            c.wait()
+        qblk = q_ref[0]
+        kblk = k_buf[slot].reshape(hb, bk, d_p)
+        vblk = v_buf[slot].reshape(hb, bk, d_p)
+        if last:
+            rows = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk, 1), 1)
+            vblk = jnp.where(rows <= pos, vblk, jnp.zeros_like(vblk))
         if quantized:
-            qblk = q_ref[0, 0].astype(jnp.float32)
-            kblk = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-        else:
-            qblk = q_ref[0, 0]
-            kblk = k_ref[0, 0]
-        # (G, ps) scores: the q group rides the MXU in the input dtype
+            qblk = qblk.astype(jnp.float32)
+            kblk = kblk.astype(jnp.float32)
+            vblk = vblk.astype(jnp.float32)
+        # (hb, G, bk) scores: the q groups ride the MXU in the input dtype
         s = jax.lax.dot_general(
-            qblk, kblk, (((1,), (1,)), ((), ())),
+            qblk, kblk, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.DEFAULT) * scale
-        cols = pi * ps + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= pos, s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        if quantized:
+            col0 = pl.multiple_of(i * bk, 128)
+            s = s * ks_ref[0, :, :, pl.ds(col0, bk)]
+        if last:
+            cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+            s = jnp.where(cols <= pos, s, -jnp.inf)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         # no jnp.isfinite (its primitive has no Mosaic lowering on some
         # jax versions): m_safe only needs the all-masked guard, and
         # exp(-inf - finite) is already an exact 0 for masked columns
@@ -518,24 +608,35 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         m_safe = jnp.where(m_cur == -jnp.inf, 0.0, m_cur)
         p = jnp.exp(s - m_safe)
         alpha = jnp.exp(m_prev - m_safe)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_cur
-        vblk = v_ref[0, 0]
+        l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
         if quantized:
-            vblk = vblk.astype(jnp.float32) * vs_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
+            vs = vs_ref[0, :, :, pl.ds(col0, bk)]
+            p = p * (jnp.where(cols <= pos, vs, 0.0) if last else vs)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(vblk.dtype), vblk, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.DEFAULT)
+        return m_cur, l_cur, acc
 
-    pl.when(pi * ps <= pos)(_compute)
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
 
-    @pl.when(pi == n_pages - 1)
-    def _done():
-        l_fin = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_fin).astype(o_ref.dtype)
+    carry = jax.lax.fori_loop(
+        0, n_full, functools.partial(block, last=False),
+        (jnp.full((hb, g_p, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((hb, g_p, 1), jnp.float32),
+         jnp.zeros((hb, g_p, d_p), jnp.float32)))
+    _, l_fin, acc = jax.lax.cond(
+        n_full < n_blocks,
+        lambda c: block(n_full, c, last=True), lambda c: c, carry)
+    o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
+# jitted so that a step with many layers traces the kernel and lowers it
+# to Mosaic once, not once a layer: the layers' calls share one function
+# of the module (a decode executable's set-up time, PERF.md section 6)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
                          k_scale=None, v_scale=None, interpret=False):
     """q: (b, 1, heads, hd); pools: (kvh, P, ps, hd); page_table: (b,
@@ -559,39 +660,51 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - rep), (0, d_p - hd)))
     kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
     vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
+    hb, ppb = _decode_tiling(kvh, ps, d_p, max_pages, kp.dtype.itemsize,
+                             quantized)
+    # whole blocks: the last may reach past the table where ppb does not
+    # divide it, through null pages whose columns are masked
+    table = jnp.pad(page_table.astype(jnp.int32),
+                    ((0, 0), (0, _round_up(max_pages, ppb) - max_pages)),
+                    constant_values=NULL_PAGE)
 
-    q_spec = pl.BlockSpec((1, 1, g_p, d_p),
-                          lambda b_, h_, pi, pt, ps_: (b_, h_, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, ps, d_p),
-                           lambda b_, h_, pi, pt, ps_: (h_, pt[b_, pi],
-                                                        0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
+    q_spec = pl.BlockSpec((1, hb, g_p, d_p),
+                          lambda b_, h_, pt, ps_: (b_, h_, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, in_hbm, in_hbm]
     operands = [qg, kp, vp]
     if quantized:
-        sc_spec = pl.BlockSpec((1, 1, ps, 1),
-                               lambda b_, h_, pi, pt, ps_: (h_, pt[b_, pi],
-                                                            0, 0))
+        length = table.shape[1] * ps
+
+        def row_scales(pool):
+            # (kvh, P, ps, 1) -> the rows' own scales, tokens on the lanes
+            g = pool[..., 0][:, table]                   # (kvh, b, maxP, ps)
+            return jnp.transpose(g, (1, 0, 2, 3)).reshape(b, kvh, 1, length)
+
+        sc_spec = pl.BlockSpec((1, hb, 1, length),
+                               lambda b_, h_, pt, ps_: (b_, h_, 0, 0))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [row_scales(k_scale), row_scales(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, max_pages),
+        grid=(b, kvh // hb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((g_p, d_p), jnp.float32),
-            pltpu.VMEM((g_p, 1), jnp.float32),
-            pltpu.VMEM((g_p, 1), jnp.float32),
+            pltpu.VMEM((2, hb, ppb, ps, d_p), kp.dtype),
+            pltpu.VMEM((2, hb, ppb, ps, d_p), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K or V, slot)
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, ps=ps, scale=scale,
-                          n_pages=max_pages, quantized=quantized),
+        functools.partial(_paged_decode_kernel, ps=ps, ppb=ppb, hb=hb,
+                          scale=scale, n_pages=max_pages,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g_p, d_p), q.dtype),
         interpret=interpret,
         name=scopes.PAGED_DECODE_KERNEL,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
+    )(table, pos.astype(jnp.int32), *operands)
     return out[:, :, :rep, :hd].reshape(b, 1, heads, hd)
 
 
@@ -599,14 +712,17 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
 
 def _ragged_attend_kernel(pt_ref, pos_ref, row_ref, q_ref, k_ref, v_ref,
                           *rest, ps, scale, n_pages, quantized=False):
-    """Grid (token, kv_head, page): the decode kernel's flash loop with the
-    batch axis replaced by a flat TOKEN axis — the BlockSpec index map
-    gathers page `pi` of token t's OWN page-table row (row_ref, scalar-
-    prefetched alongside the table). Pages wholly past the token's
-    position are skipped splash-style, and tokens parked at the table
-    capacity (flat-batch padding) skip every page and emit zeros.
-    Quantized pools dequantize each K/V tile in-register against the
-    (page_size, 1) scale tiles, as in the decode kernel."""
+    """Grid (token, kv_head, page) over a flat TOKEN axis: one (page_size,
+    head_dim) K/V tile a grid step, the BlockSpec index map gathering
+    page `pi` of token t's OWN page-table row (row_ref, scalar-prefetched
+    alongside the table); online softmax in fp32 VMEM scratch (flash
+    structure). Pages wholly past the token's position are skipped
+    splash-style, and tokens parked at the table capacity (flat-batch
+    padding) skip every page and emit zeros. Quantized pools dequantize
+    each K/V tile in-register against its (page_size, 1) scale tile.
+    The page axis of the grid is the table's capacity, not the token's
+    length: the grid the decode kernel had until it walked a row's own
+    pages in blocks (PERF.md section 7)."""
     from jax.experimental import pallas as pl
 
     if quantized:

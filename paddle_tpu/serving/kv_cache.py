@@ -232,9 +232,10 @@ class PagedLayerCache:
     attention modules ride the paged path unmodified.
 
     k_pool/v_pool: (kv_heads, num_pages, page_size, head_dim) — kv-head
-                   major so the Pallas decode kernel's BlockSpec can gather
-                   one (page_size, head_dim) tile per grid step without a
-                   per-step pool transpose
+                   major so the Pallas kernels reach a page's (page_size,
+                   head_dim) tile, for one head or a block of heads, by
+                   one copy straight from the pool, without a per-step
+                   pool transpose
     page_table:    (B, max_pages) int32 — logical page j of row i lives in
                    physical page page_table[i, j] (0 = null page padding)
     row_ids:       optional (T,) int32 — ragged flat-batch mode: the step

@@ -9,6 +9,7 @@ Only one process may hold libtpu, so the topology is described inside a
 fixture of this one file and never at import; under xdist only the
 worker that is handed this file loads the library.
 """
+import functools
 import json
 import os
 import re
@@ -70,10 +71,21 @@ _POOL = ((16, 1024, 16, 128), jnp.bfloat16)
 _TABLE = ((8, 128), jnp.int32)
 
 
-def _paged_decode():
-    args = [((8, 1, 16, 128), jnp.bfloat16), _POOL, _POOL, _TABLE,
-            ((8,), jnp.int32)]
-    return paged._paged_decode_pallas, args
+def _paged_decode(rows=16, heads=16, pool=((16, 2049, 16, 128), jnp.bfloat16),
+                  pages=128, scales=False):
+    """The serve cell's own decode call: 16 kv heads of 128, pages of 16
+    tokens, 128 pages a row, the engine's pool of 2,049 pages, bf16; one
+    executable a power-of-two row count."""
+    args = [((rows, 1, heads, 128), jnp.bfloat16), pool, pool,
+            ((rows, pages), jnp.int32), ((rows,), jnp.int32)]
+    if not scales:
+        return paged._paged_decode_pallas, args
+
+    def fn(q, k, v, table, pos, k_scale, v_scale):
+        return paged._paged_decode_pallas(q, k, v, table, pos,
+                                          k_scale=k_scale, v_scale=v_scale)
+
+    return fn, args + [(pool[0][:3] + (1,), jnp.float32)] * 2
 
 
 def _ragged_paged():
@@ -120,6 +132,16 @@ def _layer_norm_decode():
 
 _ONE_CHIP = {
     "paged_decode": _paged_decode,
+    **{f"paged_decode_rows{n}": functools.partial(_paged_decode, rows=n)
+       for n in (1, 2, 4, 8)},
+    # grouped-query: 32 q heads on 8 kv heads
+    "paged_decode_gqa": functools.partial(
+        _paged_decode, rows=8, heads=32,
+        pool=((8, 1024, 16, 128), jnp.bfloat16)),
+    # int8 pages of 32 tokens with their fp32 scale slabs
+    "paged_decode_int8": functools.partial(
+        _paged_decode, rows=8, pool=((16, 512, 32, 128), jnp.int8),
+        pages=64, scales=True),
     "ragged_paged": _ragged_paged,
     "flash_causal": _flash_causal,
     "flash_train_dropout": _flash_train_dropout,
@@ -290,6 +312,20 @@ def test_paged_decode_kernel_is_named_where_it_is_created(serve_hlo):
     calls = _custom_calls(serve_hlo["decode_block"])
     assert scopes.PAGED_DECODE_KERNEL in calls
     assert not any("closed_call" in c for c in calls)
+
+
+def test_every_kernel_under_paged_attention_is_a_paged_decode(serve_hlo):
+    """`paged_decode_kernel_roofline.serve` sums the events whose name
+    holds `paged_decode`: a kernel of the decode step's paged attention
+    under another name (a second call, a combine step) would be work the
+    roofline leaves out."""
+    calls = re.findall(r'%([\w.]+) = [^\n]*custom-call\([^\n]*'
+                       r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', serve_hlo["decode_block"])
+    inside = [name for name, op_name in calls
+              if _scoped(f'op_name="{op_name}"', scopes.PAGED_ATTENTION)]
+    assert inside
+    assert all(scopes.PAGED_DECODE_KERNEL in name for name in inside), inside
 
 
 def test_paged_ragged_kernel_is_named_where_it_is_created(topo):

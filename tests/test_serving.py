@@ -565,6 +565,110 @@ class TestPagedAttentionParity:
                                         interpret=True)
         np.testing.assert_allclose(np.asarray(out), ref.numpy(), atol=1e-5)
 
+    @staticmethod
+    def _decode_case(rng, *, rep, hd, ps, dtype, max_pages, pos, kvh=2,
+                     int8=False):
+        """Pools, a page table with repeated and out-of-order pages (12
+        real pages for a longer table), q and the jnp reference's output
+        for a decode step at positions `pos`."""
+        heads, P, b = kvh * rep, 13, len(pos)
+        shape = (kvh, P, ps, hd)
+        scales = {}
+        if int8:
+            kp, vp = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                      for _ in range(2))
+            scales = {n: jnp.asarray(rng.uniform(0.005, 0.02, shape[:3]
+                                                 + (1,)), jnp.float32)
+                      for n in ("k_scale", "v_scale")}
+        else:
+            kp, vp = (jnp.asarray(rng.standard_normal(shape), dtype)
+                      for _ in range(2))
+        pt = jnp.asarray(rng.integers(1, P, (b, max_pages)), jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
+        q = jnp.asarray(rng.standard_normal((b, 1, heads, hd)), dtype)
+        cache = PagedLayerCache(kp, vp, pt, **scales)
+        ref = satt._paged_decode_reference(Tensor(q), cache, pos, rep)
+        return q, cache, pos, np.asarray(ref._data.astype(jnp.float32))
+
+    @staticmethod
+    def _decode_kernel(q, cache, pos):
+        out = satt._paged_decode_pallas(
+            q, cache.k_pool, cache.v_pool, cache.page_table, pos,
+            k_scale=cache.k_scale, v_scale=cache.v_scale, interpret=True)
+        return np.asarray(out.astype(jnp.float32))
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("ps", [8, 16])
+    @pytest.mark.parametrize("hd", [32, 64, 128])
+    @pytest.mark.parametrize("rep", [1, 4])
+    def test_decode_kernel_walks_blocks_like_the_reference(self, rng, rep,
+                                                           hd, ps, dtype):
+        """The block-walking decode kernel against the jnp gather: a
+        table of 20 pages that no block of 128 tokens divides, rows at
+        position 0 (one page), one under and on a page edge, one under
+        and on a block edge, a page past it, and in the table's last slot
+        (the full table), pages repeated and out of order."""
+        max_pages, bk = 20, 128
+        pos = [0, ps - 1, ps, bk - 1, bk, bk + ps, max_pages * ps - 1]
+        assert satt._decode_tiling(2, ps, 128, max_pages, 4, False)[1] \
+            * ps == bk and (max_pages * ps) % bk
+        q, cache, pos, ref = self._decode_case(
+            rng, rep=rep, hd=hd, ps=ps, dtype=jnp.dtype(dtype),
+            max_pages=max_pages, pos=pos)
+        np.testing.assert_allclose(
+            self._decode_kernel(q, cache, pos), ref,
+            atol=1e-5 if dtype == "float32" else 2e-2)
+
+    @pytest.mark.parametrize("rep", [1, 4])
+    def test_decode_kernel_dequantizes_int8_pages(self, rng, rep):
+        """int8 pages of 32 tokens with their fp32 scale slabs."""
+        ps, max_pages = 32, 6
+        pos = [0, ps - 1, ps, 4 * ps - 1, 4 * ps, max_pages * ps - 1]
+        q, cache, pos, ref = self._decode_case(
+            rng, rep=rep, hd=64, ps=ps, dtype=jnp.float32,
+            max_pages=max_pages, pos=pos, int8=True)
+        np.testing.assert_allclose(self._decode_kernel(q, cache, pos), ref,
+                                   atol=1e-4)
+
+    def test_decode_kernel_parked_rows_and_stale_pages(self, rng):
+        """Rows parked at the table's capacity walk nothing and yield
+        finite values; whatever sits behind a live row's position (NaN
+        in the null page, where parked rows write, and in its last
+        page's stale slots) stays out of its output."""
+        ps, max_pages = 16, 12
+        park = max_pages * ps
+        pos = np.asarray([park, 5, park, 130, ps * 3 - 1, park])
+        q, cache, pos_d, ref = self._decode_case(
+            rng, rep=1, hd=32, ps=ps, dtype=jnp.float32,
+            max_pages=max_pages, pos=pos)
+        pt = np.asarray(cache.page_table).copy()
+        kp, vp = (np.asarray(x).copy() for x in (cache.k_pool, cache.v_pool))
+        for row in np.flatnonzero(pos < park):
+            # the row's own pages, in order, then null pages; NaN in
+            # every slot past its position
+            n = pos[row] // ps + 1
+            pt[row, :n] = 1 + (np.arange(n) + 3 * row) % 12
+            pt[row, n:] = NULL_PAGE
+        for pool in (kp, vp):
+            pool[:, NULL_PAGE] = np.nan
+        dirty = {"k": kp.copy(), "v": vp.copy()}
+        for row in np.flatnonzero(pos < park):
+            last = pt[row, pos[row] // ps]
+            if (pt[pos < park] == last).sum() == 1:     # nobody else's
+                for pool in dirty.values():
+                    pool[:, last, pos[row] % ps + 1:] = np.nan
+        clean = PagedLayerCache(jnp.asarray(np.nan_to_num(kp)),
+                                jnp.asarray(np.nan_to_num(vp)),
+                                jnp.asarray(pt))
+        ref = satt._paged_decode_reference(Tensor(q), clean, pos_d, 1)
+        out = self._decode_kernel(
+            q, PagedLayerCache(jnp.asarray(dirty["k"]),
+                               jnp.asarray(dirty["v"]), jnp.asarray(pt)),
+            pos_d)
+        assert np.isfinite(out).all()
+        live = pos < park
+        np.testing.assert_allclose(out[live], ref.numpy()[live], atol=1e-5)
+
     def test_kernel_shape_gates(self):
         assert satt.paged_decode_available(16, 128)
         assert not satt.paged_decode_available(7, 128)   # ragged sublanes

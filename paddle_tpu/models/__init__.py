@@ -15,3 +15,17 @@ from .llama import (  # noqa: F401
 from .t5 import (  # noqa: F401
     T5Config, T5ForConditionalGeneration, T5Model,
 )
+
+
+_LAZY = {"MlaMoeConfig": "mla_moe", "MlaMoeModel": "mla_moe",
+         "MlaMoeForCausalLM": "mla_moe"}
+
+
+def __getattr__(name):
+    # imported when first asked for: a process that serves another model
+    # does not pay for this one
+    if name in _LAZY:
+        import importlib
+        return getattr(importlib.import_module(
+            f"{__name__}.{_LAZY[name]}"), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

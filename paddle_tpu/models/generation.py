@@ -71,6 +71,11 @@ def attend_with_cache(q, k, v, cache, start_pos, rep, bias=None):
 def init_caches(model, batch, max_len, dtype=jnp.float32):
     """Zeroed (k, v) cache pair per decoder layer, sized from the config."""
     cfg = _config_of(model)
+    if getattr(cfg, "latent_cache_dim", None) is not None:
+        raise NotImplementedError(
+            f"{type(model).__name__} caches a latent row a token, which "
+            "the static (k, v) cache of models.generation cannot hold: "
+            "serve it through paddle_tpu.serving.ServingEngine")
     kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     head_dim = cfg.hidden_size // cfg.num_attention_heads
     shape = (batch, max_len, kv_heads, head_dim)

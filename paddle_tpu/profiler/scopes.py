@@ -31,6 +31,21 @@ OPTIMIZER_UPDATE = "optimizer_update"
 
 SERVE_SCOPES = (EMBED, ATTN_QKV, KV_WRITE, PAGED_ATTENTION,
                 PREFILL_ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLING)
+
+# sub-scopes, nested inside a serving scope: models/mla_moe.py. Under
+# `mlp`, the parts of a dropless expert layer: the router (float32
+# scores, top-k, weights), the dispatch (sort of the (token, expert)
+# pairs, group sizes, gather), the grouped matmuls, the weighted combine
+# and the shared expert. Under `paged_attention`, the absorption of a
+# latent-attention decode query into the cached latent's space
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+MOE_SHARED = "moe_shared"
+MLA_ABSORB = "mla_absorb"
+SERVE_SUBSCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
+                   MOE_SHARED, MLA_ABSORB)
 TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
                 OPTIMIZER_UPDATE)
 
@@ -38,6 +53,8 @@ TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
 # `%paged_decode.N` / `%paged_ragged.N` whatever encloses the call
 PAGED_DECODE_KERNEL = "paged_decode"
 PAGED_RAGGED_KERNEL = "paged_ragged"
+# the absorbed-form decode kernel over a latent pool, `%mla_decode.N`
+MLA_DECODE_KERNEL = "mla_decode"
 
 # jitted executables: the trace's `XLA Modules` line reads
 # `jit_<name>`; a family is its first word

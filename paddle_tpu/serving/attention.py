@@ -48,11 +48,14 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..profiler import scopes
-from .kv_cache import NULL_PAGE, PagedLayerCache, overflow_position
+from .kv_cache import (NULL_PAGE, LatentLayerCache, PagedLayerCache,
+                       overflow_position)
 
 __all__ = ["paged_attend", "paged_decode_attention",
            "paged_decode_available", "ragged_paged_attention",
-           "ragged_attention_available", "advance_positions", "KERNEL_MODE"]
+           "ragged_attention_available", "advance_positions", "KERNEL_MODE",
+           "latent_write", "latent_prefill_attention",
+           "latent_decode_attention"]
 
 # "auto": Pallas kernel on TPU, jnp reference elsewhere; "off": always the
 # reference; "interpret": run the Pallas kernel in interpret mode (hermetic
@@ -132,6 +135,30 @@ def _write_pages(pool, vals, entries, slots):
     return pool.at[:, entries, slots].set(flat)
 
 
+def _write_targets(page_table, pos, ps: int, row_ids=None):
+    """(entries, slots), each (b, s): the physical page and the slot in
+    it that the token at global position `pos[i, j]` of page-table row i
+    (or, flat ragged batch, of row `row_ids[j]`) is written to."""
+    max_pages = page_table.shape[1]
+    page_idx = pos // ps
+    if row_ids is not None:
+        # flat ragged batch (b == 1, s == T): token t writes through the
+        # page table ROW it belongs to, not batch row 0
+        pt_rows = page_table[row_ids]                    # (T, maxP)
+        entries = jnp.take_along_axis(
+            pt_rows, jnp.clip(page_idx[0], 0, max_pages - 1)[:, None],
+            axis=1)[:, 0][None]                          # (1, T)
+    else:
+        entries = jnp.take_along_axis(
+            page_table, jnp.clip(page_idx, 0, max_pages - 1), axis=1)
+    # padding rows whose position overflows the table (suffix prefill:
+    # offset + bucket may exceed max_pages * page_size) must land in the
+    # null page — clipping the index instead would alias them onto the
+    # sequence's REAL last page and corrupt it
+    entries = jnp.where(page_idx >= max_pages, NULL_PAGE, entries)
+    return entries, pos % ps
+
+
 def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
                  bias=None):
     """The paged twin of `attend_with_cache`: write K/V into the pool,
@@ -147,7 +174,6 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
     page_table = cache.page_table
     ps = cache.page_size
     b, s = q.shape[0], q.shape[1]
-    max_pages = page_table.shape[1]
 
     with jax.named_scope(scopes.KV_WRITE):
         kd_raw = k._data if hasattr(k, "_data") else k
@@ -165,23 +191,7 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
             kd = kd_raw.astype(kp.dtype)
             vd = vd_raw.astype(vp.dtype)
         pos = _positions(start_pos, b, s)                # (b, s)
-        page_idx = pos // ps
-        if cache.row_ids is not None:
-            # flat ragged batch (b == 1, s == T): token t writes through the
-            # page table ROW it belongs to, not batch row 0
-            pt_rows = page_table[cache.row_ids]          # (T, maxP)
-            entries = jnp.take_along_axis(
-                pt_rows, jnp.clip(page_idx[0], 0, max_pages - 1)[:, None],
-                axis=1)[:, 0][None]                      # (1, T)
-        else:
-            entries = jnp.take_along_axis(
-                page_table, jnp.clip(page_idx, 0, max_pages - 1), axis=1)
-        # padding rows whose position overflows the table (suffix prefill:
-        # offset + bucket may exceed max_pages * page_size) must land in the
-        # null page — clipping the index instead would alias them onto the
-        # sequence's REAL last page and corrupt it
-        entries = jnp.where(page_idx >= max_pages, NULL_PAGE, entries)
-        slots = pos % ps
+        entries, slots = _write_targets(page_table, pos, ps, cache.row_ids)
         kp = _write_pages(kp, kd.reshape(b * s, *kd.shape[2:]),
                           entries.reshape(-1), slots.reshape(-1))
         vp = _write_pages(vp, vd.reshape(b * s, *vd.shape[2:]),
@@ -706,6 +716,230 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
         name=scopes.PAGED_DECODE_KERNEL,
     )(table, pos.astype(jnp.int32), *operands)
     return out[:, :, :rep, :hd].reshape(b, 1, heads, hd)
+
+
+# ------------------------------------------------------------ latent pools
+#
+# The twin of `paged_attend` for a model with latent attention (MLA,
+# models/mla_moe.py), in three pieces because the model does work
+# between them: it writes one row a token ([normed latent; rotated rope
+# key], `latent_write`), prefills over the step's own expanded K/V
+# (`latent_prefill_attention`), and decodes in the absorbed form, the
+# scores taken against the cached rows themselves and the latent summed
+# under the softmax (`latent_decode_attention`, the `mla_decode` kernel).
+
+def latent_write(rows, cache: LatentLayerCache, start_pos):
+    """Write (b, s, width) rows into the latent pool at each token's own
+    position, zero-padded to the pool's whole tiles. Returns (new cache
+    view, (b, s) positions)."""
+    b, s, width = rows.shape
+    with jax.named_scope(scopes.KV_WRITE):
+        pos = _positions(start_pos, b, s)
+        entries, slots = _write_targets(cache.page_table, pos,
+                                        cache.page_size)
+        rows = jnp.pad(rows.reshape(b * s, width).astype(cache.pool.dtype),
+                       ((0, 0), (0, cache.pool.shape[-1] - width)))
+        pool = cache.pool.at[entries.reshape(-1), slots.reshape(-1)].set(
+            rows)
+    return LatentLayerCache(pool, cache.page_table), pos
+
+
+def latent_prefill_attention(q, k, v, scale: float):
+    """Exact causal attention of a prefill that starts at position 0 over
+    the step's own expanded K/V. q, k: (b, s, heads, dk); v: (b, s, heads,
+    dv) with dv <= dk. The flash helper takes one width, so V rides padded
+    to dk and the output is cut back; `scale` must be dk ** -0.5, which is
+    what the helper applies."""
+    from ..nn import functional as F
+
+    dk, dv = q.shape[-1], v.shape[-1]
+    if abs(scale * math.sqrt(dk) - 1.0) > 1e-6:
+        raise ValueError("the flash helper scales by head width ** -0.5")
+    with jax.named_scope(scopes.PREFILL_ATTENTION):
+        _count_dispatch("mla_prefill")
+        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dk - dv)))
+        ctx = F.scaled_dot_product_attention(
+            Tensor(q), Tensor(k), Tensor(vp), is_causal=True)
+        return ctx._data[..., :dv]
+
+
+def latent_decode_attention(q, cache: LatentLayerCache, pos, scale: float,
+                            latent: int):
+    """One query a row over the row's cached latent rows, absorbed form.
+
+    q: (b, heads, width), a head being [q_nope absorbed into the latent's
+    space (`latent` wide); rotated q_rope]; pos: (b,) int32, each row's
+    token position. Scores are q . row over the whole width, the output
+    the softmax-weighted sum of the rows' first `latent` columns:
+    (b, heads, latent), for the model to take through W_uv."""
+    with jax.named_scope(scopes.PAGED_ATTENTION):
+        ps = cache.page_size
+        use_kernel = (KERNEL_MODE != "off" and ps % 8 == 0
+                      and (KERNEL_MODE == "interpret" or _on_tpu()))
+        if use_kernel:
+            _count_dispatch("mla_decode_pallas_interpret"
+                            if KERNEL_MODE == "interpret"
+                            else "mla_decode_pallas")
+            return _mla_decode_pallas(
+                q, cache.pool, cache.page_table, pos, scale=float(scale),
+                latent=latent, interpret=KERNEL_MODE == "interpret")
+        _count_dispatch("mla_decode_reference")
+        return _mla_decode_reference(q, cache, pos, scale, latent)
+
+
+def _mla_decode_reference(q, cache, pos, scale, latent):
+    """Gather each row's pages into a contiguous (b, L, width) view and
+    take the softmax in float32: the kernel's test reference and the
+    `KERNEL_MODE="off"` path. Columns past a row's position are masked
+    and their rows kept out of the sum, whatever they hold."""
+    pool, page_table = cache.pool, cache.page_table
+    b, length = page_table.shape[0], page_table.shape[1] * cache.page_size
+    rows = pool[page_table].reshape(
+        b, length, pool.shape[-1])[..., :q.shape[-1]]
+    allowed = jnp.arange(length, dtype=jnp.int32)[None, :] <= pos[:, None]
+    rows = jnp.where(allowed[..., None], rows, jnp.zeros_like(rows))
+    s = jnp.einsum("bhw,blw->bhl", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    # -1e30, not -inf: a parked row sees no column, and its output, which
+    # nobody reads, stays finite (a mean of zeroed rows)
+    p = jax.nn.softmax(jnp.where(allowed[:, None, :], s, -1e30), -1)
+    return jnp.einsum("bhl,blc->bhc", p.astype(rows.dtype),
+                      rows[..., :latent],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+# tokens of cached rows one compute block of the latent decode kernel
+# holds: a row is 1,280 B where a K/V token of 16 heads was 8 KB, and the
+# rows run to thousands of tokens, so a block is larger than the K/V
+# kernel's: 64 pages of 16 by one copy each, 1.3 MB a slot (on the v5e,
+# 32 rows of 2,100-16,800 tokens, whose bytes need 326 us: blocks of 256
+# tokens 825 us, 512 655, 1,024 602; PERF.md section 6, PR 29)
+_MLA_BLOCK_TOKENS = 1024
+
+
+def _mla_decode_kernel(pt_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sems,
+                       *, ps, ppb, scale, n_pages, latent):
+    """Grid (batch,): built as `_paged_decode_kernel` is. The pool stays
+    in HBM; a row's own pages are walked by a loop of `cdiv(pos + 1, ppb *
+    ps)` compute blocks (0 for a parked row), a block's `ppb` pages
+    brought into one of two VMEM slots by one copy each, block i + 1 in
+    flight while block i is computed. All heads share the one cached row
+    a token, so they ride the MXU together: scores (heads, block) =
+    q . rows over the whole width, then the online softmax's sum of the
+    rows' first `latent` columns, fp32 maximum, sum and accumulator."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b_ = pl.program_id(0)
+    pos = pos_ref[b_]
+    bk = ppb * ps
+    g_p, width = q_ref.shape[1], q_ref.shape[2]
+    length = jnp.where(pos < n_pages * ps, pos + 1, 0)
+    n_blocks = (length + bk - 1) // bk
+    n_full = length // bk
+
+    def block_copies(i, slot):
+        """The copies of block `i` into `slot`; `i=None` (the waits) needs
+        shapes and semaphore only and reads no table entry."""
+        return [pltpu.make_async_copy(
+            pool_hbm.at[0 if i is None else pt_ref[b_, i * ppb + j]],
+            buf.at[slot, j], sems.at[slot]) for j in range(ppb)]
+
+    def start(i, slot):
+        for c in block_copies(i, slot):
+            c.start()
+
+    def block(i, carry, last):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        if not last:
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start(i + 1, 1 - slot)
+
+        for c in block_copies(None, slot):
+            c.wait()
+        rows = buf[slot].reshape(bk, width)
+        if last:
+            # past the row's position: stale slots and null pages, kept
+            # out of scores and sums whatever they hold
+            at = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            rows = jnp.where(at <= pos, rows, jnp.zeros_like(rows))
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT) * scale
+        if last:
+            cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            s = jnp.where(cols <= pos, s, -jnp.inf)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = jnp.where(m_cur == -jnp.inf, 0.0, m_cur)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
+        l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :latent],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
+        return m_cur, l_cur, acc
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    carry = jax.lax.fori_loop(
+        0, n_full, functools.partial(block, last=False),
+        (jnp.full((g_p, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((g_p, 1), jnp.float32),
+         jnp.zeros((g_p, latent), jnp.float32)))
+    _, l_fin, acc = jax.lax.cond(
+        n_full < n_blocks,
+        lambda c: block(n_full, c, last=True), lambda c: c, carry)
+    o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+
+
+# jitted for the reason `_paged_decode_pallas` is: the layers of a step
+# share one traced and lowered kernel
+@functools.partial(jax.jit, static_argnames=("scale", "latent", "interpret"))
+def _mla_decode_pallas(q, pool, page_table, pos, *, scale, latent,
+                       interpret=False):
+    """q: (b, heads, row width); pool: (P, ps, width), the row width in
+    whole tiles; page_table: (b, maxP) i32; pos: (b,) i32. Returns (b,
+    heads, latent)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, _ = q.shape
+    _, ps, width = pool.shape
+    max_pages = page_table.shape[1]
+    g_p = _round_up(heads, 8)
+    ppb = min(max(1, _MLA_BLOCK_TOKENS // ps), max_pages)
+    # the pool's columns past the row are zero, and so are q's
+    qg = jnp.pad(q, ((0, 0), (0, g_p - heads), (0, width - q.shape[2])))
+    table = jnp.pad(page_table.astype(jnp.int32),
+                    ((0, 0), (0, _round_up(max_pages, ppb) - max_pages)),
+                    constant_values=NULL_PAGE)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, g_p, width), lambda b_, pt, ps_: (b_, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, g_p, latent),
+                               lambda b_, pt, ps_: (b_, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, ppb, ps, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_decode_kernel, ps=ps, ppb=ppb, scale=scale,
+                          n_pages=max_pages, latent=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, g_p, latent), q.dtype),
+        interpret=interpret,
+        name=scopes.MLA_DECODE_KERNEL,
+    )(table, pos.astype(jnp.int32), qg, pool)
+    return out[:, :heads]
 
 
 # ------------------------------------------------------- Pallas ragged path

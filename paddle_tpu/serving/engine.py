@@ -277,6 +277,46 @@ class ServingObs:
         self.spec_wasted = None
         self.spec_target_steps = None
         self.spec_tokens_per_step = None
+        # expert-layer handles, bound by bind_moe() only for a model
+        # whose steps return an expert histogram
+        self.moe_pairs = None
+
+    def bind_moe(self) -> None:
+        """Expert-layer observability: counters fed from the (layers,
+        experts) histogram of tokens an expert that every prefill and
+        every step of a decode block returns with its tokens (no host
+        sync of its own). A layer dispatch is one expert layer in one
+        model step that routed at least one token."""
+        c = self.registry.counter
+        self.moe_pairs = c(
+            "serving_moe_pairs_total",
+            "(token, expert) pairs routed, padding and parked rows "
+            "left out")
+        self.moe_experts_touched = c(
+            "serving_moe_experts_touched_total",
+            "distinct experts with a token, summed over layer dispatches")
+        self.moe_layer_dispatches = c(
+            "serving_moe_layer_dispatches_total",
+            "expert layers run, one a layer a model step")
+        self.moe_max_expert_tokens = c(
+            "serving_moe_max_expert_tokens_total",
+            "tokens of the fullest expert, summed over layer dispatches")
+
+    def moe_block(self, hist, family: str) -> None:
+        """Count one drained block's histograms, (..., layers, experts),
+        and leave the distinct experts it touched on a marker span (a
+        span's attributes are fixed when it starts, and this number
+        comes back with the tokens)."""
+        hist = np.asarray(hist).reshape(-1, hist.shape[-1])
+        touched = np.count_nonzero(hist, axis=-1)
+        pairs, experts = int(hist.sum()), int(touched.sum())
+        self.moe_pairs.inc(pairs)
+        self.moe_experts_touched.inc(experts)
+        self.moe_layer_dispatches.inc(int(np.count_nonzero(touched)))
+        self.moe_max_expert_tokens.inc(int(hist.max(axis=-1).sum()))
+        with RecordEvent("serving.moe_stats", family=family,
+                         experts_touched=experts, pairs=pairs):
+            pass
 
     def bind_tp(self, tp_size: int, overlap: bool = False) -> None:
         """TP observability (ISSUE 10): the measured all-reduce latency
@@ -455,6 +495,30 @@ class ServingEngine:
                 f"unknown kv_dtype {kv_dtype!r}: expected one of "
                 "'fp32', 'bf16', 'int8', 'fp8'")
         self.kv_dtype = kv_dtype
+        # a model with latent attention (its config names the cached
+        # row's width) gets a latent pool; what is not yet written over
+        # that pool kind is refused here, by the option's name, never
+        # taken down a path that would compute something else
+        if getattr(cfg, "latent_cache_dim", None) is not None:
+            refused = [name for name, on in (
+                ("tp_size", int(tp_size) > 1),
+                ("kv_dtype", kv_dtype in ("int8", "fp8")),
+                ("enable_prefix_caching", bool(enable_prefix_caching)),
+                ("enable_chunked_prefill", bool(enable_chunked_prefill)),
+                ("spec_config", spec_config is not None)) if on]
+            if refused:
+                raise ValueError(
+                    f"{type(model).__name__} serves over a latent KV pool, "
+                    f"which does not support {', '.join(refused)} yet "
+                    "(tensor parallelism, quantized pages, and any "
+                    "prefill at an offset: prefix cache, chunked prefill "
+                    "and the ragged step, speculative verify)")
+        # the serving protocol's two optional parts (models/mla_moe.py):
+        # `logits_at` makes a prefill return one position's logits, and
+        # the cache path returns a third value whose expert histogram
+        # feeds the serving_moe_* counters
+        self._logits_at = bool(getattr(model, "serving_logits_at", False))
+        self._has_aux = bool(getattr(model, "serving_aux", False))
         self.tp_quantized_allreduce = bool(tp_quantized_allreduce)
         if self.tp_quantized_allreduce and int(tp_size) < 2:
             raise ValueError(
@@ -591,7 +655,7 @@ class ServingEngine:
             # WITHOUT touching serving.quant
             c = self.cache
             fp32_bytes = (c.num_layers * c.num_pages * c.page_size
-                          * 2 * c.num_kv_heads * c.head_dim * 4)
+                          * c.slot_elems * 4)
             rms = None
             if c.quantized:
                 from .quant import measure_roundtrip_error
@@ -600,6 +664,8 @@ class ServingEngine:
                                    rms)
         if self._obs is not None and self.spec_config is not None:
             self._obs.bind_spec()
+        if self._obs is not None and self._has_aux:
+            self._obs.bind_moe()
         # SLO accounting (ISSUE 13): per-request-class TTFT/TPOT targets
         # feeding windowed attainment gauges + a goodput counter. Rides
         # on the metrics registry, so it requires one; with no classes
@@ -1134,19 +1200,27 @@ class ServingEngine:
         if key not in self._jit_cache:
             model = self.model if tp is None else tp.shard_model
 
+            logits_at = self._logits_at
+
             def prefill(params, buffers, ids, pools, page_table, last_idx,
                         key_data, temps, top_ks, top_ps):
                 views = views_from_pools(pools, page_table)
-                (logits, new_views), _ = call_functional(
-                    model, params, buffers, (Tensor(ids),),
-                    kwargs={"caches": views, "start_pos": 0},
+                kwargs = {"caches": views, "start_pos": 0}
+                if logits_at:
+                    # the model computes that position's logits alone
+                    kwargs["logits_at"] = last_idx
+                out, _ = call_functional(
+                    model, params, buffers, (Tensor(ids),), kwargs=kwargs,
                     training=False)
-                last = jax.lax.dynamic_slice_in_dim(
-                    logits, last_idx, 1, axis=1)[:, 0]
+                logits, new_views = out[0], out[1]
+                last = logits[:, 0] if logits_at else (
+                    jax.lax.dynamic_slice_in_dim(
+                        logits, last_idx, 1, axis=1)[:, 0])
                 key_data, subs = _split_rows(key_data)
                 tok = _sample_batch(last, subs, temps, top_ks, top_ps)
+                # a model with auxiliary outputs (out[2]) hands them on
                 return (tok.astype(jnp.int32), key_data,
-                        pools_from_views(new_views))
+                        pools_from_views(new_views)) + tuple(out[2:])
 
             if tp is not None:
                 prefill = tp.wrap_prefill_exec(prefill)
@@ -1228,6 +1302,7 @@ class ServingEngine:
         key_data = self._key_state[req.request_id][None]
 
         def dispatch():
+            aux = ()
             if n_cached:
                 tok, new_kd, pools = self._prefill_offset_jit(bucket)(
                     self.params, self.buffers, jnp.asarray(ids),
@@ -1235,12 +1310,18 @@ class ServingEngine:
                     jnp.int32(len(suffix) - 1), jnp.int32(n_cached),
                     key_data, *knobs)
             else:
-                tok, new_kd, pools = self._prefill_jit(bucket)(
+                tok, new_kd, pools, *aux = self._prefill_jit(bucket)(
                     self.params, self.buffers, jnp.asarray(ids),
                     self.cache.pools, page_table,
                     jnp.int32(len(suffix) - 1), key_data, *knobs)
             self.cache.pools = pools
             self._key_state[req.request_id] = new_kd[0]
+            if aux:
+                # the histogram rides the token's own transfer
+                tok, hist = jax.device_get(  # noqa: HOST-SYNC — the prefill's one sync, as below
+                    (tok, aux[0]["moe_expert_tokens"]))
+                if self._obs is not None:
+                    self._obs.moe_block(hist, "prefill")
             return int(np.asarray(tok)[0])
 
         t0 = time.perf_counter()
@@ -1673,10 +1754,11 @@ class ServingEngine:
                 def body(carry, _):
                     tokens, pools, positions, key_data, remaining = carry
                     views = views_from_pools(pools, page_tables)
-                    (logits, new_views), _ = call_functional(
+                    out, _ = call_functional(
                         model, params, buffers, (Tensor(tokens[:, None]),),
                         kwargs={"caches": views, "start_pos": positions},
                         training=False)
+                    logits, new_views = out[0], out[1]
                     pools = pools_from_views(new_views)
                     key_data, subs = _split_rows(key_data)
                     nxt = _sample_batch(logits[:, 0], subs, temps,
@@ -1689,14 +1771,17 @@ class ServingEngine:
                     tokens = jnp.where(alive, nxt, tokens)
                     positions = advance_positions(
                         positions, remaining > 0, max_pages, page_size)
+                    # a model's auxiliary outputs (out[2]) stack over the
+                    # block's steps beside the tokens
                     return (tokens, pools, positions, key_data,
-                            remaining), emit
+                            remaining), (emit,) + tuple(out[2:])
 
                 carry = (tokens, pools, positions, key_data, remaining)
-                (tokens, pools, positions, key_data, remaining), emitted = \
-                    jax.lax.scan(body, carry, None, length=horizon)
+                (tokens, pools, positions, key_data, remaining), \
+                    (emitted, *aux) = jax.lax.scan(
+                        body, carry, None, length=horizon)
                 return (jnp.transpose(emitted), pools, tokens, positions,
-                        key_data, remaining)
+                        key_data, remaining) + tuple(aux)
 
             if tp is not None:
                 decode_block = tp.wrap_decode_exec(decode_block)
@@ -1816,7 +1901,7 @@ class ServingEngine:
                 [r for r in reqs if r.status == "running"], err,
                 "decode")
             return events_prev + ev
-        emitted, pools, tokens, positions, key_data, remaining = out
+        emitted, pools, tokens, positions, key_data, remaining, *aux = out
         for req, n in zip(reqs, incr):
             req.inflight += n
         if self._obs is not None:
@@ -1841,6 +1926,8 @@ class ServingEngine:
             "key_data": key_data, "remaining": remaining, "knobs": knobs,
             "t0": t0,
         }
+        if aux:
+            self._pending["moe_hist"] = aux[0]["moe_expert_tokens"]
         if prev is not None:
             # async overlap: block k+1 is dispatched and running; pulling
             # block k's tokens now costs (at most) the device time block
@@ -2019,8 +2106,14 @@ class ServingEngine:
         t_in = time.perf_counter()
         sstats = rec.get("spec_stats")
         windows = rec.get("windows")
+        moe_hist = rec.get("moe_hist")
         with RecordEvent("serving.host_drain"):
-            if sstats is None:
+            if moe_hist is not None:
+                pulled, err = self._guarded_call(
+                    "drain", lambda: jax.device_get((rec["emitted"], moe_hist)))  # noqa: HOST-SYNC — still THE one sync per block: the expert histogram comes back in the tokens' transfer (PR 3 contract)
+                toks, moe_hist = (pulled if pulled is not None
+                                  else (None, None))
+            elif sstats is None:
                 toks, err = self._guarded_call(
                     "drain", lambda: np.asarray(jax.device_get(rec["emitted"])))  # noqa: HOST-SYNC — THE one sync per decode block (PR 3 contract)
             else:
@@ -2041,6 +2134,8 @@ class ServingEngine:
             return []
         if o is not None:
             o.host_syncs.inc()
+            if moe_hist is not None:
+                o.moe_block(moe_hist, "decode")
         now = time.perf_counter()
         kd = rec["key_data"]
         events: List[Tuple[int, int]] = []

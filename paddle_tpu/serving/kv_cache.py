@@ -28,8 +28,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PagedLayerCache",
-           "NULL_PAGE", "pages_for", "overflow_position",
-           "views_from_pools", "pools_from_views"]
+           "LatentLayerCache", "NULL_PAGE", "pages_for",
+           "overflow_position", "views_from_pools", "pools_from_views"]
 
 NULL_PAGE = 0
 
@@ -290,10 +290,49 @@ class PagedLayerCache:
         return cls(kp, vp, pt, rid, k_scale=ks, v_scale=vs)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class LatentLayerCache:
+    """One layer's view of a latent pool (the pool kind of a model with
+    latent attention, MLA): one row a token, the normed compressed
+    latent followed by the one rotated rope key all heads share, and no
+    V. `serving.attention.latent_write` writes it and
+    `latent_decode_attention` reads it; the allocator, the page tables and the null page are the K/V kind's.
+
+    pool:       (num_pages, page_size, width) — a page is one contiguous
+                (page_size, width) slab, which the decode kernel brings
+                by one copy. `width` is the row's own width rounded up
+                to whole 128-lane tiles, as the chip's memory holds it
+                anyway (a kernel cannot copy part of a tile out of HBM);
+                the columns past the row stay zero
+    page_table: (B, max_pages) int32, as `PagedLayerCache`'s
+    """
+
+    pool: jnp.ndarray
+    page_table: jnp.ndarray
+
+    @property
+    def page_size(self) -> int:
+        return self.pool.shape[1]
+
+    def tree_flatten(self):
+        return (self.pool, self.page_table), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
 def views_from_pools(pools, page_table, row_ids=None):
-    """Per-layer PagedLayerCache list from engine pool tuples — 2-tuples
-    (k, v) for plain pools, 4-tuples (k, v, k_scale, v_scale) for
-    quantized ones. Runs at trace time inside every jitted step."""
+    """Per-layer cache views from engine pool tuples — 1-tuples (pool,)
+    for the latent kind, 2-tuples (k, v) for plain K/V pools, 4-tuples
+    (k, v, k_scale, v_scale) for quantized ones. Runs at trace time
+    inside every jitted step."""
+    if pools and len(pools[0]) == 1:
+        if row_ids is not None:
+            raise NotImplementedError(
+                "a latent pool has no flat ragged step (row_ids)")
+        return [LatentLayerCache(p[0], page_table) for p in pools]
     return [PagedLayerCache(p[0], p[1], page_table, row_ids,
                             k_scale=p[2] if len(p) == 4 else None,
                             v_scale=p[3] if len(p) == 4 else None)
@@ -302,8 +341,9 @@ def views_from_pools(pools, page_table, row_ids=None):
 
 def pools_from_views(views):
     """Inverse of `views_from_pools`: pool tuples from the new caches a
-    step returned, preserving 2- vs 4-tuple arity."""
-    return [(v.k_pool, v.v_pool) if v.k_scale is None
+    step returned, preserving the tuples' arity."""
+    return [(v.pool,) if isinstance(v, LatentLayerCache)
+            else (v.k_pool, v.v_pool) if v.k_scale is None
             else (v.k_pool, v.v_pool, v.k_scale, v.v_scale)
             for v in views]
 
@@ -314,17 +354,32 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None,
+                 latent_dim: Optional[int] = None):
+        """`latent_dim` selects the pool kind: None for K and V pools of
+        `num_kv_heads` heads of `head_dim`; a row's width for the latent
+        kind, one (num_pages, page_size, latent_dim rounded up to whole
+        128-lane tiles) pool a layer (`num_kv_heads` and `head_dim` are
+        then not read)."""
         self.num_layers = num_layers
         self.num_pages = num_pages
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
+        self.latent_dim = latent_dim
         if kv_dtype is not None and kv_dtype in _PLAIN_KV_DTYPES:
             dtype = _PLAIN_KV_DTYPES[kv_dtype]
             kv_dtype = None
         self.quant_spec = None
-        if kv_dtype is not None:
+        if latent_dim is not None:
+            if kv_dtype is not None:
+                raise ValueError(
+                    f"kv_dtype={kv_dtype!r}: a latent pool holds fp32 or "
+                    "bf16 rows; quantized latent pages are not written yet")
+            self.pools = [(jnp.zeros((num_pages, page_size,
+                                      self.slot_elems), dtype),)
+                          for _ in range(num_layers)]
+        elif kv_dtype is not None:
             # quantized pools ONLY: the fp32/bf16 constructor path above
             # must never import serving.quant
             from .quant import SCALE_DTYPE, resolve_kv_dtype
@@ -360,16 +415,30 @@ class PagedKVCache:
         return self.quant_spec is not None
 
     @property
+    def kind(self) -> str:
+        """"latent" or "kv": what a page holds."""
+        return "kv" if self.latent_dim is None else "latent"
+
+    @property
+    def slot_elems(self) -> int:
+        """Stored elements of one token in one layer: the latent row in
+        whole 128-lane tiles, or K and V of every kv head."""
+        if self.latent_dim is not None:
+            return -(-self.latent_dim // 128) * 128
+        return 2 * self.num_kv_heads * self.head_dim
+
+    @property
     def page_bytes(self) -> int:
-        """Bytes one logical page occupies across all layers: K+V data
-        slabs plus (quantized pools) the parallel scale slabs. This is
-        the capacity unit — resident sequences per pool byte budget is
+        """Bytes one logical page occupies across all layers: the data
+        slabs of the pool's kind plus (quantized pools) the parallel
+        scale slabs. This is the capacity unit — resident sequences per
+        pool byte budget is
         `budget // (pages_for(seq_len) * page_bytes)`."""
         itemsize = (self.quant_spec.storage_itemsize
                     if self.quant_spec is not None
                     else jnp.dtype(self.dtype).itemsize)
-        per_slot = 2 * self.num_kv_heads * (
-            self.head_dim * itemsize + (4 if self.quantized else 0))
+        per_slot = self.slot_elems * itemsize + (
+            2 * self.num_kv_heads * 4 if self.quantized else 0)
         return self.num_layers * self.page_size * per_slot
 
     @property
@@ -387,6 +456,8 @@ class PagedKVCache:
         kv_heads = getattr(cfg, "num_key_value_heads",
                            cfg.num_attention_heads)
         head_dim = cfg.hidden_size // cfg.num_attention_heads
+        # a model with latent attention says how wide its cached row is
+        latent_dim = getattr(cfg, "latent_cache_dim", None)
         # validate the model's compute dtype against the requested pool
         # format up front — the old code silently assumed fp32 pools and
         # a mismatch surfaced as a cryptic XLA dtype error mid-step
@@ -405,7 +476,8 @@ class PagedKVCache:
                 f"unknown kv_dtype {kv_dtype!r}: expected one of "
                 "'fp32', 'bf16', 'int8', 'fp8'")
         return cls(cfg.num_hidden_layers, num_pages, page_size, kv_heads,
-                   head_dim, dtype, kv_dtype=kv_dtype)
+                   head_dim, dtype, kv_dtype=kv_dtype,
+                   latent_dim=latent_dim)
 
     def shard_pools(self, mesh, spec) -> None:
         """Place every layer's pool tuple onto `mesh` under `spec` —
@@ -419,6 +491,9 @@ class PagedKVCache:
         layout."""
         from jax.sharding import NamedSharding
 
+        if self.latent_dim is not None:
+            raise ValueError("a latent pool has no kv-head axis to shard: "
+                             "tensor parallelism over it is not written yet")
         sh = NamedSharding(mesh, spec)
         self.pools = [tuple(jax.device_put(x, sh) for x in layer)
                       for layer in self.pools]
@@ -437,11 +512,12 @@ class PagedKVCache:
             out[i, :len(pages)] = pages
         return jnp.asarray(out)
 
-    def layer_views(self, page_table: jnp.ndarray) -> List[PagedLayerCache]:
-        """Per-layer PagedLayerCache list in the shape the models expect
-        for their `caches` argument."""
+    def layer_views(self, page_table: jnp.ndarray) -> list:
+        """Per-layer views of the pool's kind (`PagedLayerCache` or
+        `LatentLayerCache`) in the shape the models expect for their
+        `caches` argument."""
         return views_from_pools(self.pools, page_table)
 
-    def update(self, new_views: Sequence[PagedLayerCache]) -> None:
+    def update(self, new_views: Sequence) -> None:
         """Adopt the pools a jitted step returned (the step's new_caches)."""
         self.pools = pools_from_views(new_views)

@@ -130,6 +130,37 @@ def _layer_norm_decode():
                                  ((2048,), jnp.bfloat16)]
 
 
+def _mla_decode(rows=32):
+    """The latent cell's own decode call: 32 heads over one cached row
+    of 512 + 64 a token held in 640 columns, pages of 16, 1,088 pages a
+    row, the configuration's pool of 26,000 pages, bf16."""
+    def fn(q, pool, table, pos):
+        return paged._mla_decode_pallas(q, pool, table, pos,
+                                        scale=192 ** -0.5, latent=512)
+
+    return fn, [((rows, 32, 576), jnp.bfloat16),
+                ((26000, 16, 640), jnp.bfloat16),
+                ((rows, 1088), jnp.int32), ((rows,), jnp.int32)]
+
+
+def _moe_grouped_matmul(pairs, down=False):
+    """One projection of the 256 experts of 2048 x 768 over the sorted
+    pairs of a decode step (256, or 8 for one row) or of a prefill's
+    8,192-token chunk (65,536)."""
+    from paddle_tpu.models import mla_moe
+
+    def fn(xs, w, sizes):
+        was, mla_moe.GROUPED_MATMUL = mla_moe.GROUPED_MATMUL, "gmm"
+        try:
+            return mla_moe._grouped_matmul(xs, w, sizes)
+        finally:
+            mla_moe.GROUPED_MATMUL = was
+
+    k, n = (768, 2048) if down else (2048, 768)
+    return fn, [((pairs, k), jnp.bfloat16), ((256, k, n), jnp.bfloat16),
+                ((256,), jnp.int32)]
+
+
 _ONE_CHIP = {
     "paged_decode": _paged_decode,
     **{f"paged_decode_rows{n}": functools.partial(_paged_decode, rows=n)
@@ -147,6 +178,14 @@ _ONE_CHIP = {
     "flash_train_dropout": _flash_train_dropout,
     "layer_norm_train": _layer_norm_train,
     "layer_norm_decode": _layer_norm_decode,
+    "mla_decode": _mla_decode,
+    "mla_decode_rows1": functools.partial(_mla_decode, rows=1),
+    "moe_grouped_matmul_decode": functools.partial(_moe_grouped_matmul, 256),
+    "moe_grouped_matmul_one_row": functools.partial(_moe_grouped_matmul, 8),
+    "moe_grouped_matmul_prefill": functools.partial(_moe_grouped_matmul,
+                                                    65536),
+    "moe_grouped_matmul_down": functools.partial(_moe_grouped_matmul, 65536,
+                                                 down=True),
 }
 
 
@@ -390,3 +429,97 @@ def test_train_scope_reaches_the_compiled_step(train_hlo, scope):
     if scope not in (scopes.GRAD_REDUCE, scopes.OPTIMIZER_UPDATE):
         # forward and backward both carry the layer's scope
         assert any("transpose(jvp(" + scope in n for n in names)
+
+
+# ------------------------- the latent-attention, routed-expert decoder
+
+@pytest.fixture(scope="module")
+def mla_moe_hlo(topo, kernel_paths):
+    """The compiler's text of the decode block and of a bucketed prefill
+    of a small MlaMoe model (one dense and one expert layer, heads and
+    ranks at the published widths) behind a default engine."""
+    from paddle_tpu.models import MlaMoeConfig, MlaMoeForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = MlaMoeConfig(vocab_size=1024, hidden_size=256, num_hidden_layers=2,
+                       num_attention_heads=2, q_lora_rank=128,
+                       intermediate_size=512, moe_intermediate_size=128,
+                       n_routed_experts=8, num_experts_per_tok=2,
+                       dtype="bfloat16", deferred_weights=True)
+    model = MlaMoeForCausalLM(cfg)
+    model.eval()
+    eng = ServingEngine(model, page_size=16, max_batch_size=4,
+                        max_seq_len=256, kv_dtype="bf16")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    def knobs(b):
+        return (sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
+                sds((b,), jnp.int32), sds((b,), jnp.float32))
+
+    state = (abstract(eng.params), abstract(eng.buffers))
+    pools, pages = abstract(eng.cache.pools), eng.max_pages_per_seq
+    decode = eng._decode_block_jit(8).lower(
+        *state, sds((4,), jnp.int32), pools, sds((4, pages), jnp.int32),
+        sds((4,), jnp.int32), *knobs(4), sds((4,), jnp.int32),
+        sds((4,), jnp.int32)).compile().as_text()
+    prefill = eng._prefill_jit(128).lower(
+        *state, sds((1, 128), jnp.int32), pools, sds((1, pages), jnp.int32),
+        sds((), jnp.int32), *knobs(1)).compile().as_text()
+    return {"decode_block": decode, "prefill": prefill}
+
+
+def test_mla_decode_kernel_is_named_where_it_is_created(mla_moe_hlo):
+    calls = _custom_calls(mla_moe_hlo["decode_block"])
+    assert scopes.MLA_DECODE_KERNEL in calls
+    assert scopes.PAGED_DECODE_KERNEL not in calls
+    assert scopes.MLA_DECODE_KERNEL not in _custom_calls(
+        mla_moe_hlo["prefill"])
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill"])
+def test_expert_matmuls_keep_the_name_their_metric_reads(mla_moe_hlo,
+                                                         program):
+    """`moe_experts_roofline.serve` sums the events whose name holds its
+    `match`: the name the compiler derives for the grouped matmul's
+    kernel from the jitted function around its `pallas_call`, three a
+    layer, all under `mlp/moe_experts`."""
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           "moe_experts_roofline.serve.json")) as f:
+        match = json.load(f)["args"]["match"]
+    text = mla_moe_hlo[program]
+    calls = re.findall(r'%([\w.\-]+) = [^\n]*custom-call\([^\n]*'
+                       r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', text)
+    under = [name for name, op_name in calls
+             if _scoped(f'op_name="{op_name}"', scopes.MOE_EXPERTS)]
+    assert len(under) >= 3
+    assert all(match in name for name in under), under
+    assert not [name for name, op_name in calls if match in name
+                and not _scoped(f'op_name="{op_name}"', scopes.MOE_EXPERTS)]
+
+
+@pytest.mark.parametrize("scope", scopes.SERVE_SCOPES)
+def test_serve_scope_reaches_the_latent_model_s_step(mla_moe_hlo, scope):
+    program = ("prefill" if scope == scopes.PREFILL_ATTENTION
+               else "decode_block")
+    assert _scoped(mla_moe_hlo[program], scope)
+
+
+@pytest.mark.parametrize("scope", scopes.SERVE_SUBSCOPES)
+def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, scope):
+    parent = (scopes.PAGED_ATTENTION if scope == scopes.MLA_ABSORB
+              else scopes.MLP)
+    programs = (["decode_block"] if scope == scopes.MLA_ABSORB
+                else ["decode_block", "prefill"])
+    for program in programs:
+        names = _scoped(mla_moe_hlo[program], scope)
+        assert names, (program, scope)
+        # nested inside the serving scope the benchmark's list holds
+        assert all(re.search(parent + r"/(?:[^/]+/)*" + scope, n)
+                   for n in names)

@@ -127,12 +127,41 @@ def _positions(start_pos, b: int, s: int) -> jnp.ndarray:
     return start[:, None] + offs[None, :]
 
 
-def _write_pages(pool, vals, entries, slots):
-    """Scatter (b*s, kvh, hd) token K/V rows into the (kvh, P, ps, hd)
-    pool at (entries, slots). Rows mapped to the null page collide there
-    harmlessly — nothing reads page 0 through a real page table."""
-    flat = jnp.transpose(vals, (1, 0, 2))            # (kvh, b*s, hd)
-    return pool.at[:, entries, slots].set(flat)
+@jax.jit     # traced and lowered once a shape, not once a layer
+def _write_pages(pool, vals, entries, slots=None):
+    """Scatter token K/V rows into the (kvh, P, ps, hd) pool: (n, kvh,
+    hd) rows at (entries, slots), or, with `slots` None, (n, ps, kvh,
+    hd) whole pages at `entries`. Rows mapped to the null page collide
+    there harmlessly — nothing reads page 0 through a real page table.
+
+    The write goes through the pool's (kvh * P, ps, hd) view, at index
+    (h * P + entry, slot) for every kv head h: a reshape of leading
+    dimensions, no bytes move. Do NOT tidy it back into
+    `pool.at[:, entries, slots].set(flat)`. The TPU compiler updates a
+    donated operand in place only where the indexed dimensions lead and
+    the window is the minor dimensions; for the direct form (window over
+    dimension 0) it relayouts the whole pool and back at every write
+    (described v5e, the 1.3B pool, PR 30):
+
+      %copy.2 = bf16[16,2049,16,128]{3,0,2,1:T(8,128)(2,1)} copy(%pool.1)
+      ROOT %copy.3 = bf16[16,2049,16,128]{3,2,1,0:T(8,128)(2,1)} copy(%fusion)
+
+    which was 71% of the device's time in GPT serving.
+    `tests/test_chip_compile.py` fails if a pool-sized copy comes back.
+
+    The scatter takes its updates one at a time, and a row of hd is a
+    part of a tile: a prefill from position 0 over whole pages writes
+    (ps, hd) pages instead, ps times fewer updates of whole tiles
+    (`PERF.md` section 6, PR 30, has what each form read on the chip).
+    """
+    kvh, num_pages, ps, hd = pool.shape
+    heads = jnp.arange(kvh, dtype=entries.dtype)[:, None] * num_pages
+    rows = (heads + entries[None, :]).reshape(-1)    # (kvh * n,)
+    index = (rows,) if slots is None else (rows, jnp.tile(slots, kvh))
+    view = pool.reshape(kvh * num_pages, ps, hd)
+    flat = jnp.moveaxis(vals, -2, 0)                 # (kvh, n, [ps,] hd)
+    view = view.at[index].set(flat.reshape(-1, *view.shape[len(index):]))
+    return view.reshape(pool.shape)
 
 
 def _write_targets(page_table, pos, ps: int, row_ids=None):
@@ -174,6 +203,8 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
     page_table = cache.page_table
     ps = cache.page_size
     b, s = q.shape[0], q.shape[1]
+    raw_start = start_pos._data if hasattr(start_pos, "_data") else start_pos
+    static_zero = isinstance(raw_start, int) and raw_start == 0
 
     with jax.named_scope(scopes.KV_WRITE):
         kd_raw = k._data if hasattr(k, "_data") else k
@@ -191,24 +222,29 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
             kd = kd_raw.astype(kp.dtype)
             vd = vd_raw.astype(vp.dtype)
         pos = _positions(start_pos, b, s)                # (b, s)
-        entries, slots = _write_targets(page_table, pos, ps, cache.row_ids)
-        kp = _write_pages(kp, kd.reshape(b * s, *kd.shape[2:]),
-                          entries.reshape(-1), slots.reshape(-1))
-        vp = _write_pages(vp, vd.reshape(b * s, *vd.shape[2:]),
-                          entries.reshape(-1), slots.reshape(-1))
+        # a prefill from position 0 over whole pages: one update a page,
+        # at the page of its first token
+        whole = static_zero and cache.row_ids is None and s % ps == 0
+        entries, slots = _write_targets(
+            page_table, pos[:, ::ps] if whole else pos, ps, cache.row_ids)
+        entries = entries.reshape(-1)
+        slots, n = (None, (b * s // ps, ps)) if whole else (
+            slots.reshape(-1), (b * s,))
+
+        def write(pool, vals):
+            return _write_pages(pool, vals.reshape(*n, *vals.shape[2:]),
+                                entries, slots)
+
+        kp, vp = write(kp, kd), write(vp, vd)
         ks_pool, vs_pool = cache.k_scale, cache.v_scale
         if cache.quantized:
             # the scale slab is scattered with the SAME entries/slots as the
             # data slab — the null-page/overflow routing above covers both
-            ks_pool = _write_pages(ks_pool, k_sc.reshape(b * s, -1, 1),
-                                   entries.reshape(-1), slots.reshape(-1))
-            vs_pool = _write_pages(vs_pool, v_sc.reshape(b * s, -1, 1),
-                                   entries.reshape(-1), slots.reshape(-1))
+            ks_pool = write(ks_pool, k_sc.reshape(b, s, -1, 1))
+            vs_pool = write(vs_pool, v_sc.reshape(b, s, -1, 1))
         new_cache = PagedLayerCache(kp, vp, page_table, cache.row_ids,
                                     k_scale=ks_pool, v_scale=vs_pool)
 
-    raw_start = start_pos._data if hasattr(start_pos, "_data") else start_pos
-    static_zero = isinstance(raw_start, int) and raw_start == 0
     # the exact prefill attends this step's own K/V block; every other
     # branch reaches K/V through the page table: pool views, pads, the
     # relayout, the kernel, the output slice

@@ -235,7 +235,13 @@ class PagedLayerCache:
                    major so the Pallas kernels reach a page's (page_size,
                    head_dim) tile, for one head or a block of heads, by
                    one copy straight from the pool, without a per-step
-                   pool transpose
+                   pool transpose. Written only by `attention._write_pages`,
+                   and only through the (kv_heads * num_pages, page_size,
+                   head_dim) view: scattered as `pool.at[:, entries,
+                   slots]` the TPU compiler copies the whole pool into
+                   another layout and back at every write, where through
+                   the view it updates the donated buffer in place (the
+                   compiler's text is quoted there)
     page_table:    (B, max_pages) int32 — logical page j of row i lives in
                    physical page page_table[i, j] (0 = null page padding)
     row_ids:       optional (T,) int32 — ragged flat-batch mode: the step

@@ -262,12 +262,11 @@ def engine():
             "spec": ServingEngine(model, spec_config=SpecConfig(), **kw)}
 
 
-@pytest.fixture(scope="module")
-def serve_hlo(topo, kernel_paths, engine):
-    """The compiler's text of the decode block and of a bucketed prefill
-    for one described chip."""
-    eng = engine["plain"]
-    one_chip = SingleDeviceSharding(topo.devices[0])
+def _compile_serve(eng, device, rows, bucket):
+    """The engine's decode block (horizon 8, `rows` rows) and its
+    prefill of `bucket` tokens, compiled for one described chip from
+    shapes alone."""
+    one_chip = SingleDeviceSharding(device)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -282,13 +281,22 @@ def serve_hlo(topo, kernel_paths, engine):
     state = (abstract(eng.params), abstract(eng.buffers))
     pools, pages = abstract(eng.cache.pools), eng.max_pages_per_seq
     decode = eng._decode_block_jit(8).lower(
-        *state, sds((4,), jnp.int32), pools, sds((4, pages), jnp.int32),
-        sds((4,), jnp.int32), *knobs(4), sds((4,), jnp.int32),
-        sds((4,), jnp.int32)).compile().as_text()
-    prefill = eng._prefill_jit(128).lower(
-        *state, sds((1, 128), jnp.int32), pools, sds((1, pages), jnp.int32),
-        sds((), jnp.int32), *knobs(1)).compile().as_text()
+        *state, sds((rows,), jnp.int32), pools,
+        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
+        *knobs(rows), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    prefill = eng._prefill_jit(bucket).lower(
+        *state, sds((1, bucket), jnp.int32), pools,
+        sds((1, pages), jnp.int32), sds((), jnp.int32), *knobs(1)).compile()
     return {"decode_block": decode, "prefill": prefill}
+
+
+@pytest.fixture(scope="module")
+def serve_hlo(topo, kernel_paths, engine):
+    """The compiler's text of the decode block and of a bucketed prefill
+    for one described chip."""
+    compiled = _compile_serve(engine["plain"], topo.devices[0], 4, 128)
+    return {name: c.as_text() for name, c in compiled.items()}
 
 
 @pytest.fixture(scope="module")
@@ -429,6 +437,61 @@ def test_train_scope_reaches_the_compiled_step(train_hlo, scope):
     if scope not in (scopes.GRAD_REDUCE, scopes.OPTIMIZER_UPDATE):
         # forward and backward both carry the layer's scope
         assert any("transpose(jvp(" + scope in n for n in names)
+
+
+# ------------------------------- the K/V write leaves the pool where it is
+
+_CELL_POOL = (16, 2049, 16, 128)   # one layer's K (or V) pool, 134 MB in bf16
+
+
+@pytest.fixture(scope="module")
+def serve_pool_compiled(topo, kernel_paths):
+    """The serve steps of the GPT cells at their own attention widths and
+    their own pool (16 heads of 128, pages of 16, 16 rows of 2,048
+    tokens: 2,049 pages), two layers with a narrow MLP and vocabulary:
+    the full decode block and the largest prefill bucket."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = GPTConfig(vocab_size=1024, hidden_size=2048, num_hidden_layers=2,
+                    num_attention_heads=16, intermediate_size=512,
+                    max_position_embeddings=2048)
+    model = GPTForCausalLM(cfg).bfloat16()
+    model.eval()
+    eng = ServingEngine(model, page_size=16, max_batch_size=16,
+                        max_seq_len=2048, kv_dtype="bf16")
+    pools = jax.tree_util.tree_leaves(eng.cache.pools)
+    assert all(p.shape == _CELL_POOL and p.dtype == jnp.bfloat16
+               for p in pools)
+    return _compile_serve(eng, topo.devices[0], 16, 2048)
+
+
+def _pool_sized_moves(text: str) -> list:
+    """The `copy` and `transpose` instructions whose result has as many
+    elements as a pool: the pool itself or any view of it."""
+    size = int(np.prod(_CELL_POOL))
+    moves = re.findall(r"\n\s*(?:ROOT )?(%\S+ = \w+\[([\d,]+)\]\S* "
+                       r"(?:copy|transpose)\([^\n]*)", text)
+    return [line[:240] for line, dims in moves
+            if np.prod([int(d) for d in dims.split(",")]) == size]
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill"])
+def test_no_copy_of_a_whole_kv_pool(serve_pool_compiled, program):
+    """`_write_pages` updates the donated pool in place. Written as
+    `pool.at[:, entries, slots].set(...)` the compiler relayouts the
+    whole operand and back at every write, 71% of the device's time in
+    the GPT cells until PR 30: the op_name of what comes back says which
+    consumer asked for another layout."""
+    text = serve_pool_compiled[program].as_text()
+    assert "[16,2049,16,128]" in text
+    assert _pool_sized_moves(text) == []
+
+
+def test_decode_block_s_temporaries_are_under_one_pool(serve_pool_compiled):
+    temp = (serve_pool_compiled["decode_block"].memory_analysis()
+            .temp_size_in_bytes)
+    assert temp < int(np.prod(_CELL_POOL)) * 2
 
 
 # ------------------------- the latent-attention, routed-expert decoder

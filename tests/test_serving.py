@@ -700,6 +700,136 @@ class TestPagedAttentionParity:
         assert np.any(np.asarray(new_view.k_pool[0, NULL_PAGE]) != 0)
 
 
+# ------------------------------------------------- the K/V write in place
+
+def _write_case(name, rng):
+    """(page_table, start_pos, b, s, row_ids, page_size) of one caller of
+    `_write_pages`, at CPU size; pools have 40 pages, page 0 the null
+    page."""
+    if name == "decode16":
+        # a full decode block's step: 16 rows, one token each, every row
+        # at its own position in its own pages
+        pt = 1 + np.arange(16 * 2).reshape(16, 2)
+        return pt, rng.integers(0, 16, 16), 16, 1, None, 8
+    if name in ("prefill64", "prefill64_whole_pages"):
+        return 3 + np.arange(8)[None], 0, 1, 64, None, 8
+    if name == "prefill_whole_pages_overflow":
+        # two rows of 32 tokens over tables of 3 pages of 8: each row's
+        # fourth page is past its table
+        return np.array([[9, 4, 30], [2, 17, 5]]), 0, 2, 32, None, 8
+    if name == "offset_prefill_overflow":
+        # offset 20 + 16 tokens over a table of 4 pages of 8: positions
+        # 32..35 are past the table and go to the null page
+        return np.array([[9, 4, 30, 17]]), jnp.int32(20), 1, 16, None, 8
+    if name == "ragged_row_ids":
+        # the flat batch: 3 rows' tokens on one sequence axis, decode
+        # rows of one token beside a 6-token prefill chunk
+        pt = np.array([[5, 6, 7], [11, 3, 2], [20, 21, 22]])
+        row_ids = np.array([0, 1, 1, 1, 1, 1, 1, 2, 2])
+        pos = np.array([[11, 2, 3, 4, 5, 6, 7, 8, 9]])
+        return pt, pos, 1, 9, row_ids, 4
+    assert name == "parked_rows"
+    # rows 1, 2 and 5 of 6 are parked at the table-overflow position
+    pt = 1 + np.arange(6 * 3).reshape(6, 3)
+    park = 3 * 8
+    return pt, np.array([4, park, park, 23, 0, park]), 6, 1, None, 8
+
+
+class TestWritePages:
+    """`_write_pages` against a plain numpy loop over (entries, slots):
+    the same rows at the same (page, slot) of the same pool, every other
+    byte as it was."""
+
+    @pytest.mark.parametrize("kvh", [1, 4])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("case", [
+        "decode16", "prefill64", "prefill64_whole_pages",
+        "prefill_whole_pages_overflow", "offset_prefill_overflow",
+        "ragged_row_ids", "parked_rows"])
+    def test_matches_a_numpy_loop_bit_for_bit(self, rng, case, dtype, kvh):
+        pt, start, b, s, row_ids, ps = _write_case(case, rng)
+        P, hd, n = 40, 8, b * s
+        pos = satt._positions(jnp.asarray(start, jnp.int32), b, s)
+        entries, slots = satt._write_targets(
+            jnp.asarray(pt, jnp.int32), pos, ps,
+            None if row_ids is None else jnp.asarray(row_ids, jnp.int32))
+        entries, slots = entries.reshape(-1), slots.reshape(-1)
+        # a prefill from position 0 over whole pages hands pages, not
+        # rows: (n / ps, ps, kvh, width) at the page of each first token
+        whole = "whole_pages" in case
+
+        def draw(shape, dt):
+            if dt == "int8":
+                return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+            return jnp.asarray(rng.standard_normal(shape), dt)
+
+        # an int8 pool is a data slab and an fp32 (kvh, P, ps, 1) scale
+        # slab, written by the same function at the same targets
+        slabs = [(dtype, hd)] + ([("float32", 1)] if dtype == "int8" else [])
+        for dt, width in slabs:
+            pool = draw((kvh, P, ps, width), dt)
+            vals = draw((n, kvh, width), dt)
+            if whole:
+                got = satt._write_pages(
+                    pool, vals.reshape(n // ps, ps, kvh, width),
+                    entries[::ps])
+            else:
+                got = satt._write_pages(pool, vals, entries, slots)
+            got = np.asarray(got.astype(jnp.float32))
+            assert got.shape == (kvh, P, ps, width)
+            want = np.asarray(pool.astype(jnp.float32)).copy()
+            rows = np.asarray(vals.astype(jnp.float32))
+            e, sl = np.asarray(entries), np.asarray(slots)
+            for t in range(n):
+                want[:, e[t], sl[t]] = rows[t]
+            # real pages: bit for bit, written and untouched alike
+            np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+            # the null page takes the collisions: a slot holds one of the
+            # rows sent there, or what it held
+            for slot in range(ps):
+                sent = [rows[t] for t in range(n)
+                        if e[t] == NULL_PAGE and sl[t] == slot]
+                sent = sent or [want[:, NULL_PAGE, slot]]
+                assert any(np.array_equal(got[:, NULL_PAGE, slot], r)
+                           for r in sent)
+        if case in ("offset_prefill_overflow", "parked_rows",
+                    "prefill_whole_pages_overflow"):
+            assert np.any(np.asarray(entries) == NULL_PAGE)
+        else:
+            assert not np.any(np.asarray(entries) == NULL_PAGE)
+
+    @pytest.mark.parametrize("s,whole", [(16, True), (10, False)])
+    def test_paged_attend_chooses_the_form_from_its_shapes(
+            self, rng, monkeypatch, s, whole):
+        """From position 0 over whole pages `paged_attend` writes pages,
+        otherwise rows; either way the pools are the numpy loop's."""
+        kvh, P, ps, hd, b = 2, 12, 4, 8, 2
+        pt = np.array([[3, 7, 1, 9], [2, 8, 5, 11]])
+        pools = [jnp.asarray(rng.standard_normal((kvh, P, ps, hd)),
+                             jnp.float32) for _ in range(2)]
+        view = PagedLayerCache(*pools, jnp.asarray(pt, jnp.int32))
+        q, k, v = (Tensor(jnp.asarray(
+            rng.standard_normal((b, s, kvh, hd)), jnp.float32))
+            for _ in range(3))
+        forms, real = [], satt._write_pages
+
+        def spy(pool, vals, entries, slots=None):
+            forms.append(slots is None)
+            return real(pool, vals, entries, slots)
+
+        monkeypatch.setattr(satt, "_write_pages", spy)
+        _, new_view = satt.paged_attend(q, k, v, view, 0, 1)
+        assert forms == [whole, whole]
+        for pool, vals, got in ((pools[0], k, new_view.k_pool),
+                                (pools[1], v, new_view.v_pool)):
+            want = np.asarray(pool).copy()
+            for i in range(b):
+                for j in range(s):
+                    want[:, pt[i, j // ps], j % ps] = np.asarray(
+                        vals._data[i, j])
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # -------------------------------------------------- continuous batching
 
 class TestContinuousBatching:

@@ -113,8 +113,10 @@ class CallGraph:
         table: Dict[str, List[FuncNode]] = {}
         self._by_name[path] = table
 
+        children = mod.children()
+
         def visit(node: ast.AST, qual: str, cls: str) -> None:
-            for child in ast.iter_child_nodes(node):
+            for child in children[node]:
                 if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
                     q = f"{qual}.{child.name}" if qual else child.name
                     fn = FuncNode(FuncKey(path, q, child.lineno),

@@ -70,14 +70,48 @@ class ParsedModule:
         self._noqa_reasons: Dict[int, str] = {}
         self._jax_aliases: Optional[Set[str]] = None
         self._nodes: Optional[List[ast.AST]] = None
+        self._children: Dict[ast.AST, List[ast.AST]] = {}
+        self._walks: Dict[ast.AST, List[ast.AST]] = {}
 
     def nodes(self) -> List[ast.AST]:
         """Every AST node, in ``ast.walk`` order, computed once — a
         full sweep runs ~10 rules over each module and a fresh walk per
-        rule is the single biggest cost of the whole sweep."""
+        rule is the single biggest cost of the whole sweep. The one
+        pass that reads the tree's fields also keeps each node's child
+        list, which `children` hands out and `walk` reads."""
         if self._nodes is None:
-            self._nodes = list(ast.walk(self.tree))
+            children = self._children
+            order = [self.tree]
+            for node in order:  # grows as it is read: breadth first
+                # ast.iter_child_nodes, without its two generators
+                kids = children[node] = []
+                for name in node._fields:
+                    value = getattr(node, name, None)
+                    if isinstance(value, list):
+                        for item in value:
+                            if isinstance(item, ast.AST):
+                                kids.append(item)
+                    elif isinstance(value, ast.AST):
+                        kids.append(value)
+                order.extend(kids)
+            self._nodes = order
         return self._nodes
+
+    def children(self) -> Dict[ast.AST, List[ast.AST]]:
+        """Each node's ``ast.iter_child_nodes``, as a list."""
+        self.nodes()
+        return self._children
+
+    def walk(self, node: ast.AST) -> List[ast.AST]:
+        """``ast.walk(node)`` for a node of this tree, as a list kept
+        per root: several rules walk the same function."""
+        found = self._walks.get(node)
+        if found is None:
+            children = self.children()
+            found = self._walks[node] = [node]
+            for sub in found:
+                found.extend(children[sub])
+        return found
 
     # -- suppression -------------------------------------------------------
     @property
@@ -261,10 +295,11 @@ def call_chain(call: ast.Call) -> Optional[List[str]]:
     return dotted_chain(call.func)
 
 
-def walk_stmts(body: Sequence[ast.stmt]) -> Iterator[ast.AST]:
+def walk_stmts(module: ParsedModule,
+               body: Sequence[ast.stmt]) -> Iterator[ast.AST]:
     """ast.walk over a statement list (a Try body without its handlers)."""
     for stmt in body:
-        yield from ast.walk(stmt)
+        yield from module.walk(stmt)
 
 
 def is_jax_call(call: ast.Call, aliases: Set[str]) -> bool:
@@ -322,8 +357,9 @@ def traced_functions(module: ParsedModule) -> List[FunctionInfo]:
     parents: Dict[ast.AST, ast.AST] = {}
     all_defs: List[ast.AST] = []
     calls: List[ast.Call] = []
+    children = module.children()
     for node in module.nodes():
-        for child in ast.iter_child_nodes(node):
+        for child in children[node]:
             parents[child] = node
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             all_defs.append(node)

@@ -68,9 +68,10 @@ def _builder_positions(module: ParsedModule) -> Dict[int, FrozenSet[int]]:
     body creates a donating jit (the ``_prefill_jit`` builder shape).
     One O(module) walk: each call attributes to its innermost def."""
     out: Dict[int, FrozenSet[int]] = {}
+    children = module.children()
 
     def visit(node: ast.AST, owner: Optional[int]) -> None:
-        for child in ast.iter_child_nodes(node):
+        for child in children[node]:
             if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 visit(child, id(child))
                 continue

@@ -41,20 +41,18 @@ def _contains_tuple(expr: ast.AST) -> bool:
     return any(isinstance(n, ast.Tuple) for n in ast.walk(expr))
 
 
-def _has_jit_call(fn: ast.AST) -> bool:
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Call):
-            chain = dotted_chain(node.func)
-            if chain is not None and tuple(chain) in _JIT_CHAINS:
-                return True
-    return False
+def _is_jit_call(node: ast.AST) -> bool:
+    if not isinstance(node, ast.Call):
+        return False
+    chain = dotted_chain(node.func)
+    return chain is not None and tuple(chain) in _JIT_CHAINS
 
 
-def _cache_subscript_keys(fn: ast.AST) -> Set[str]:
+def _cache_subscript_keys(nodes: List[ast.AST]) -> Set[str]:
     """Names used to index a container whose attribute/name mentions
     'cache', e.g. `self._jit_cache[key]`."""
     keys: Set[str] = set()
-    for node in ast.walk(fn):
+    for node in nodes:
         if not isinstance(node, ast.Subscript):
             continue
         base = node.value
@@ -79,17 +77,20 @@ class JitCacheKeyRule(Rule):
 
     def check(self, module: ParsedModule) -> Iterator[Finding]:
         hits: List[Tuple[int, str]] = []
+        if not any(_is_jit_call(node) for node in module.nodes()):
+            return      # most modules build no executable
         for fn in module.nodes():
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
-            if not _has_jit_call(fn):
+            fn_nodes = module.walk(fn)
+            if not any(_is_jit_call(node) for node in fn_nodes):
                 continue
-            cache_keys = _cache_subscript_keys(fn)
+            cache_keys = _cache_subscript_keys(fn_nodes)
             if not cache_keys:
                 continue
             # the key assignment(s): `key = <expr with a tuple>`
             key_assigns: List[ast.Assign] = []
-            for node in ast.walk(fn):
+            for node in fn_nodes:
                 if (isinstance(node, ast.Assign)
                         and len(node.targets) == 1
                         and isinstance(node.targets[0], ast.Name)
@@ -115,7 +116,7 @@ class JitCacheKeyRule(Rule):
             # one-pass derivation map: `b, prompt_len = ids.shape` means a
             # key containing `b` covers parameter `ids`
             derived: Dict[str, Set[str]] = {}
-            for node in ast.walk(fn):
+            for node in fn_nodes:
                 if isinstance(node, ast.Assign):
                     srcs = {n.id for n in ast.walk(node.value)
                             if isinstance(n, ast.Name)}

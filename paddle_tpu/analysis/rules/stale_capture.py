@@ -67,9 +67,9 @@ class StaleCaptureRule(Rule):
             fn = info.node
             body = fn.body
             if isinstance(body, list):
-                body_nodes = [n for stmt in body for n in ast.walk(stmt)]
+                body_nodes = [n for stmt in body for n in module.walk(stmt)]
             else:  # Lambda: .body is a single expression, not a list
-                body_nodes = list(ast.walk(body))
+                body_nodes = module.walk(body)
             for n in body_nodes:
                 if (isinstance(n, ast.Attribute)
                         and isinstance(n.value, ast.Name)

@@ -64,9 +64,10 @@ def _is_logging_call(call: ast.Call) -> bool:
     return False
 
 
-def _handler_is_silent(handler: ast.ExceptHandler) -> bool:
+def _handler_is_silent(module: ParsedModule,
+                       handler: ast.ExceptHandler) -> bool:
     bound = handler.name
-    for node in walk_stmts(handler.body):
+    for node in walk_stmts(module, handler.body):
         if isinstance(node, ast.Raise):
             return False
         if isinstance(node, ast.Call) and _is_logging_call(node):
@@ -90,13 +91,14 @@ class SwallowedApiRule(Rule):
         for node in module.nodes():
             if not isinstance(node, ast.Try):
                 continue
-            body_calls = [n for n in walk_stmts(node.body)
+            body_calls = [n for n in walk_stmts(module, node.body)
                           if isinstance(n, ast.Call)]
             if not body_calls:
                 continue
             jax_calls = [c for c in body_calls if is_jax_call(c, aliases)]
             for handler in node.handlers:
-                if not _is_broad(handler) or not _handler_is_silent(handler):
+                if not _is_broad(handler) or not _handler_is_silent(
+                        module, handler):
                     continue
                 if jax_calls:
                     api = ".".join(

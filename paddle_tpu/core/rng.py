@@ -24,8 +24,8 @@ def _key_impl() -> Optional[str]:
     """RNG implementation for framework keys. Default: threefry (jax's
     default — reproducible across backends). PADDLE_TPU_RNG_IMPL=rbg swaps
     in XLA's RngBitGenerator, which lowers to the TPU's hardware PRNG —
-    ~10x cheaper per dropout mask than threefry's 20 u32 rounds (PERF_NOTES
-    r5 trace: threefry bits dominate the per-layer residual fusions). Masks
+    which spares threefry's 20 u32 rounds a dropout mask (no cell of
+    `chipbench` draws a mask yet: `PERF.md` section 7). Masks
     are then not bit-reproducible across backends, which Paddle's dropout
     contract does not promise."""
     return os.environ.get("PADDLE_TPU_RNG_IMPL") or None

@@ -1,4 +1,4 @@
-"""paddle_tpu.models — flagship model families for the driver benchmarks.
+"""paddle_tpu.models — the models `chipbench`'s cells train and serve.
 
 Upstream these live in the PaddleNLP ecosystem (ERNIE/GPT/LLaMA on top of
 paddle.nn); here they are first-class so the framework ships runnable

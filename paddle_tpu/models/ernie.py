@@ -1,4 +1,4 @@
-"""ERNIE/BERT-family encoder for pretraining benchmarks.
+"""ERNIE/BERT-family encoder: the model of `chipbench`'s pretraining cell.
 
 Capability target: ERNIE-1.0 pretraining (BASELINE.json config #3; upstream
 model lives in the PaddleNLP ecosystem, not core Paddle). Architecture is the
@@ -38,12 +38,13 @@ class ErnieConfig:
     initializer_range: float = 0.02
     # activation checkpointing: rerun each encoder layer's forward in the
     # backward instead of keeping its activations (jax.remat via
-    # fleet.recompute) — trades ~1/3 more FLOPs for O(layers) less HBM,
-    # unlocking larger bench batches (PERF_NOTES r5)
+    # fleet.recompute) — trades ~1/3 more FLOPs for O(layers) less HBM
+    # (the `chipbench` train cell leaves it off)
     recompute: bool = False
     # MLM head via fused_linear_cross_entropy: forward(…, masked_lm_labels=)
     # returns the loss without materializing (b*s, vocab) f32 logits
-    # (PERF_NOTES r5 trace: ~10 ms + ~2.4 GB at base/batch-32)
+    # (the `chipbench` train cell sets it; tests/test_chip_compile.py
+    # holds the compiled step to it)
     fused_mlm_loss: bool = False
 
     @classmethod

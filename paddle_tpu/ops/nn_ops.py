@@ -573,8 +573,8 @@ def fused_linear_cross_entropy(x, weight, bias=None, label=None,
     pass, and a custom VJP recomputes each chunk's logits in the backward
     (flash-attention's trick applied to the LM head). Cuts the f32 logits
     buffer (batch*seq x vocab) from the train step's live set and removes
-    the layout copies XLA spends on it (PERF_NOTES round-5 trace: ~10 ms and
-    ~2.4 GB at ERNIE-base batch 32 x seq 512).
+    the layout copies XLA spends on it (1.2 GB in float32 at ERNIE-base,
+    batch 32 x seq 512, from the shapes).
 
     Upstream analog: paddle.incubate's fused CE path (upstream layout,
     unverified — mount empty). Semantics match

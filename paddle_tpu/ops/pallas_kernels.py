@@ -207,7 +207,7 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
             # precision is pinned on every kernel dot: a global
             # jax_default_matmul_precision="highest" would otherwise force
             # an fp32 contract on bf16 vectors, which Mosaic rejects
-            # ("Bad lhs type" — caught by the AOT tier of test_hlo_perf)
+            # ("Bad lhs type" — caught by tests/test_chip_compile.py)
             s = jax.lax.dot_general(
                 blk(q_ref), blk(k_ref), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,

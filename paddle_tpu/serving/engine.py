@@ -618,7 +618,7 @@ class ServingEngine:
             # decode rows and chunks share it — keyed on a small set of
             # total-token buckets, instead of the decode block plus one
             # dispatch per chunk. `enable_ragged_step=False` keeps the
-            # PR 6 chained pipeline (the bench's comparison baseline)
+            # PR 6 chained pipeline (no caller but tests: ROADMAP.md D2)
             self.enable_ragged_step = bool(enable_ragged_step)
             self.token_buckets = (
                 ragged_token_buckets(max_batch_size,

@@ -2,8 +2,8 @@
 
 A chunked-prefill step used to be a dispatch CHAIN: one fused decode
 block plus one chunked-prefill call per scheduled chunk — N+1 dispatches
-whose per-dispatch overhead (PERF_NOTES, PR 6) is the same order as the
-work itself on small steps. Ragged Paged Attention (arxiv 2604.15464)
+whose per-dispatch overhead is the same order as the work itself on
+small steps (not measured on the chip: no cell runs chunked prefill). Ragged Paged Attention (arxiv 2604.15464)
 shows the rows can share one kernel invocation over the paged pool:
 this module packs a step's decode rows (one input token each) and
 prefill-chunk rows (their page-aligned extents) into ONE flat (1, T)

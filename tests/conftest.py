@@ -42,3 +42,33 @@ def _seed_framework():
 
     paddle.seed(1234)
     yield
+
+
+@pytest.fixture
+def host_drawn_weights(monkeypatch):
+    """Weights drawn by NumPy on the host. An eager constructor compiles
+    one generator program a distinct weight shape, half a second each on
+    a CPU: 60 s for DenseNet-121's 120 shapes. For tests of an
+    architecture (what it builds, the shapes it returns, that gradients
+    flow), not of its initial values: the initializers keep their own
+    tests."""
+    import jax.numpy as jnp
+    from paddle_tpu.core.dtype import convert_dtype
+    from paddle_tpu.nn import initializer
+
+    draw = np.random.default_rng(1234)
+
+    def normal(self, shape, dtype):
+        x = draw.standard_normal(tuple(shape), dtype=np.float32)
+        x *= self.std
+        x += self.mean
+        return jnp.asarray(x, convert_dtype(dtype))
+
+    def uniform(self, shape, dtype):
+        x = draw.random(tuple(shape), dtype=np.float32)
+        x *= self.high - self.low
+        x += self.low
+        return jnp.asarray(x, convert_dtype(dtype))
+
+    monkeypatch.setattr(initializer.Normal, "__call__", normal)
+    monkeypatch.setattr(initializer.Uniform, "__call__", uniform)

@@ -1,9 +1,11 @@
 """The main path's Pallas kernels compiled at real widths for a described
 (not attached) TPU v5e — the third rehearsal of a chip run, kept as a
 test — and the names the device trace is read by (kernels, scopes,
-executables), read from the compiler's own text of the two hot steps
-at tiny widths. A compile that passes is a compile: nothing here runs
-on a chip.
+executables), read from the compiler's own text of the programs the
+benchmark's cells run, at tiny widths; and on that same text, what
+those programs must not hold: a whole attention matrix, whole logits,
+a matmul operand wider than bf16. A compile that passes is a compile:
+nothing here runs on a chip.
 
 Only one process may hold libtpu, so the topology is described inside a
 fixture of this one file and never at import; under xdist only the
@@ -161,6 +163,13 @@ def _moe_grouped_matmul(pairs, down=False):
                 ((256,), jnp.int32)]
 
 
+def _moe_gather_dispatch():
+    """The capacity-bound expert layer's dispatch gather: indices
+    prefetched as scalars, one HBM -> VMEM copy a row."""
+    return pk.gather_rows, [((1024, 512), jnp.bfloat16),
+                            ((2048,), jnp.int32)]
+
+
 _ONE_CHIP = {
     "paged_decode": _paged_decode,
     **{f"paged_decode_rows{n}": functools.partial(_paged_decode, rows=n)
@@ -186,35 +195,107 @@ _ONE_CHIP = {
                                                     65536),
     "moe_grouped_matmul_down": functools.partial(_moe_grouped_matmul, 65536,
                                                  down=True),
+    "moe_gather_dispatch": _moe_gather_dispatch,
 }
 
 
-@pytest.mark.parametrize("case", [*_ONE_CHIP, "tp_overlap_ring"])
-def test_compiles_for_v5e(topo, case):
-    if case == "tp_overlap_ring":
-        # the split-collective ring of tensor-parallel serving, over all
-        # four chips of the host
-        from paddle_tpu.parallel.mesh import build_mesh
-        from paddle_tpu.serving.overlap import overlap_probe_fn
+def _tp_overlap_ring(devices):
+    """The split-collective ring of tensor-parallel serving."""
+    from paddle_tpu.parallel.mesh import build_mesh
+    from paddle_tpu.serving.overlap import overlap_probe_fn
 
-        mesh = build_mesh((("tp", 4),), devices=topo.devices)
-        fn = overlap_probe_fn(mesh, 256, 2)
-        args = [jax.ShapeDtypeStruct((8, 256), jnp.float32,
-                                     sharding=NamedSharding(mesh, P()))]
-        wanted = "collective-permute"
+    mesh = build_mesh((("tp", 4),), devices=devices)
+    x = jax.ShapeDtypeStruct((8, 256), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    return jax.jit(overlap_probe_fn(mesh, 256, 2)), [x], "collective-permute"
+
+
+def _ring_attention(devices):
+    """Causal ring attention over a sequence split four ways: the step
+    kernel's SMEM offsets and its `pl.when` block skip."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(devices), ("sep",))
+    spec = P(None, "sep", None, None)
+    sharding = NamedSharding(mesh, spec)
+
+    def ring(q, k, v):
+        return pk.ring_flash_attention_pallas(q, k, v, axis_name="sep",
+                                              causal=True)
+
+    fn = jax.jit(jax.shard_map(ring, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec),
+                 in_shardings=sharding, out_shardings=sharding)
+    x = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16)
+    return fn, [x, x, x], "tpu_custom_call"
+
+
+def _zero2_reduce_scatter(devices):
+    """ZeRO-2 through hapi's step (`group_sharded_parallel`, level
+    `os_g`): the TPU pipeline turns the gradient's all-reduce and shard
+    slice into a reduce-scatter, which the CPU pipeline never creates."""
+    from types import SimpleNamespace
+
+    import paddle_tpu as paddle
+    from jax.sharding import Mesh
+    from paddle_tpu import nn
+    from paddle_tpu.distributed.fleet.meta_parallel import (
+        group_sharded_parallel)
+
+    group = SimpleNamespace(mesh=Mesh(np.array(devices), ("sharding",)),
+                            axis_name="sharding")
+    paddle.seed(7)
+    net = nn.Sequential(nn.Linear(64, 256), nn.ReLU(), nn.Linear(256, 64))
+    opt = paddle.optimizer.Adam(learning_rate=0.01,
+                                parameters=net.parameters())
+    wrapped, _ = group_sharded_parallel(net, opt, level="os_g", group=group)
+    model = paddle.Model(wrapped)
+    model.prepare(optimizer=opt, loss=nn.MSELoss())
+    params, buffers = model._sync_state_in()
+    model._ensure_opt_state(params)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    data = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    args = [abstract(params), abstract(buffers), abstract(model._opt_state),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
+            (data,), (data,)]
+    return model._build_train_step(), args, "reduce-scatter"
+
+
+# over all four chips of the host
+_FOUR_CHIPS = {"tp_overlap_ring": _tp_overlap_ring,
+               "ring_attention": _ring_attention,
+               "zero2_reduce_scatter": _zero2_reduce_scatter}
+
+
+@pytest.mark.parametrize("case", [*_ONE_CHIP, *_FOUR_CHIPS])
+def test_compiles_for_v5e(topo, case):
+    if case in _FOUR_CHIPS:
+        fn, args, wanted = _FOUR_CHIPS[case](topo.devices)
     else:
         fn, shapes = _ONE_CHIP[case]()
+        fn, wanted = jax.jit(fn), "tpu_custom_call"
         one_chip = SingleDeviceSharding(topo.devices[0])
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape, dtype in shapes]
-        wanted = "tpu_custom_call"
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert wanted in compiled.as_text()
+    assert wanted in fn.lower(*args).compile().as_text()
 
 
 # ------------------------------------------ the names the trace is read by
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# The tiny programs' sizes that the gates below look for. 512 tokens a
+# row and a prefill bucket of 512 are no other width of these models
+# (two heads everywhere), and 4,096 train tokens are two of the fused
+# loss's chunks of 2,048.
+_VOCAB, _HEADS, _SEQ = 1024, 2, 512
+_TRAIN_ROWS = 8
 
 
 @pytest.fixture(scope="module")
@@ -251,12 +332,12 @@ def engine():
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.serving import ServingEngine, SpecConfig
 
-    cfg = GPTConfig(vocab_size=1024, hidden_size=256, num_hidden_layers=2,
-                    num_attention_heads=2, intermediate_size=512,
-                    max_position_embeddings=256)
+    cfg = GPTConfig(vocab_size=_VOCAB, hidden_size=256, num_hidden_layers=2,
+                    num_attention_heads=_HEADS, intermediate_size=768,
+                    max_position_embeddings=_SEQ)
     model = GPTForCausalLM(cfg).bfloat16()
     model.eval()
-    kw = dict(page_size=16, max_batch_size=4, max_seq_len=256,
+    kw = dict(page_size=16, max_batch_size=4, max_seq_len=_SEQ,
               kv_dtype="bf16")
     return {"plain": ServingEngine(model, **kw),
             "spec": ServingEngine(model, spec_config=SpecConfig(), **kw)}
@@ -295,7 +376,7 @@ def _compile_serve(eng, device, rows, bucket):
 def serve_hlo(topo, kernel_paths, engine):
     """The compiler's text of the decode block and of a bucketed prefill
     for one described chip."""
-    compiled = _compile_serve(engine["plain"], topo.devices[0], 4, 128)
+    compiled = _compile_serve(engine["plain"], topo.devices[0], 4, _SEQ)
     return {name: c.as_text() for name, c in compiled.items()}
 
 
@@ -313,9 +394,9 @@ def train_hlo(topo, kernel_paths):
     from paddle_tpu.parallel.mesh import DP_AXIS, TP_AXIS
 
     model = ErnieForPretraining(ErnieConfig(
-        vocab_size=1024, hidden_size=128, num_hidden_layers=1,
-        num_attention_heads=2, intermediate_size=256,
-        max_position_embeddings=128, hidden_dropout_prob=0.0,
+        vocab_size=_VOCAB, hidden_size=128, num_hidden_layers=1,
+        num_attention_heads=_HEADS, intermediate_size=256,
+        max_position_embeddings=_SEQ, hidden_dropout_prob=0.0,
         attention_probs_dropout_prob=0.0, fused_mlm_loss=True))
     model.train()
     _, buffers = extract_state(model)
@@ -347,7 +428,7 @@ def train_hlo(topo, kernel_paths):
                                     sharding=NamedSharding(step.mesh, P()))
 
     batch = (jax.ShapeDtypeStruct(
-        (8, 128), jnp.int32,
+        (_TRAIN_ROWS, _SEQ), jnp.int32,
         sharding=NamedSharding(step.mesh, P(DP_AXIS))),) * 2
     return step._step.lower(
         abstract(params, {k: step._spec[k] for k in params}),
@@ -499,42 +580,23 @@ def test_decode_block_s_temporaries_are_under_one_pool(serve_pool_compiled):
 @pytest.fixture(scope="module")
 def mla_moe_hlo(topo, kernel_paths):
     """The compiler's text of the decode block and of a bucketed prefill
-    of a small MlaMoe model (one dense and one expert layer, heads and
-    ranks at the published widths) behind a default engine."""
+    of a small MlaMoe model (one dense and one expert layer, head widths
+    and the latent rank as published) behind a default engine."""
     from paddle_tpu.models import MlaMoeConfig, MlaMoeForCausalLM
     from paddle_tpu.serving import ServingEngine
 
-    cfg = MlaMoeConfig(vocab_size=1024, hidden_size=256, num_hidden_layers=2,
-                       num_attention_heads=2, q_lora_rank=128,
-                       intermediate_size=512, moe_intermediate_size=128,
-                       n_routed_experts=8, num_experts_per_tok=2,
-                       dtype="bfloat16", deferred_weights=True)
+    cfg = MlaMoeConfig(vocab_size=_VOCAB, hidden_size=256,
+                       num_hidden_layers=2, num_attention_heads=_HEADS,
+                       q_lora_rank=128, intermediate_size=768,
+                       moe_intermediate_size=128, n_routed_experts=8,
+                       num_experts_per_tok=2, dtype="bfloat16",
+                       deferred_weights=True)
     model = MlaMoeForCausalLM(cfg)
     model.eval()
     eng = ServingEngine(model, page_size=16, max_batch_size=4,
-                        max_seq_len=256, kv_dtype="bf16")
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def abstract(tree):
-        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
-
-    def knobs(b):
-        return (sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
-                sds((b,), jnp.int32), sds((b,), jnp.float32))
-
-    state = (abstract(eng.params), abstract(eng.buffers))
-    pools, pages = abstract(eng.cache.pools), eng.max_pages_per_seq
-    decode = eng._decode_block_jit(8).lower(
-        *state, sds((4,), jnp.int32), pools, sds((4, pages), jnp.int32),
-        sds((4,), jnp.int32), *knobs(4), sds((4,), jnp.int32),
-        sds((4,), jnp.int32)).compile().as_text()
-    prefill = eng._prefill_jit(128).lower(
-        *state, sds((1, 128), jnp.int32), pools, sds((1, pages), jnp.int32),
-        sds((), jnp.int32), *knobs(1)).compile().as_text()
-    return {"decode_block": decode, "prefill": prefill}
+                        max_seq_len=_SEQ, kv_dtype="bf16")
+    compiled = _compile_serve(eng, topo.devices[0], 4, _SEQ)
+    return {name: c.as_text() for name, c in compiled.items()}
 
 
 def test_mla_decode_kernel_is_named_where_it_is_created(mla_moe_hlo):
@@ -586,3 +648,76 @@ def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, scope):
         # nested inside the serving scope the benchmark's list holds
         assert all(re.search(parent + r"/(?:[^/]+/)*" + scope, n)
                    for n in names)
+
+
+# --------- what the measured programs must not hold (no chip: the text)
+
+@pytest.fixture(scope="module")
+def programs(train_hlo, serve_hlo, mla_moe_hlo):
+    """The compiler's text of the programs the benchmark's cells run, by
+    name: `ZeroTrainStep` over ERNIE with the fused loss, and the decode
+    block and bucketed prefill of a default engine over GPT and over the
+    latent-attention decoder."""
+    return {"train": train_hlo,
+            **{f"gpt_{name}": text for name, text in serve_hlo.items()},
+            **{f"mla_moe_{name}": text
+               for name, text in mla_moe_hlo.items()}}
+
+
+def _arrays(text: str) -> set:
+    """Every array type the text names, as (dtype, dims)."""
+    return {(dtype, tuple(int(d) for d in dims.split(",")))
+            for dtype, dims in re.findall(
+                r"\b(pred|[su]\d+|bf16|f16|f32|f64)\[([\d,]+)\]", text)}
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("train", _TRAIN_ROWS), ("gpt_prefill", 1), ("mla_moe_prefill", 1)])
+def test_no_whole_attention_matrix(programs, program, rows):
+    """Flash keeps the scores in VMEM a block at a time: no array has
+    two dimensions of the sequence and room for every row's every head
+    (`[b, heads, s, s]` in whatever order or merged)."""
+    whole = [a for a in _arrays(programs[program])
+             if a[1].count(_SEQ) >= 2
+             and np.prod(a[1]) >= rows * _HEADS * _SEQ * _SEQ]
+    assert whole == []
+
+
+@pytest.mark.parametrize("program,tokens", [
+    ("train", _TRAIN_ROWS * _SEQ), ("mla_moe_prefill", _SEQ)])
+def test_no_whole_logits(programs, program, tokens):
+    """The fused linear + cross-entropy of the train step sees the
+    vocabulary a chunk of 2,048 tokens at a time, and the latent model's
+    prefill projects the one position it samples from (`logits_at`; at
+    the cell's 16,384 x 129,280 the whole would be 4.2 GB): no array has
+    the vocabulary beside every token of the step. GPT's prefill does
+    hold `(bucket, vocab)` today (`PERF.md` section 7) and is no case."""
+    whole = [a for a in _arrays(programs[program])
+             if _VOCAB in a[1] and np.prod(a[1]) >= tokens * _VOCAB]
+    assert whole == []
+
+
+def _matmul_operands(text: str) -> list:
+    """(op_name, operand dtypes) of every `dot` and `convolution`, the
+    two forms a matmul has in the chip's text."""
+    dtype_of = dict(re.findall(r"%([\w.\-]+) = (\w+)\[", text))
+    found = re.findall(r" (?:convolution|dot)\(([^)]*)\)[^\n]*?"
+                       r'op_name="([^"]*)"', text)
+    return [(op_name, [dtype_of[name] for name in
+                       re.findall(r"%([\w.\-]+)", operands)])
+            for operands, op_name in found]
+
+
+@pytest.mark.parametrize("program", [
+    "train", "gpt_decode_block", "gpt_prefill", "mla_moe_decode_block",
+    "mla_moe_prefill"])
+def test_every_matmul_takes_bf16_operands(programs, program):
+    """One float32 operand forfeits the MXU's bf16 rate. No exception is
+    needed: where the design computes in float32 (the latent model's
+    router, the fused loss's logits and its weight gradient) the
+    operands were bf16 before the cast, so the compiler folds the cast
+    into the matmul and keeps the float32 in the result alone. The
+    experts' grouped matmuls are kernels, not in this list."""
+    matmuls = _matmul_operands(programs[program])
+    assert len(matmuls) >= 9
+    assert [m for m in matmuls if set(m[1]) != {"bf16"}] == []

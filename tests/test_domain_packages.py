@@ -413,21 +413,6 @@ class TestProfiler:
             pass  # must not raise or leak into any profiler
 
 
-def test_tape_overhead_benchmark_smoke():
-    """benchmarks/tape_overhead.py runs and yields sane numbers."""
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "tape_overhead.py")
-    spec = importlib.util.spec_from_file_location("tape_overhead", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    out = mod.measure(n_ops=5)
-    assert out["per_op_us"]["dispatch_tape"] > 0
-    assert out["train_step_ms"]["jitted_functional"] > 0
-
-
 def test_check_nan_inf_flag_guards_jitted_paths():
     """FLAGS_check_nan_inf must catch NaNs in BOTH regimes: eager dispatch
     (op-output check) and jitted steps (jax_debug_nans wiring)."""

@@ -556,37 +556,43 @@ def test_recompute_accepts_none_args_and_matches():
     """r5 regression: a literal None argument (attention_mask=None) used to
     collide with recompute's tensor-slot sentinel and crash; and the
     rematerialized backward must reproduce the exact losses (dropout keys
-    ride the functional trace stream)."""
+    ride the functional trace stream). Two steps of the one measured
+    trainer, `ZeroTrainStep`."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.jit.functional import extract_state
+    from paddle_tpu.jit.functional import call_functional, extract_state
     from paddle_tpu.models import ErnieConfig, ErnieForPretraining
-    import bench
+    from paddle_tpu.parallel import ZeroTrainStep
 
     def run(recompute):
         paddle.seed(3)
         cfg = ErnieConfig.tiny()
         cfg.recompute = recompute
+        cfg.fused_mlm_loss = True
         model = ErnieForPretraining(cfg)
         model.train()
         opt = paddle.optimizer.Adam(learning_rate=1e-3,
                                     parameters=model.parameters())
-        params, buffers = extract_state(model)
-        opt_state = opt.functional_state(params)
-        step = jax.jit(bench.make_train_step(model, opt))
+        _, buffers = extract_state(model)
+        key = jax.random.key(7)
+
+        def loss_fn(params, ids, labels):
+            (loss, _nsp), _ = call_functional(
+                model, params, buffers, (ids, None, None, None, labels),
+                rng_key=key, training=True)
+            return loss.astype(jnp.float32)
+
+        step = ZeroTrainStep(model, opt, loss_fn, stage=0, dp=1)
+        params, state = step.init_state()
         ids = jnp.asarray(np.random.RandomState(0).randint(
             0, cfg.vocab_size, (2, 32)))
-        paddle.seed(7)
-        from paddle_tpu.core.rng import default_generator
-
         losses = []
         for t in range(1, 3):
-            key = default_generator().next_key()
-            loss, params, buffers, opt_state = step(
-                params, buffers, opt_state, jnp.float32(1e-3),
-                jnp.int32(t), key, ids, ids)
+            loss, params, state = step(params, state, (ids, ids), 1e-3, t)
             losses.append(float(np.asarray(loss)))
         return losses
 
-    np.testing.assert_allclose(run(False), run(True), rtol=1e-5)
+    dense, remat = run(False), run(True)
+    assert dense[1] < dense[0]      # the update was applied
+    np.testing.assert_allclose(dense, remat, rtol=1e-5)

@@ -147,7 +147,7 @@ def test_model_forward(ctor, chw):
     assert out.shape == [2, 10]
 
 
-def test_model_zoo_constructs():
+def test_model_zoo_constructs(host_drawn_weights):
     # constructor-only smoke (forwards are expensive on CPU)
     for ctor in (vgg11, mobilenet_v1):
         net = ctor(num_classes=4)

@@ -4,7 +4,7 @@ gather_rows is the dispatch/combine primitive: out[m] = src[idx[m]] with
 zero rows for over-capacity slots, scatter-add transpose for grads. The
 fused _routed_forward must match the einsum reference bit-for-tolerance,
 forward AND backward, in interpret mode on CPU; the Mosaic compile of the
-kernel itself is covered by the AOT tier in test_hlo_perf.py.
+kernel itself is a case of tests/test_chip_compile.py.
 """
 import numpy as np
 import pytest

@@ -11,8 +11,7 @@ or parse errors, 2 usage error.
 
 The analysis package is pure stdlib; this entry point loads it WITHOUT
 importing `paddle_tpu` (which would pull in jax) so linting stays
-sub-second and backend-free — cheap enough for the fast lane and for
-bench.py's non-fatal `lint` phase.
+backend-free and cheap enough for the fast lane.
 """
 import argparse
 import importlib.util

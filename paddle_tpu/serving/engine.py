@@ -110,7 +110,20 @@ def _sample_batch(logits, keys, temps, top_ks, top_ps):
     """Per-row sampling with TRACED knobs (the batch mixes requests with
     different sampling params). Mirrors generation._sample row-wise:
     greedy where temperature == 0, else temperature -> top-k -> top-p ->
-    categorical."""
+    categorical. A batch whose rows are ALL greedy (padding and parked
+    rows carry temperature 0) takes the argmax alone: the conditional's
+    predicate is a scalar the program computes from its own input, so
+    the device runs one branch and no executable is added."""
+    return jax.lax.cond(
+        jnp.all(temps == 0.0),
+        lambda: jnp.argmax(logits.astype(jnp.float32), axis=-1),
+        lambda: _sample_rows(logits, keys, temps, top_ks, top_ps))
+
+
+def _sample_rows(logits, keys, temps, top_ks, top_ps):
+    """The branch of `_sample_batch` for a batch in which some row
+    samples: two sorts, a softmax and a cumulative sum over the whole
+    vocabulary, for every row; greedy rows among them keep the argmax."""
     vocab = logits.shape[-1]
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1)
@@ -178,6 +191,12 @@ class ServingObs:
                             "(prefill, chunk, decode block, or ragged "
                             "step) — the per-step launch cost the "
                             "ragged executable collapses to one")
+        self.greedy_dispatches = c(
+            "serving_greedy_dispatches_total",
+            "device program launches whose rows were all at "
+            "temperature 0: the sampler took the argmax alone (over "
+            "serving_dispatches_total, the share of launches that "
+            "skipped the sort of the vocabulary)")
         self.preemptions = c("serving_preemptions_total",
                              "requests preempted and requeued")
         self.prefill_seconds = c("serving_prefill_seconds_total",
@@ -280,6 +299,14 @@ class ServingObs:
         # expert-layer handles, bound by bind_moe() only for a model
         # whose steps return an expert histogram
         self.moe_pairs = None
+
+    def dispatched(self, greedy: bool) -> None:
+        """One device program launched; `greedy` is the host's reading of
+        the predicate `_sample_batch` takes on the device, from the same
+        float32 temperatures (the host's own arrays: no sync)."""
+        self.dispatches.inc()
+        if greedy:
+            self.greedy_dispatches.inc()
 
     def bind_moe(self) -> None:
         """Expert-layer observability: counters fed from the (layers,
@@ -1347,7 +1374,7 @@ class ServingEngine:
         prev_t = req.last_token_t            # set => this is a re-prefill
         if o is not None:
             o.prefill_steps.inc()
-            o.dispatches.inc()
+            o.dispatched(np.float32(sp.temperature) == 0.0)
             o.host_syncs.inc()
             o.prefill_seconds.inc(now - t0)
             o.lifecycle.span(req.request_id, "prefill", t0, now)
@@ -1462,7 +1489,7 @@ class ServingEngine:
         o = self._obs
         if o is not None:
             o.prefill_chunks.inc()
-            o.dispatches.inc()
+            o.dispatched(np.float32(sp.temperature) == 0.0)
             o.prefill_seconds.inc(now - t0)
             # profiler-only spans for intermediate chunks (retained
             # lifecycle lists must not grow per chunk); the final chunk
@@ -1708,7 +1735,7 @@ class ServingEngine:
                     o.prefill_steps.inc()
         if o is not None:
             o.ragged_steps.inc()
-            o.dispatches.inc()
+            o.dispatched(not batch.temps.any())
             o.step_phase["assemble"].observe(t0 - t_in)
             o.step_phase["dispatch"].observe(now - t0)
             if decode:
@@ -1857,6 +1884,7 @@ class ServingEngine:
             kds.extend([jnp.zeros((2,), jnp.uint32)] * (b - len(reqs)))
             knobs = (jnp.asarray(temps), jnp.asarray(top_ks),
                      jnp.asarray(top_ps), jnp.asarray(eos_ids))
+            greedy = not temps.any()
             tokens = jnp.asarray(tokens)
             positions = jnp.asarray(positions)
             remaining = jnp.asarray(remaining)
@@ -1866,7 +1894,7 @@ class ServingEngine:
             # no host sync anywhere on this path
             tokens, positions = prev["tokens"], prev["positions"]
             key_data, remaining = prev["key_data"], prev["remaining"]
-            knobs = prev["knobs"]
+            knobs, greedy = prev["knobs"], prev["greedy"]
         # in-flight accounting: the block may add up to min(h, budget)
         # tokens per row before the host sees them; _ensure_decode_pages
         # reserves against this bound before the NEXT block (applied
@@ -1909,7 +1937,7 @@ class ServingEngine:
             self._obs.step_phase["assemble"].observe(t0 - t_in)
             self._obs.step_phase["dispatch"].observe(t1 - t0)
             self._obs.decode_steps.inc()
-            self._obs.dispatches.inc()
+            self._obs.dispatched(greedy)
             self._obs.decode_rows_live.inc(live)
             self._obs.decode_rows_dispatched.inc(b)
             if self._last_decode_dispatch_t is not None:
@@ -1924,7 +1952,7 @@ class ServingEngine:
             "rids": rids, "reqs": list(reqs), "incr": incr,
             "emitted": emitted, "tokens": tokens, "positions": positions,
             "key_data": key_data, "remaining": remaining, "knobs": knobs,
-            "t0": t0,
+            "greedy": greedy, "t0": t0,
         }
         if aux:
             self._pending["moe_hist"] = aux[0]["moe_expert_tokens"]
@@ -2071,7 +2099,7 @@ class ServingEngine:
             self._obs.step_phase["assemble"].observe(t0 - t_in)
             self._obs.step_phase["dispatch"].observe(t1 - t0)
             self._obs.decode_steps.inc()
-            self._obs.dispatches.inc()
+            self._obs.dispatched(not temps.any())
             self._obs.decode_rows_live.inc(live)
             self._obs.decode_rows_dispatched.inc(b)
             if self._last_decode_dispatch_t is not None:

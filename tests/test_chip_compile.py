@@ -721,3 +721,58 @@ def test_every_matmul_takes_bf16_operands(programs, program):
     matmuls = _matmul_operands(programs[program])
     assert len(matmuls) >= 9
     assert [m for m in matmuls if set(m[1]) != {"bf16"}] == []
+
+
+# ------------- a batch of greedy rows never runs the sampler's sorts
+
+def _computations(text: str) -> tuple:
+    """The text's computations as ({name: body}, the entry's name)."""
+    found = re.findall(r"^(ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+                       re.M | re.S)
+    entry, = [name for is_entry, name, _ in found if is_entry]
+    return {name: body for _, name, body in found}, entry
+
+
+def _reached_outside_branches(text: str) -> dict:
+    """{name: body} of the computations the entry reaches (fusions, loop
+    bodies and conditions, reducers) without entering a branch of a
+    `conditional`: what the device runs whatever the predicates read."""
+    comps, entry = _computations(text)
+    called = re.compile(r"\w+=\{?(%[\w.\-]+(?:, %[\w.\-]+)*)\}?")
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name].splitlines():
+            if " conditional(" in line:
+                continue
+            for group in called.findall(line):
+                todo.extend(n.lstrip("%") for n in group.split(", ")
+                            if n.lstrip("%") in comps)
+    return {name: comps[name] for name in seen}
+
+
+def _sampler_sorts(body: str) -> list:
+    """The `sort`s of the scope `sampling` among a computation's lines
+    (the expert layer sorts its pairs by expert: another scope's)."""
+    return [line for line in body.splitlines() if " sort(" in line
+            and _scoped(line, scopes.SAMPLING)]
+
+
+@pytest.mark.parametrize("program", [
+    "gpt_decode_block", "gpt_prefill", "mla_moe_decode_block",
+    "mla_moe_prefill"])
+def test_sampler_sorts_only_inside_a_conditional_s_branch(programs, program):
+    """`_sample_batch` sorts the vocabulary (twice) only where some row
+    of the batch samples: in the compiler's text both sorts sit in a
+    branch of a `conditional`, none in the entry computation or in the
+    decode loop's body, where every greedy step would pay for them. A
+    `select` in the conditional's place (a `vmap` over the sampler, a
+    `where` on the predicate) runs both sides and fails here."""
+    text = programs[program]
+    always = _reached_outside_branches(text)
+    assert [(name, line[:120]) for name, body in always.items()
+            for line in _sampler_sorts(body)] == []
+    assert len(_sampler_sorts(text)) == 2

@@ -1,6 +1,7 @@
 """The engine's counters at dispatch and admission, and the attributes
 its spans carry: host integers and clocks, counted exactly, and not at
-all with `enable_metrics=False`."""
+all with `enable_metrics=False`; among them the launches whose rows were
+all greedy, which took the argmax alone."""
 import functools
 import time
 
@@ -10,7 +11,8 @@ import paddle_tpu as paddle
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.observability import MetricsRegistry
 from paddle_tpu.serving import (BlockAllocator, Request, SamplingParams,
-                                Scheduler, ServingEngine, ServingObs)
+                                Scheduler, ServingEngine, ServingObs,
+                                SpecConfig)
 
 HORIZON = 4
 MAX_BATCH = 4
@@ -65,6 +67,72 @@ def test_dispatch_and_admission_counters_count_exactly():
             for r in sorted(eng.requests)] == budgets
 
 
+_SAMPLED = dict(temperature=0.8, top_k=7, top_p=0.9, seed=42)
+
+# every family of launch the engine has: the bucketed prefill and the
+# decode block; the chained chunk pipeline; the ragged step; the
+# speculative block
+_FAMILIES = {
+    "prefill_and_decode": {},
+    "chunk": dict(enable_chunked_prefill=True, prefill_chunk_tokens=8,
+                  enable_ragged_step=False),
+    "ragged": dict(enable_chunked_prefill=True, prefill_chunk_tokens=8),
+    "spec": dict(spec_config=SpecConfig(lookahead=2)),
+}
+
+
+def _dispatches(obs):
+    return obs.dispatches.value, obs.greedy_dispatches.value
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_greedy_dispatches_count_the_launches_that_skipped_the_sort(family):
+    """`serving_greedy_dispatches_total` over `serving_dispatches_total`
+    is the share of launches whose rows were all at temperature 0: all of
+    them in a greedy run, none of a sampled request's own, and the
+    difference is exactly the launches that carried a sampling row."""
+    eng = _engine(**_FAMILIES[family])
+    obs = eng._obs
+    assert eng.metrics.get("serving_greedy_dispatches_total") is \
+        obs.greedy_dispatches
+    for prompt, n in (([3, 5, 7, 11, 13, 17, 19, 23, 29, 31], 9),
+                      ([2, 4, 6], 6)):
+        eng.add_request(prompt, max_new_tokens=n, temperature=0.0)
+    eng.run()
+    launched, greedy = _dispatches(obs)
+    assert launched == greedy > 2
+    # a sampling request alone: none of its launches is greedy (its
+    # prompt is one chunk: a ragged step gives an intermediate chunk's
+    # row, which samples nothing, temperature 0)
+    eng.add_request([1, 2, 3, 4, 5, 6], max_new_tokens=7, **_SAMPLED)
+    eng.run()
+    launched_2, greedy_2 = _dispatches(obs)
+    assert launched_2 > launched + 1 and greedy_2 == greedy
+    # mixed: the greedy request outlives the sampling one, so the batch
+    # turns greedy again and the launches after that count
+    eng.add_request([5, 4, 3], max_new_tokens=3, **_SAMPLED)
+    eng.add_request([9, 8, 7, 6], max_new_tokens=14, temperature=0.0)
+    eng.run()
+    launched_3, greedy_3 = _dispatches(obs)
+    assert greedy_2 < greedy_3 < greedy_2 + (launched_3 - launched_2)
+
+
+def test_a_chained_block_inherits_its_first_block_s_reading():
+    """A chained decode block takes its knobs from the block before it
+    (no host arrays): it counts as that block counted."""
+    eng = _engine()
+    eng.add_request([1, 2, 3], max_new_tokens=3 * HORIZON + 1,
+                    temperature=0.0)
+    eng.run()
+    assert eng._obs.decode_steps.value >= 3
+    assert _dispatches(eng._obs)[0] == _dispatches(eng._obs)[1]
+    eng.add_request([1, 2, 3], max_new_tokens=3 * HORIZON + 1, **_SAMPLED)
+    before = _dispatches(eng._obs)
+    eng.run()
+    after = _dispatches(eng._obs)
+    assert after[0] - before[0] >= 4 and after[1] == before[1]
+
+
 def test_a_requeued_request_counts_again_from_its_requeue():
     obs = ServingObs(MetricsRegistry())
     sched = Scheduler(BlockAllocator(6), page_size=4, max_batch_size=2,
@@ -89,14 +157,16 @@ def test_a_requeued_request_counts_again_from_its_requeue():
     assert 0.0 <= obs.queue_wait_seconds.value - first < 1.0
 
 
-def test_metrics_off_touches_no_counter(monkeypatch):
+@pytest.mark.parametrize("knobs", [dict(temperature=0.0), _SAMPLED],
+                         ids=["greedy", "sampled"])
+def test_metrics_off_touches_no_counter(monkeypatch, knobs):
     import paddle_tpu.observability.metrics as obsm
 
     eng = _engine(enable_metrics=False)
     assert eng._obs is None and eng.scheduler.obs is None
     # warm first: tracing may count its dispatch selections in the
     # global registry
-    eng.add_request([9, 8, 7], max_new_tokens=6, temperature=0.0)
+    eng.add_request([9, 8, 7], max_new_tokens=6, **knobs)
     eng.run()
 
     def boom(*a, **kw):
@@ -104,7 +174,7 @@ def test_metrics_off_touches_no_counter(monkeypatch):
 
     monkeypatch.setattr(obsm.MetricsRegistry, "counter", boom)
     monkeypatch.setattr(obsm.Counter, "inc", boom)
-    rid = eng.add_request([1, 2, 3], max_new_tokens=6, temperature=0.0)
+    rid = eng.add_request([1, 2, 3], max_new_tokens=6, **knobs)
     assert len(eng.run()[rid]) == 3 + 6
 
 

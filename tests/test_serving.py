@@ -972,6 +972,142 @@ class TestServingSampling:
         assert eng.compile_counts()["sample"] <= 2
 
 
+def _sample_batch_before(logits, keys, temps, top_ks, top_ps):
+    """`engine._sample_batch` as it stood before a batch of greedy rows
+    took the argmax alone (PR 31's body, kept here as the reference):
+    every row is sorted twice whatever its temperature."""
+    vocab = logits.shape[-1]
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1)
+    t_safe = jnp.where(temps > 0.0, temps, 1.0)
+    scaled = logits / t_safe[:, None]
+    k_eff = jnp.where(top_ks > 0, jnp.minimum(top_ks, vocab), vocab)
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
+    masked = jnp.where(scaled < kth, -jnp.inf, scaled)
+    sorted_m = jnp.sort(masked, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sorted_m, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cutoff_idx = jnp.minimum(
+        jnp.sum(cum < top_ps[:, None], axis=-1, keepdims=True), vocab - 1)
+    cutoff = jnp.take_along_axis(sorted_m, cutoff_idx, axis=-1)
+    masked = jnp.where(masked < cutoff, -jnp.inf, masked)
+    sampled = jax.vmap(jax.random.categorical)(keys, masked)
+    return jnp.where(temps == 0.0, greedy, sampled)
+
+
+_ROWS, _VOCAB_S, _STEPS = 6, 97, 3
+
+# (temperatures, top_k, top_p) of the six rows; a scalar fills every row
+_SAMPLER_CASES = {
+    "all_greedy": (0.0, 0, 1.0),
+    # rows 4 and 5 are the padding of a power-of-two batch: 0 / 0 / 1.0
+    "one_sampler_among_greedy_and_padding":
+        ([0.0, 0.0, 0.7, 0.0, 0.0, 0.0], [0, 5, 40, 0, 0, 0],
+         [1.0, 0.9, 0.8, 1.0, 1.0, 1.0]),
+    "sampling_k0_p1": (0.9, 0, 1.0),
+    "sampling_k0_p09": (0.9, 0, 0.9),
+    "sampling_k5_p1": (0.9, 5, 1.0),
+    "sampling_k5_p09": (0.9, 5, 0.9),
+    "sampling_k_over_vocab_p1": (1.3, 4 * _VOCAB_S, 1.0),
+    "sampling_k_over_vocab_p09": (1.3, 4 * _VOCAB_S, 0.9),
+    "ties_greedy": (0.0, 0, 1.0),
+    "ties_mixed": ([0.0, 0.6, 0.0, 1.0, 0.0, 0.0], 3, 0.95),
+}
+
+
+class TestSampleBatch:
+    """A batch whose rows are all greedy takes the argmax alone, under a
+    `lax.cond` on a predicate the sampler reads from its own
+    temperatures; a batch in which any row samples runs the arithmetic
+    it always ran. Every case is held to the old body bit for bit."""
+
+    @staticmethod
+    def _inputs(case, dtype):
+        rng = np.random.default_rng(len(case))
+        logits = rng.normal(size=(_STEPS, _ROWS, _VOCAB_S)) * 3.0
+        if case.startswith("ties"):
+            # a handful of distinct values under a ceiling: the maximum
+            # repeats in every row, and the k-th and the nucleus cut fall
+            # inside ties
+            logits = np.minimum(np.round(logits), 3.0)
+        temps, top_ks, top_ps = (
+            np.broadcast_to(np.asarray(v, t), (_ROWS,))
+            for v, t in zip(_SAMPLER_CASES[case],
+                            (np.float32, np.int32, np.float32)))
+        key_data = rng.integers(0, 2 ** 32, size=(_ROWS, 2),
+                                dtype=np.uint32)
+        return (jnp.asarray(logits, dtype), jnp.asarray(key_data),
+                jnp.asarray(temps), jnp.asarray(top_ks),
+                jnp.asarray(top_ps))
+
+    @pytest.mark.parametrize("how", ["jit", "scan"])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("case", sorted(_SAMPLER_CASES))
+    def test_matches_the_old_body_bit_for_bit(self, case, dtype, how):
+        from paddle_tpu.serving.engine import _sample_batch, _split_rows
+
+        logits, key_data, *knobs = self._inputs(case, dtype)
+
+        def one_step(sampler):
+            _, keys = _split_rows(key_data)
+            return sampler(logits[0], keys, *knobs)
+
+        def scanned(sampler):
+            # the decode block's shape: the key chain is the carry, the
+            # knobs are closed over
+            def body(kd, step_logits):
+                kd, keys = _split_rows(kd)
+                return kd, sampler(step_logits, keys, *knobs)
+            return jax.lax.scan(body, key_data, logits)
+
+        run = one_step if how == "jit" else scanned
+        got = jax.jit(lambda: run(_sample_batch))()
+        want = jax.jit(lambda: run(_sample_batch_before))()
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        if case.startswith("ties") and how == "jit":
+            # a greedy row's token is the FIRST index of its maximum
+            row = np.asarray(logits[0].astype(jnp.float32))[0]
+            assert int(np.asarray(got)[0]) == int(
+                np.flatnonzero(row == row.max())[0])
+            assert (row == row.max()).sum() > 1
+
+    @pytest.mark.parametrize("traffic", ["all_greedy", "mixed"])
+    def test_engine_streams_and_executables_are_the_old_sampler_s(
+            self, traffic, monkeypatch):
+        """The same requests with the same seeds through two engines, one
+        tracing today's sampler and one the old body: the same token
+        streams, and `compile_counts()` is the same dictionary (no
+        executable added for the greedy branch)."""
+        import paddle_tpu.serving.engine as eng_mod
+
+        def run():
+            eng = ServingEngine(_llama(), page_size=8, max_batch_size=4,
+                                max_seq_len=32, prefill_buckets=(16, 32),
+                                decode_horizon=4)
+            sampled = dict(temperature=0.8, top_k=7, top_p=0.9, seed=42)
+            rids = [
+                eng.add_request([3, 1, 4, 1, 5], max_new_tokens=9,
+                                temperature=0.0),
+                eng.add_request([2, 7, 1], max_new_tokens=6,
+                                **(sampled if traffic == "mixed"
+                                   else dict(temperature=0.0))),
+                eng.add_request([8, 2, 8, 1, 8, 2], max_new_tokens=11,
+                                temperature=0.0)]
+            outs = eng.run()
+            return [outs[r] for r in rids], eng.compile_counts()
+
+        streams, counts = run()
+        monkeypatch.setattr(eng_mod, "_sample_batch", _sample_batch_before)
+        streams_before, counts_before = run()
+        assert streams == streams_before
+        assert counts == counts_before
+        assert counts["sample"] == 0 and counts["decode"] >= 1
+
+
 # ------------------------------------------------------- decode horizon
 
 class TestDecodeHorizon:

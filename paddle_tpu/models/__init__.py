@@ -18,7 +18,9 @@ from .t5 import (  # noqa: F401
 
 
 _LAZY = {"MlaMoeConfig": "mla_moe", "MlaMoeModel": "mla_moe",
-         "MlaMoeForCausalLM": "mla_moe"}
+         "MlaMoeForCausalLM": "mla_moe",
+         "HybridSsmConfig": "hybrid_ssm", "HybridSsmModel": "hybrid_ssm",
+         "HybridSsmForCausalLM": "hybrid_ssm"}
 
 
 def __getattr__(name):

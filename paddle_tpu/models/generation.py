@@ -76,6 +76,11 @@ def init_caches(model, batch, max_len, dtype=jnp.float32):
             f"{type(model).__name__} caches a latent row a token, which "
             "the static (k, v) cache of models.generation cannot hold: "
             "serve it through paddle_tpu.serving.ServingEngine")
+    if getattr(cfg, "state_cache_spec", None) is not None:
+        raise NotImplementedError(
+            f"{type(model).__name__} keeps a recurrent state a layer, "
+            "which the static (k, v) cache of models.generation cannot "
+            "hold: serve it through paddle_tpu.serving.ServingEngine")
     kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     head_dim = cfg.hidden_size // cfg.num_attention_heads
     shape = (batch, max_len, kv_heads, head_dim)

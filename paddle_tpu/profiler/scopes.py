@@ -44,8 +44,20 @@ MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
 MOE_SHARED = "moe_shared"
 MLA_ABSORB = "mla_absorb"
+# models/hybrid_ssm.py, the parts of a Mamba-2 mixer: the first norm and
+# the input projection, and the causal conv (under `attn_qkv`); the
+# one-token state update of a decode step (under `paged_attention`) and
+# the chunked scan of a prefill with its write of the slot (under
+# `prefill_attention`); the gate, the gated norm, the output projection
+# and the residual (under `attn_out`)
+SSM_IN_PROJ = "ssm_in_proj"
+SSM_CONV = "ssm_conv"
+SSM_STATE_UPDATE = "ssm_state_update"
+SSM_CHUNK_SCAN = "ssm_chunk_scan"
+SSM_GATE_OUT = "ssm_gate_out"
 SERVE_SUBSCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
-                   MOE_SHARED, MLA_ABSORB)
+                   MOE_SHARED, MLA_ABSORB, SSM_IN_PROJ, SSM_CONV,
+                   SSM_STATE_UPDATE, SSM_CHUNK_SCAN, SSM_GATE_OUT)
 TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
                 OPTIMIZER_UPDATE)
 
@@ -55,6 +67,8 @@ PAGED_DECODE_KERNEL = "paged_decode"
 PAGED_RAGGED_KERNEL = "paged_ragged"
 # the absorbed-form decode kernel over a latent pool, `%mla_decode.N`
 MLA_DECODE_KERNEL = "mla_decode"
+# the in-place state update of serving/ssm.py, `%ssm_decode.N`
+SSM_DECODE_KERNEL = "ssm_decode"
 
 # jitted executables: the trace's `XLA Modules` line reads
 # `jit_<name>`; a family is its first word

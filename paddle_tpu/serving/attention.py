@@ -232,8 +232,11 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
             slots.reshape(-1), (b * s,))
 
         def write(pool, vals):
-            return _write_pages(pool, vals.reshape(*n, *vals.shape[2:]),
-                                entries, slots)
+            # a pool of packed heads takes a token's (kv_heads, hd) as
+            # its (kv_heads / g, g * hd): the same bytes
+            return _write_pages(
+                pool, vals.reshape(*n, pool.shape[0], pool.shape[-1]),
+                entries, slots)
 
         kp, vp = write(kp, kd), write(vp, vd)
         ks_pool, vs_pool = cache.k_scale, cache.v_scale
@@ -243,7 +246,8 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
             ks_pool = write(ks_pool, k_sc.reshape(b, s, -1, 1))
             vs_pool = write(vs_pool, v_sc.reshape(b, s, -1, 1))
         new_cache = PagedLayerCache(kp, vp, page_table, cache.row_ids,
-                                    k_scale=ks_pool, v_scale=vs_pool)
+                                    k_scale=ks_pool, v_scale=vs_pool,
+                                    head_pack=cache.head_pack)
 
     # the exact prefill attends this step's own K/V block; every other
     # branch reaches K/V through the page table: pool views, pads, the
@@ -294,6 +298,44 @@ def _expand_kv(x, rep):
     return jnp.repeat(x, rep, axis=2) if rep > 1 else x
 
 
+def _gathered(g, rows: int, hd: int):
+    """Pages gathered through a page table, (kvh, rows, maxP, ps, width),
+    as the contiguous (rows, L, kv heads, hd) the reference paths attend:
+    a row of packed heads (`width` a multiple of `hd`) falls apart into
+    its heads; a scale slab is gathered with `hd` 1."""
+    kvh, _, mp, ps, width = g.shape
+    return jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(
+        rows, mp * ps, kvh * width // hd, hd)
+
+
+def _pack_queries(qg, pack: int):
+    """(n, kvh, G, hd) grouped queries for a pool of `pack` heads a row:
+    (n, kvh / pack, pack * G, pack * hd), the queries of head j of a row
+    block in columns [j * hd, (j + 1) * hd) and zero elsewhere, so that
+    their scores against the packed row are their own head's (0 * k of
+    the neighbour adds nothing) and the kernel runs unchanged over rows
+    that fill whole 128-lane tiles."""
+    if pack == 1:
+        return qg
+    n, kvh, g, hd = qg.shape
+    q5 = qg.reshape(n, kvh // pack, pack, g, hd)
+    return jnp.concatenate([
+        jnp.pad(q5[:, :, j], ((0, 0),) * 3
+                + ((j * hd, (pack - 1 - j) * hd),)) for j in range(pack)],
+        axis=2)
+
+
+def _unpack_context(out, pack: int, g: int, hd: int):
+    """Inverse of `_pack_queries` on the kernel's output: a query of head
+    j reads its own head's columns of the packed value rows."""
+    if pack == 1:
+        return out
+    n, kp = out.shape[:2]
+    o6 = out.reshape(n, kp, pack, g, pack, hd)
+    return jnp.stack([o6[:, :, j, :, j] for j in range(pack)],
+                     axis=2).reshape(n, kp * pack, g, hd)
+
+
 def _crop_bias(bias, length: int) -> jnp.ndarray:
     """Additive bias (1, heads, s, L) -> (1, heads, s, length): crop or
     zero-pad the key axis (the paged step's key extent is maxP*page_size,
@@ -340,16 +382,13 @@ def _prefill_attention_paged(q, cache: PagedLayerCache, pos, rep,
     ps = cache.page_size
     length = page_table.shape[1] * ps
 
-    def gather(pool, scale=None):
-        g = pool[:, page_table]                  # (kvh, b, maxP, ps, hd)
-        kvh, _, mp, _, hd = g.shape
-        out = jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(
-            b, mp * ps, kvh, hd)
+    def gather(pool, scale=None, hd=q.shape[-1]):
+        out = _gathered(pool[:, page_table], b, hd)
         if scale is None:
             return out
         # quantized pool: dequantize against the gathered scale slab
         # ((kvh, b, maxP, ps, 1) -> (b, L, kvh, 1) by the same permute)
-        return out.astype(jnp.float32) * gather(scale)
+        return out.astype(jnp.float32) * gather(scale, hd=1)
 
     kf = _expand_kv(gather(kp, cache.k_scale), rep)
     vf = _expand_kv(gather(vp, cache.v_scale), rep)
@@ -389,6 +428,7 @@ def paged_decode_attention(q, cache: PagedLayerCache, pos, rep,
                                    cache.page_table, pos,
                                    k_scale=cache.k_scale,
                                    v_scale=cache.v_scale,
+                                   pack=cache.head_pack,
                                    interpret=KERNEL_MODE == "interpret")
         return Tensor(out)
     _count_dispatch("decode_reference_quant" if cache.quantized
@@ -408,14 +448,11 @@ def _paged_decode_reference(q, cache, pos, rep, bias=None):
     ps = cache.page_size
     length = page_table.shape[1] * ps
     # (kvh, b, maxP, ps, hd) -> (b, L, kvh, hd)
-    def gather(pool, scale=None):
-        g = pool[:, page_table]
-        kvh, _, mp, _, hd = g.shape
-        out = jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(
-            b, mp * ps, kvh, hd)
+    def gather(pool, scale=None, hd=q.shape[-1]):
+        out = _gathered(pool[:, page_table], b, hd)
         if scale is None:
             return out
-        return out.astype(jnp.float32) * gather(scale)
+        return out.astype(jnp.float32) * gather(scale, hd=1)
 
     kf = _expand_kv(gather(kp, cache.k_scale), rep)
     vf = _expand_kv(gather(vp, cache.v_scale), rep)
@@ -464,6 +501,7 @@ def ragged_paged_attention(q, cache: PagedLayerCache, pos, rep, bias=None):
                                    cache.row_ids,
                                    k_scale=cache.k_scale,
                                    v_scale=cache.v_scale,
+                                   pack=cache.head_pack,
                                    interpret=KERNEL_MODE == "interpret")
         return Tensor(out)
     _count_dispatch("ragged_reference_quant" if cache.quantized
@@ -491,14 +529,11 @@ def _ragged_attention_reference(q, cache, pos, rep, bias=None):
     length = page_table.shape[1] * ps
     pt = page_table[rows]                             # (T, maxP)
 
-    def gather(pool, scale=None):
-        g = pool[:, pt]                    # (kvh, T, maxP, pgsz, hd)
-        kvh, _, mp, _, hd = g.shape
-        out = jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(
-            t, mp * ps, kvh, hd)
+    def gather(pool, scale=None, hd=q.shape[-1]):
+        out = _gathered(pool[:, pt], t, hd)
         if scale is None:
             return out
-        return out.astype(jnp.float32) * gather(scale)
+        return out.astype(jnp.float32) * gather(scale, hd=1)
 
     kf = _expand_kv(gather(kp, cache.k_scale), rep)
     vf = _expand_kv(gather(vp, cache.v_scale), rep)
@@ -682,30 +717,37 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
 # jitted so that a step with many layers traces the kernel and lowers it
 # to Mosaic once, not once a layer: the layers' calls share one function
 # of the module (a decode executable's set-up time, PERF.md section 6)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("pack", "interpret"))
 def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
-                         k_scale=None, v_scale=None, interpret=False):
-    """q: (b, 1, heads, hd); pools: (kvh, P, ps, hd); page_table: (b,
-    maxP) i32; pos: (b,) i32; k_scale/v_scale: optional (kvh, P, ps, 1)
-    fp32 scale slabs (quantized pools). Returns (b, 1, heads, hd)."""
+                         k_scale=None, v_scale=None, pack=1,
+                         interpret=False):
+    """q: (b, 1, heads, hd); pools: (kvh, P, ps, hd), or (kvh / pack, P,
+    ps, pack * hd) with `pack` heads a row (the view's `head_pack`);
+    page_table: (b, maxP) i32; pos: (b,) i32; k_scale/v_scale: optional
+    (kvh, P, ps, 1) fp32 scale slabs (quantized pools). Returns (b, 1,
+    heads, hd)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, _, heads, hd = q.shape
-    kvh, _, ps, _ = k_pool.shape
-    rep = heads // kvh
+    kvh, _, ps, width = k_pool.shape
+    # a pool of packed heads: `kvh` row blocks of `pack` heads each
+    rep = heads // (kvh * pack)
     max_pages = page_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
 
-    d_p = _round_up(hd, 128)
-    g_p = _round_up(rep, 8)
+    d_p = _round_up(width, 128)
+    g_p = _round_up(pack * rep, 8)
     # (b, kvh, G, hd): q head h*rep + g attends kv head h — matches the
     # repeat(axis=2) expansion of the reference path
-    qg = q.reshape(b, kvh, rep, hd)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - rep), (0, d_p - hd)))
-    kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
-    vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
+    qg = _pack_queries(q.reshape(b, kvh * pack, rep, hd), pack)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - pack * rep),
+                      (0, d_p - width)))
+    # no-ops for rows of whole tiles (heads of 128, packed heads of 64):
+    # any other width is padded here, the whole pool at every call
+    kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - width)))
+    vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - width)))
     hb, ppb = _decode_tiling(kvh, ps, d_p, max_pages, kp.dtype.itemsize,
                              quantized)
     # whole blocks: the last may reach past the table where ppb does not
@@ -751,7 +793,8 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
         interpret=interpret,
         name=scopes.PAGED_DECODE_KERNEL,
     )(table, pos.astype(jnp.int32), *operands)
-    return out[:, :, :rep, :hd].reshape(b, 1, heads, hd)
+    return _unpack_context(out[:, :, :pack * rep, :width], pack, rep,
+                           hd).reshape(b, 1, heads, hd)
 
 
 # ------------------------------------------------------------ latent pools
@@ -1053,28 +1096,31 @@ def _ragged_attend_kernel(pt_ref, pos_ref, row_ref, q_ref, k_ref, v_ref,
 
 
 def _ragged_paged_pallas(q, k_pool, v_pool, page_table, pos, row_ids,
-                         k_scale=None, v_scale=None, interpret=False):
-    """q: (1, T, heads, hd); pools: (kvh, P, ps, hd); page_table:
-    (B, maxP) i32; pos/row_ids: (T,) i32; k_scale/v_scale: optional
-    (kvh, P, ps, 1) fp32 scale slabs. Returns (1, T, heads, hd)."""
+                         k_scale=None, v_scale=None, pack=1,
+                         interpret=False):
+    """q: (1, T, heads, hd); pools: (kvh, P, ps, hd), `pack` heads a
+    row as the decode kernel's; page_table: (B, maxP) i32; pos/row_ids:
+    (T,) i32; k_scale/v_scale: optional (kvh, P, ps, 1) fp32 scale
+    slabs. Returns (1, T, heads, hd)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, t, heads, hd = q.shape
-    kvh, _, ps, _ = k_pool.shape
-    rep = heads // kvh
+    kvh, _, ps, width = k_pool.shape
+    rep = heads // (kvh * pack)
     max_pages = page_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
 
-    d_p = _round_up(hd, 128)
-    g_p = _round_up(rep, 8)
+    d_p = _round_up(width, 128)
+    g_p = _round_up(pack * rep, 8)
     # (T, kvh, G, hd): q head h*rep + g attends kv head h, exactly the
     # decode kernel's grouping with tokens in place of batch rows
-    qg = q.reshape(t, kvh, rep, hd)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - rep), (0, d_p - hd)))
-    kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
-    vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - hd)))
+    qg = _pack_queries(q.reshape(t, kvh * pack, rep, hd), pack)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - pack * rep),
+                      (0, d_p - width)))
+    kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - width)))
+    vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, d_p - width)))
 
     q_spec = pl.BlockSpec((1, 1, g_p, d_p),
                           lambda t_, h_, pi, pt, ps_, rw: (t_, h_, 0, 0))
@@ -1109,4 +1155,5 @@ def _ragged_paged_pallas(q, k_pool, v_pool, page_table, pos, row_ids,
         name=scopes.PAGED_RAGGED_KERNEL,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
       row_ids.astype(jnp.int32), *operands)
-    return out[:, :, :rep, :hd].reshape(1, t, heads, hd)
+    return _unpack_context(out[:, :, :pack * rep, :width], pack, rep,
+                           hd).reshape(1, t, heads, hd)

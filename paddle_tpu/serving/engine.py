@@ -299,6 +299,9 @@ class ServingObs:
         # expert-layer handles, bound by bind_moe() only for a model
         # whose steps return an expert histogram
         self.moe_pairs = None
+        # state-slot handles, bound by bind_state_slots() only for a
+        # model with recurrent layers
+        self.slots_in_use = None
 
     def dispatched(self, greedy: bool) -> None:
         """One device program launched; `greedy` is the host's reading of
@@ -328,6 +331,31 @@ class ServingObs:
         self.moe_max_expert_tokens = c(
             "serving_moe_max_expert_tokens_total",
             "tokens of the fullest expert, summed over layer dispatches")
+
+    def bind_state_slots(self) -> None:
+        """State-slot observability of a model with recurrent layers:
+        how many slots are held, how many were handed out, and how often
+        the K/V pages held an admission back (the slots cannot: there is
+        one a row). Host integers the scheduler already has: no sync."""
+        r = self.registry
+        self.slots_in_use = r.gauge(
+            "serving_state_slots_in_use",
+            "state slots held by running requests")
+        self.slot_allocations = r.counter(
+            "serving_state_slot_allocations_total",
+            "state slots handed out at admission")
+        self.blocked_on_pages = r.counter(
+            "serving_admission_blocked_on_pages_total",
+            "scheduling turns in which the head of the queue was not "
+            "admitted for want of pages")
+
+    def state_slots(self, allocator, allocated: bool = False) -> None:
+        self.slots_in_use.set(allocator.num_used)
+        if allocated:
+            self.slot_allocations.inc()
+
+    def admission_blocked_on_pages(self) -> None:
+        self.blocked_on_pages.inc()
 
     def moe_block(self, hist, family: str) -> None:
         """Count one drained block's histograms, (..., layers, experts),
@@ -523,10 +551,18 @@ class ServingEngine:
                 "'fp32', 'bf16', 'int8', 'fp8'")
         self.kv_dtype = kv_dtype
         # a model with latent attention (its config names the cached
-        # row's width) gets a latent pool; what is not yet written over
-        # that pool kind is refused here, by the option's name, never
-        # taken down a path that would compute something else
-        if getattr(cfg, "latent_cache_dim", None) is not None:
+        # row's width) gets a latent pool, one with recurrent layers (its
+        # config names them) state slots beside its K/V pages; what is
+        # not yet written over either is refused here, by the option's
+        # name, never taken down a path that would compute something
+        # else. Recovery needs no refusal: a snapshot holds requests,
+        # never pages or states, and a restored request re-prefills
+        self._has_state = getattr(cfg, "state_cache_spec", None) is not None
+        held_in = ("a latent KV pool"
+                   if getattr(cfg, "latent_cache_dim", None) is not None
+                   else "state slots beside its K/V pages"
+                   if self._has_state else None)
+        if held_in is not None:
             refused = [name for name, on in (
                 ("tp_size", int(tp_size) > 1),
                 ("kv_dtype", kv_dtype in ("int8", "fp8")),
@@ -535,7 +571,7 @@ class ServingEngine:
                 ("spec_config", spec_config is not None)) if on]
             if refused:
                 raise ValueError(
-                    f"{type(model).__name__} serves over a latent KV pool, "
+                    f"{type(model).__name__} serves over {held_in}, "
                     f"which does not support {', '.join(refused)} yet "
                     "(tensor parallelism, quantized pages, and any "
                     "prefill at an offset: prefix cache, chunked prefill "
@@ -659,9 +695,12 @@ class ServingEngine:
         if num_pages is None:
             # worst case every slot runs a full-length sequence, +1 null
             num_pages = max_batch_size * self.max_pages_per_seq + 1
-        self.cache = PagedKVCache.for_model(model, num_pages, page_size,
-                                            cache_dtype,
-                                            kv_dtype=self.kv_dtype)
+        # one state slot a row of the decode batch: a running request
+        # always has one, so the pages alone decide how many run
+        self.cache = PagedKVCache.for_model(
+            model, num_pages, page_size, cache_dtype,
+            kv_dtype=self.kv_dtype, pack_heads=self._tp is None,
+            state_slots=max_batch_size if self._has_state else 0)
         if self._tp is not None:
             self.cache.shard_pools(self._tp.mesh, self._tp.pool_spec)
         # observability: ONE registry per engine is the single source of
@@ -681,7 +720,7 @@ class ServingEngine:
             # equal-page fp32 baseline for the capacity gauge, computed
             # WITHOUT touching serving.quant
             c = self.cache
-            fp32_bytes = (c.num_layers * c.num_pages * c.page_size
+            fp32_bytes = (c.num_kv_layers * c.num_pages * c.page_size
                           * c.slot_elems * 4)
             rms = None
             if c.quantized:
@@ -693,6 +732,8 @@ class ServingEngine:
             self._obs.bind_spec()
         if self._obs is not None and self._has_aux:
             self._obs.bind_moe()
+        if self._obs is not None and self._has_state:
+            self._obs.bind_state_slots()
         # SLO accounting (ISSUE 13): per-request-class TTFT/TPOT targets
         # feeding windowed attainment gauges + a goodput counter. Rides
         # on the metrics registry, so it requires one; with no classes
@@ -775,15 +816,19 @@ class ServingEngine:
                                    max_num_batched_tokens=
                                    self.max_num_batched_tokens,
                                    ragged_steps=self.enable_ragged_step,
-                                   spec_lookahead=self._spec_lookahead)
+                                   spec_lookahead=self._spec_lookahead,
+                                   slot_allocator=self.cache.slot_allocator)
         self.params, self.buffers = extract_state(model)
         if self._tp is not None:
             self.params = self._tp.shard_params(self.params)
             self.buffers = self._tp.replicate(self.buffers)
         self.requests: Dict[int, Request] = {}
-        # per-request PRNG state as raw (2,) uint32 key data, resident on
-        # device — sampling never splits keys on the host
-        self._key_state: Dict[int, jax.Array] = {}
+        # per-request PRNG state as raw (2,) uint32 key data — sampling
+        # never splits keys on the host: the device's carry comes back
+        # with the tokens of a prefill or a drained block as a host row,
+        # so a batch's keys take no device operation a row to take apart
+        # or put together (a key not yet used is still a device array)
+        self._key_state: Dict[int, object] = {}
         # the dispatched-but-undrained decode block (async overlap depth
         # 1): emitted tokens + the device carries the next chained block
         # consumes without any host round-trip
@@ -1200,6 +1245,18 @@ class ServingEngine:
             pass
         return {rid: self.output(rid) for rid in self.requests}
 
+    def _slots_of(self, reqs: Sequence[Request],
+                  rows: Optional[int] = None) -> dict:
+        """The `slots=` argument of a step over a model with recurrent
+        layers: each row's state slot, the null slot for padding rows;
+        nothing at all for every other model, whose executables are
+        called as they always were."""
+        if not self._has_state:
+            return {}
+        slots = [r.state_slot for r in reqs]
+        slots += [None] * ((rows or len(slots)) - len(slots))
+        return {"slots": self.cache.slot_array(slots)}
+
     def _note_exec(self, family: str, aval) -> None:
         """Record one step family's input aval; a NEW aval is a jit-cache
         miss, counted into the registry's compile-miss counter (the set
@@ -1230,8 +1287,10 @@ class ServingEngine:
             logits_at = self._logits_at
 
             def prefill(params, buffers, ids, pools, page_table, last_idx,
-                        key_data, temps, top_ks, top_ps):
-                views = views_from_pools(pools, page_table)
+                        key_data, temps, top_ks, top_ps, slots=None):
+                # `slots`: the row's state slot, passed (by name) over a
+                # model with recurrent layers only
+                views = views_from_pools(pools, page_table, slots=slots)
                 kwargs = {"caches": views, "start_pos": 0}
                 if logits_at:
                     # the model computes that position's logits alone
@@ -1323,33 +1382,36 @@ class ServingEngine:
         page_table = self.cache.page_table_array([req.pages],
                                                  self.max_pages_per_seq)
         sp = req.sampling
-        knobs = (jnp.asarray([sp.temperature], jnp.float32),
-                 jnp.asarray([sp.top_k], jnp.int32),
-                 jnp.asarray([sp.top_p], jnp.float32))
+        # host arrays, sent with the call: made on the device each is
+        # an operation of its own, dispatched with the device waiting
+        knobs = (np.full((1,), sp.temperature, np.float32),
+                 np.full((1,), sp.top_k, np.int32),
+                 np.full((1,), sp.top_p, np.float32))
         key_data = self._key_state[req.request_id][None]
 
         def dispatch():
             aux = ()
             if n_cached:
                 tok, new_kd, pools = self._prefill_offset_jit(bucket)(
-                    self.params, self.buffers, jnp.asarray(ids),
+                    self.params, self.buffers, ids,
                     self.cache.pools, page_table,
-                    jnp.int32(len(suffix) - 1), jnp.int32(n_cached),
+                    np.int32(len(suffix) - 1), np.int32(n_cached),
                     key_data, *knobs)
             else:
                 tok, new_kd, pools, *aux = self._prefill_jit(bucket)(
-                    self.params, self.buffers, jnp.asarray(ids),
+                    self.params, self.buffers, ids,
                     self.cache.pools, page_table,
-                    jnp.int32(len(suffix) - 1), key_data, *knobs)
+                    np.int32(len(suffix) - 1), key_data, *knobs,
+                    **self._slots_of([req]))
             self.cache.pools = pools
-            self._key_state[req.request_id] = new_kd[0]
-            if aux:
-                # the histogram rides the token's own transfer
-                tok, hist = jax.device_get(  # noqa: HOST-SYNC — the prefill's one sync, as below
-                    (tok, aux[0]["moe_expert_tokens"]))
-                if self._obs is not None:
-                    self._obs.moe_block(hist, "prefill")
-            return int(np.asarray(tok)[0])
+            # the row's key, and a model's histogram, ride the token's
+            # own transfer
+            tok, kd, *hist = jax.device_get(  # noqa: HOST-SYNC — the prefill's one sync
+                (tok, new_kd) + tuple(a["moe_expert_tokens"] for a in aux[:1]))
+            self._key_state[req.request_id] = kd[0]
+            if hist and self._obs is not None:
+                self._obs.moe_block(hist[0], "prefill")
+            return int(tok[0])
 
         t0 = time.perf_counter()
         if self._recorder is not None:
@@ -1775,12 +1837,13 @@ class ServingEngine:
 
             def decode_block(params, buffers, tokens, pools, page_tables,
                              positions, key_data, temps, top_ks, top_ps,
-                             eos_ids, remaining):
+                             eos_ids, remaining, slots=None):
                 max_pages = page_tables.shape[1]
 
                 def body(carry, _):
                     tokens, pools, positions, key_data, remaining = carry
-                    views = views_from_pools(pools, page_tables)
+                    views = views_from_pools(pools, page_tables,
+                                             slots=slots)
                     out, _ = call_functional(
                         model, params, buffers, (Tensor(tokens[:, None]),),
                         kwargs={"caches": views, "start_pos": positions},
@@ -1881,14 +1944,17 @@ class ServingEngine:
                 if req.eos_token_id is not None:
                     eos_ids[i] = req.eos_token_id
                 kds.append(self._key_state[req.request_id])
-            kds.extend([jnp.zeros((2,), jnp.uint32)] * (b - len(reqs)))
+            kds.extend([np.zeros((2,), np.uint32)] * (b - len(reqs)))
             knobs = (jnp.asarray(temps), jnp.asarray(top_ks),
                      jnp.asarray(top_ps), jnp.asarray(eos_ids))
             greedy = not temps.any()
             tokens = jnp.asarray(tokens)
             positions = jnp.asarray(positions)
             remaining = jnp.asarray(remaining)
-            key_data = jnp.stack(kds)
+            # the keys are host rows (`_key_state`), stacked there and
+            # sent once: a stack on the device is an operation a row,
+            # dispatched while the device waits
+            key_data = jnp.asarray(np.stack(kds))
         else:
             # chained block: consume the pending block's device carries —
             # no host sync anywhere on this path
@@ -1908,7 +1974,8 @@ class ServingEngine:
         def dispatch():
             out = self._decode_block_jit(h)(
                 self.params, self.buffers, tokens, self.cache.pools,
-                page_tables, positions, key_data, *knobs, remaining)
+                page_tables, positions, key_data, *knobs, remaining,
+                **self._slots_of(reqs, b))
             self.cache.pools = out[1]
             return out
 
@@ -1916,8 +1983,10 @@ class ServingEngine:
         if self._recorder is not None:
             self._recorder.record("dispatch", family="decode",
                                   rows=len(reqs), horizon=h)
+        attrs = ({"state_slots": self.cache.slot_allocator.num_used}
+                 if self._has_state else {})
         with RecordEvent("serving.decode_block", rows=live,
-                         rows_dispatched=b, horizon=h):
+                         rows_dispatched=b, horizon=h, **attrs):
             out, err = self._guarded_call("dispatch", dispatch)
         if out is None:
             # a decode dispatch implicates the whole batch. Drain the
@@ -2136,19 +2205,17 @@ class ServingEngine:
         windows = rec.get("windows")
         moe_hist = rec.get("moe_hist")
         with RecordEvent("serving.host_drain"):
+            # the rows' keys come back with the tokens, and with them a
+            # model's expert histogram or a spec block's accept counters
+            extra = moe_hist if moe_hist is not None else sstats
+            pulled, err = self._guarded_call(
+                "drain", lambda: jax.device_get((rec["emitted"], rec["key_data"], extra)))  # noqa: HOST-SYNC — THE one sync per block, in a single transfer (PR 3 contract)
+            toks, kd, extra = (pulled if pulled is not None
+                               else (None, None, None))
             if moe_hist is not None:
-                pulled, err = self._guarded_call(
-                    "drain", lambda: jax.device_get((rec["emitted"], moe_hist)))  # noqa: HOST-SYNC — still THE one sync per block: the expert histogram comes back in the tokens' transfer (PR 3 contract)
-                toks, moe_hist = (pulled if pulled is not None
-                                  else (None, None))
-            elif sstats is None:
-                toks, err = self._guarded_call(
-                    "drain", lambda: np.asarray(jax.device_get(rec["emitted"])))  # noqa: HOST-SYNC — THE one sync per decode block (PR 3 contract)
+                moe_hist = extra
             else:
-                pulled, err = self._guarded_call(
-                    "drain", lambda: jax.device_get((rec["emitted"], rec["spec_stats"])))  # noqa: HOST-SYNC — still THE one sync per block: a spec block's tokens and accept counters come back in a single transfer (PR 3 contract)
-                toks, sstats = (pulled if pulled is not None
-                                else (None, None))
+                sstats = extra
         if toks is None:
             # the block's tokens are unrecoverable: give back the
             # in-flight reservation and isolate exactly the block's
@@ -2165,11 +2232,10 @@ class ServingEngine:
             if moe_hist is not None:
                 o.moe_block(moe_hist, "decode")
         now = time.perf_counter()
-        kd = rec["key_data"]
         events: List[Tuple[int, int]] = []
         for i, req in enumerate(rec["reqs"]):
             req.inflight = max(req.inflight - rec["incr"][i], 0)
-            self._key_state[req.request_id] = kd[i]
+            self._key_state[req.request_id] = kd[i]    # a row of host memory
             if req.status != "running":
                 continue
             prev_t = req.last_token_t

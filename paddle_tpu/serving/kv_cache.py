@@ -27,11 +27,15 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PagedLayerCache",
-           "LatentLayerCache", "NULL_PAGE", "pages_for",
+__all__ = ["BlockAllocator", "SlotAllocator", "PagedKVCache",
+           "PagedLayerCache", "LatentLayerCache", "StateLayerCache",
+           "StateSpec", "LayerPool", "NULL_PAGE", "NULL_SLOT", "pages_for",
            "overflow_position", "views_from_pools", "pools_from_views"]
 
 NULL_PAGE = 0
+# the state slot that padding rows and rows parked inside a decode block
+# read and write, as the null page is for K/V: never handed out
+NULL_SLOT = 0
 
 # unquantized pool dtypes resolvable WITHOUT importing serving.quant —
 # kv_dtype="fp32"/"bf16" must keep the quantization module entirely
@@ -231,7 +235,11 @@ class PagedLayerCache:
     dispatches on this type (duck-typed by `page_table`), so LLaMA/GPT/T5
     attention modules ride the paged path unmodified.
 
-    k_pool/v_pool: (kv_heads, num_pages, page_size, head_dim) — kv-head
+    k_pool/v_pool: (kv_heads, num_pages, page_size, head_dim), or, where
+                   `PagedKVCache.head_pack` = g > 1, (kv_heads / g,
+                   num_pages, page_size, g * head_dim) with head h in
+                   columns [(h % g) * head_dim, ...) of row block h // g:
+                   a reshape of a token's (kv_heads, head_dim) — kv-head
                    major so the Pallas kernels reach a page's (page_size,
                    head_dim) tile, for one head or a block of heads, by
                    one copy straight from the pool, without a per-step
@@ -263,6 +271,9 @@ class PagedLayerCache:
     row_ids: Optional[jnp.ndarray] = None
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    # kv heads side by side in one pool row (`PagedKVCache.head_pack`):
+    # static, and the one place the kernels read it from
+    head_pack: int = 1
 
     @property
     def page_size(self) -> int:
@@ -273,27 +284,23 @@ class PagedLayerCache:
         return self.k_scale is not None
 
     def tree_flatten(self):
-        # keep the 3-child structure (and treedef equality) of every
-        # existing executable when row_ids is absent; quantized views get
-        # their own aux tags so fp32/bf16 treedefs stay byte-identical
-        if self.k_scale is None:
-            if self.row_ids is None:
-                return (self.k_pool, self.v_pool, self.page_table), None
-            return (self.k_pool, self.v_pool, self.page_table,
-                    self.row_ids), True
-        if self.row_ids is None:
-            return (self.k_pool, self.v_pool, self.page_table,
-                    self.k_scale, self.v_scale), "quant"
-        return (self.k_pool, self.v_pool, self.page_table,
-                self.k_scale, self.v_scale, self.row_ids), "quant+rows"
+        # the children are what they were: row_ids and the scales appear
+        # only where they are set, so a plain view keeps three; which of
+        # them are there, and the head packing, are static
+        children = (self.k_pool, self.v_pool, self.page_table)
+        if self.quantized:
+            children += (self.k_scale, self.v_scale)
+        if self.row_ids is not None:
+            children += (self.row_ids,)
+        return children, (self.quantized, self.row_ids is not None,
+                          self.head_pack)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        if aux in (None, True):
-            return cls(*children)
-        kp, vp, pt, ks, vs = children[:5]
-        rid = children[5] if aux == "quant+rows" else None
-        return cls(kp, vp, pt, rid, k_scale=ks, v_scale=vs)
+        quantized, rows, head_pack = aux
+        ks, vs = children[3:5] if quantized else (None, None)
+        return cls(*children[:3], children[-1] if rows else None,
+                   k_scale=ks, v_scale=vs, head_pack=head_pack)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -329,28 +336,194 @@ class LatentLayerCache:
         return cls(*children)
 
 
-def views_from_pools(pools, page_table, row_ids=None):
-    """Per-layer cache views from engine pool tuples — 1-tuples (pool,)
-    for the latent kind, 2-tuples (k, v) for plain K/V pools, 4-tuples
-    (k, v, k_scale, v_scale) for quantized ones. Runs at trace time
+def _lane_pack(width: int, heads: int) -> int:
+    """How many heads of `width` columns lie side by side in one
+    128-lane row: `128 // width` where that is whole, more than one and
+    divides `heads`, else 1 (a head a row)."""
+    g = 128 // width if 0 < width < 128 and 128 % width == 0 else 1
+    return g if heads % g == 0 else 1
+
+
+class SlotAllocator:
+    """Free list of the fixed-size state slots of a model with recurrent
+    layers: one slot a live request, ids in [1, num_slots]; slot 0
+    (`NULL_SLOT`) is never handed out. A slot has one owner, so there is
+    no reference count."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 1:
+            raise ValueError("need at least 1 state slot")
+        self.num_slots = num_slots
+        self._free: List[int] = list(range(num_slots, 0, -1))
+        self._used: set = set()
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_used(self) -> int:
+        return len(self._used)
+
+    def alloc(self) -> Optional[int]:
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._used.add(slot)
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot not in self._used:
+            raise ValueError(f"double free or unknown state slot {slot}")
+        self._used.remove(slot)
+        self._free.append(slot)
+
+    def check_consistency(self) -> bool:
+        ids = sorted(self._free + list(self._used))
+        if ids != list(range(1, self.num_slots + 1)):
+            raise RuntimeError(
+                "slot allocator corrupt: free and used slots do not "
+                f"partition [1, {self.num_slots}]")
+        return True
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpec:
+    """What a model with recurrent (state-space) layers tells the cache
+    manager through its config's `state_cache_spec`: which layers carry
+    a state instead of K/V pages, and the state's sizes."""
+
+    state_layers: tuple       # a bool a decoder layer: True = state
+    heads: int                # H
+    head_dim: int             # P
+    state_dim: int            # N
+    conv_dim: int             # channels of the causal conv (x, B and C)
+    conv_width: int           # taps; the tail holds conv_width - 1 rows
+
+    @property
+    def head_pack(self) -> int:
+        """Heads that share one 128-lane row of the stored state."""
+        return _lane_pack(self.head_dim, self.heads)
+
+    @property
+    def ssm_shape(self) -> tuple:
+        """One slot's state as the pool holds it: (H / g, N, g * P), the
+        state of head h as its transpose (N, P) in lanes [(h % g) * P,
+        (h % g + 1) * P) of row block h // g. Whole 128-lane rows for a
+        head of 64 (g = 2); the decode kernel (`serving/ssm.py`) takes
+        the token's x as rows and its B and C as columns in this form,
+        and neither needs a transpose on the chip."""
+        g = self.head_pack
+        return (self.heads // g, self.state_dim, g * self.head_dim)
+
+    @property
+    def conv_elems(self) -> int:
+        return (self.conv_width - 1) * self.conv_dim
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class StateLayerCache:
+    """One recurrent layer's view of the state pools, handed to the
+    model's mixer in place of a K/V page view.
+
+    ssm_pool:  (slots + 1, *StateSpec.ssm_shape) float32: the SSM state
+               of every slot; `serving.ssm` reads and writes it, the
+               decode kernel in place
+    conv_pool: (slots + 1, (conv_width - 1) * conv_dim): the last
+               conv_width - 1 rows of the conv's input, oldest first, one
+               flat row a slot (the indexed dimension leads and the
+               window is the minor one, so a scatter into the donated
+               pool stays in place: `attention._write_pages`)
+    slots:     (B,) int32: the slot of each row of the step
+               (`NULL_SLOT` for padding)
+    """
+
+    ssm_pool: jnp.ndarray
+    conv_pool: jnp.ndarray
+    slots: jnp.ndarray
+
+    def tree_flatten(self):
+        return (self.ssm_pool, self.conv_pool, self.slots), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
+@jax.tree_util.register_pytree_node_class
+class LayerPool(tuple):
+    """One layer's pool arrays, tagged with what they hold: "kv" (k, v),
+    "kv_quant" (k, v, k_scale, v_scale), "latent" (pool,) or "state"
+    (ssm, conv), and for a "kv" pool with how many kv heads lie side by
+    side in a row (`head_pack`). Both are static under `jax.jit` (the
+    tree's auxiliary data); the leaves and their order are the bare
+    tuple's, so a program over tagged pools is the program over bare
+    ones."""
+
+    KINDS = ("kv", "kv_quant", "latent", "state")
+
+    def __new__(cls, kind: str, arrays, head_pack: int = 1):
+        if kind not in cls.KINDS:
+            raise ValueError(f"unknown pool kind {kind!r}")
+        if head_pack != 1 and kind != "kv":
+            raise ValueError(f"a {kind!r} pool does not pack heads")
+        self = super().__new__(cls, arrays)
+        self.kind = kind
+        self.head_pack = head_pack
+        return self
+
+    def tree_flatten(self):
+        return tuple(self), (self.kind, self.head_pack)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(aux[0], children, aux[1])
+
+    def __repr__(self):
+        return (f"LayerPool({self.kind!r}, {tuple(self)!r}, "
+                f"head_pack={self.head_pack})")
+
+
+def views_from_pools(pools, page_table, row_ids=None, slots=None):
+    """Per-layer cache views from the engine's tagged pools
+    (`LayerPool`), each of its own kind: a model with recurrent layers
+    has "kv" and "state" pools side by side. `slots` is the (B,) state
+    slot of each row, needed where a "state" pool is. Runs at trace time
     inside every jitted step."""
-    if pools and len(pools[0]) == 1:
-        if row_ids is not None:
-            raise NotImplementedError(
-                "a latent pool has no flat ragged step (row_ids)")
-        return [LatentLayerCache(p[0], page_table) for p in pools]
-    return [PagedLayerCache(p[0], p[1], page_table, row_ids,
-                            k_scale=p[2] if len(p) == 4 else None,
-                            v_scale=p[3] if len(p) == 4 else None)
-            for p in pools]
+    views = []
+    for p in pools:
+        if p.kind == "latent":
+            if row_ids is not None:
+                raise NotImplementedError(
+                    "a latent pool has no flat ragged step (row_ids)")
+            views.append(LatentLayerCache(p[0], page_table))
+        elif p.kind == "state":
+            if row_ids is not None or slots is None:
+                raise NotImplementedError(
+                    "a state pool is read by one slot a row: it has no "
+                    "flat ragged step, and the step must name the slots")
+            views.append(StateLayerCache(p[0], p[1], slots))
+        else:
+            quant = p.kind == "kv_quant"
+            views.append(PagedLayerCache(
+                p[0], p[1], page_table, row_ids,
+                k_scale=p[2] if quant else None,
+                v_scale=p[3] if quant else None, head_pack=p.head_pack))
+    return views
 
 
 def pools_from_views(views):
-    """Inverse of `views_from_pools`: pool tuples from the new caches a
-    step returned, preserving the tuples' arity."""
-    return [(v.pool,) if isinstance(v, LatentLayerCache)
-            else (v.k_pool, v.v_pool) if v.k_scale is None
-            else (v.k_pool, v.v_pool, v.k_scale, v.v_scale)
+    """Inverse of `views_from_pools`: tagged pools from the new caches a
+    step returned."""
+    return [LayerPool("latent", (v.pool,))
+            if isinstance(v, LatentLayerCache)
+            else LayerPool("state", (v.ssm_pool, v.conv_pool))
+            if isinstance(v, StateLayerCache)
+            else LayerPool("kv", (v.k_pool, v.v_pool), v.head_pack)
+            if v.k_scale is None
+            else LayerPool("kv_quant",
+                           (v.k_pool, v.v_pool, v.k_scale, v.v_scale))
             for v in views]
 
 
@@ -361,30 +534,72 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
                  kv_dtype: Optional[str] = None,
-                 latent_dim: Optional[int] = None):
+                 latent_dim: Optional[int] = None,
+                 head_pack: int = 1,
+                 state_spec: Optional[StateSpec] = None,
+                 state_slots: int = 0):
         """`latent_dim` selects the pool kind: None for K and V pools of
         `num_kv_heads` heads of `head_dim`; a row's width for the latent
         kind, one (num_pages, page_size, latent_dim rounded up to whole
         128-lane tiles) pool a layer (`num_kv_heads` and `head_dim` are
-        then not read)."""
+        then not read).
+
+        `head_pack` > 1 (plain K/V pools only) holds that many kv heads
+        side by side in one row: pools of (num_kv_heads / head_pack,
+        num_pages, page_size, head_pack * head_dim). A row of 64 is half
+        a 128-lane tile, which the chip's memory pads and the decode
+        kernel would have to pad again at every call; two heads of 64
+        fill the row (`attention._paged_decode_pallas` has the
+        arithmetic).
+
+        `state_spec` (a model with recurrent layers) gives the layers it
+        marks a state pool of `state_slots` + 1 slots instead of K/V
+        pages, and the cache a `slot_allocator`; the other layers get
+        the K/V pools described above."""
         self.num_layers = num_layers
         self.num_pages = num_pages
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.latent_dim = latent_dim
+        self.state_spec = state_spec
+        self.state_slots = int(state_slots) if state_spec is not None else 0
         if kv_dtype is not None and kv_dtype in _PLAIN_KV_DTYPES:
             dtype = _PLAIN_KV_DTYPES[kv_dtype]
             kv_dtype = None
         self.quant_spec = None
+        self.head_pack = int(head_pack)
+        if self.head_pack > 1 and (latent_dim is not None
+                                   or kv_dtype is not None
+                                   or num_kv_heads % self.head_pack):
+            raise ValueError(
+                f"head_pack={head_pack}: only plain K/V pools pack heads, "
+                "and the kv heads must divide into whole packs")
+        if state_spec is not None:
+            if latent_dim is not None or kv_dtype is not None:
+                raise ValueError(
+                    "state slots stand beside fp32 or bf16 K/V pages: "
+                    "latent or quantized pages beside them are not "
+                    "written yet")
+            if len(state_spec.state_layers) != num_layers:
+                raise ValueError(
+                    f"state_spec marks {len(state_spec.state_layers)} "
+                    f"layers, the model has {num_layers}")
+            if self.state_slots < 1:
+                raise ValueError("a model with recurrent layers needs "
+                                 "state_slots >= 1")
+        is_state = (tuple(state_spec.state_layers) if state_spec is not None
+                    else (False,) * num_layers)
+        self.num_kv_layers = num_layers - sum(is_state)
         if latent_dim is not None:
             if kv_dtype is not None:
                 raise ValueError(
                     f"kv_dtype={kv_dtype!r}: a latent pool holds fp32 or "
                     "bf16 rows; quantized latent pages are not written yet")
-            self.pools = [(jnp.zeros((num_pages, page_size,
-                                      self.slot_elems), dtype),)
-                          for _ in range(num_layers)]
+
+            def paged():
+                return LayerPool("latent", (jnp.zeros(
+                    (num_pages, page_size, self.slot_elems), dtype),))
         elif kv_dtype is not None:
             # quantized pools ONLY: the fp32/bf16 constructor path above
             # must never import serving.quant
@@ -394,18 +609,32 @@ class PagedKVCache:
             store = self.quant_spec.storage_dtype
             shape = (num_kv_heads, num_pages, page_size, head_dim)
             sshape = (num_kv_heads, num_pages, page_size, 1)
-            self.pools = [
-                (jnp.zeros(shape, store), jnp.zeros(shape, store),
-                 jnp.ones(sshape, SCALE_DTYPE),
-                 jnp.ones(sshape, SCALE_DTYPE))
-                for _ in range(num_layers)]
+
+            def paged():
+                return LayerPool("kv_quant", (
+                    jnp.zeros(shape, store), jnp.zeros(shape, store),
+                    jnp.ones(sshape, SCALE_DTYPE),
+                    jnp.ones(sshape, SCALE_DTYPE)))
         else:
-            shape = (num_kv_heads, num_pages, page_size, head_dim)
-            self.pools = [(jnp.zeros(shape, dtype),
-                           jnp.zeros(shape, dtype))
-                          for _ in range(num_layers)]
+            shape = (num_kv_heads // self.head_pack, num_pages, page_size,
+                     head_dim * self.head_pack)
+
+            def paged():
+                return LayerPool("kv", (jnp.zeros(shape, dtype),
+                                        jnp.zeros(shape, dtype)),
+                                 self.head_pack)
+
+        def state():
+            n = self.state_slots + 1
+            return LayerPool("state", (
+                jnp.zeros((n,) + state_spec.ssm_shape, jnp.float32),
+                jnp.zeros((n, state_spec.conv_elems), dtype)))
+
+        self.pools = [state() if s else paged() for s in is_state]
         self.dtype = dtype
         self.allocator = BlockAllocator(num_pages)
+        self.slot_allocator = (SlotAllocator(self.state_slots)
+                               if state_spec is not None else None)
 
     @property
     def kv_dtype(self) -> str:
@@ -422,48 +651,70 @@ class PagedKVCache:
 
     @property
     def kind(self) -> str:
-        """"latent" or "kv": what a page holds."""
+        """What the sequence state is held in: "kv" or "latent" pages,
+        or "kv+state": K/V pages for the attention layers and one state
+        slot a request for the recurrent ones."""
+        if self.state_spec is not None:
+            return "kv+state"
         return "kv" if self.latent_dim is None else "latent"
 
     @property
     def slot_elems(self) -> int:
-        """Stored elements of one token in one layer: the latent row in
-        whole 128-lane tiles, or K and V of every kv head."""
+        """Stored elements of one token in one paged layer: the latent
+        row in whole 128-lane tiles, or K and V of every kv head."""
         if self.latent_dim is not None:
             return -(-self.latent_dim // 128) * 128
         return 2 * self.num_kv_heads * self.head_dim
 
     @property
     def page_bytes(self) -> int:
-        """Bytes one logical page occupies across all layers: the data
-        slabs of the pool's kind plus (quantized pools) the parallel
-        scale slabs. This is the capacity unit — resident sequences per
-        pool byte budget is
+        """Bytes one logical page occupies across the paged layers: the
+        data slabs of the pool's kind plus (quantized pools) the parallel
+        scale slabs. This is the capacity unit of the paged state —
+        resident sequences per pool byte budget is
         `budget // (pages_for(seq_len) * page_bytes)`."""
         itemsize = (self.quant_spec.storage_itemsize
                     if self.quant_spec is not None
                     else jnp.dtype(self.dtype).itemsize)
         per_slot = self.slot_elems * itemsize + (
             2 * self.num_kv_heads * 4 if self.quantized else 0)
-        return self.num_layers * self.page_size * per_slot
+        return self.num_kv_layers * self.page_size * per_slot
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes of one request's state slot across the recurrent
+        layers (0 for a model without them): the float32 SSM state and
+        the conv tail in the cache's type."""
+        spec = self.state_spec
+        if spec is None:
+            return 0
+        ssm = 4 * spec.heads * spec.head_dim * spec.state_dim
+        conv = spec.conv_elems * jnp.dtype(self.dtype).itemsize
+        return (self.num_layers - self.num_kv_layers) * (ssm + conv)
 
     @property
     def pool_bytes(self) -> int:
-        """Total bytes of all pool leaves (data + scale slabs)."""
-        return self.num_pages * self.page_bytes
+        """Total bytes of all pool leaves: pages (data + scale slabs)
+        and state slots, the null page and the null slot among them."""
+        return (self.num_pages * self.page_bytes
+                + (self.state_slots + 1) * self.state_slot_bytes)
 
     @classmethod
     def for_model(cls, model, num_pages: int, page_size: int,
                   dtype=jnp.float32,
-                  kv_dtype: Optional[str] = None) -> "PagedKVCache":
+                  kv_dtype: Optional[str] = None,
+                  pack_heads: bool = True,
+                  state_slots: int = 0) -> "PagedKVCache":
         from ..models.generation import _config_of
 
         cfg = _config_of(model)
         kv_heads = getattr(cfg, "num_key_value_heads",
                            cfg.num_attention_heads)
         head_dim = cfg.hidden_size // cfg.num_attention_heads
-        # a model with latent attention says how wide its cached row is
+        # a model with latent attention says how wide its cached row is,
+        # one with recurrent layers which they are and how large a state
         latent_dim = getattr(cfg, "latent_cache_dim", None)
+        state_spec = getattr(cfg, "state_cache_spec", None)
         # validate the model's compute dtype against the requested pool
         # format up front — the old code silently assumed fp32 pools and
         # a mismatch surfaced as a cryptic XLA dtype error mid-step
@@ -481,9 +732,17 @@ class PagedKVCache:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}: expected one of "
                 "'fp32', 'bf16', 'int8', 'fp8'")
+        # plain pools of heads that divide a 128-lane row hold a row's
+        # worth of heads side by side (see `__init__`); sharded pools
+        # (`pack_heads=False` from a tensor-parallel engine) keep one
+        # head a row, since their head axis is what is sharded
+        plain = (latent_dim is None
+                 and (kv_dtype is None or kv_dtype in _PLAIN_KV_DTYPES))
+        pack = _lane_pack(head_dim, kv_heads) if pack_heads and plain else 1
         return cls(cfg.num_hidden_layers, num_pages, page_size, kv_heads,
                    head_dim, dtype, kv_dtype=kv_dtype,
-                   latent_dim=latent_dim)
+                   latent_dim=latent_dim, head_pack=pack,
+                   state_spec=state_spec, state_slots=state_slots)
 
     def shard_pools(self, mesh, spec) -> None:
         """Place every layer's pool tuple onto `mesh` under `spec` —
@@ -497,11 +756,15 @@ class PagedKVCache:
         layout."""
         from jax.sharding import NamedSharding
 
-        if self.latent_dim is not None:
-            raise ValueError("a latent pool has no kv-head axis to shard: "
-                             "tensor parallelism over it is not written yet")
+        if self.latent_dim is not None or self.state_spec is not None \
+                or self.head_pack > 1:
+            raise ValueError(
+                "a latent pool, a state pool and a pool of packed heads "
+                "have no kv-head axis to shard: tensor parallelism over "
+                "them is not written yet")
         sh = NamedSharding(mesh, spec)
-        self.pools = [tuple(jax.device_put(x, sh) for x in layer)
+        self.pools = [LayerPool(layer.kind,
+                                tuple(jax.device_put(x, sh) for x in layer))
                       for layer in self.pools]
 
     def page_table_array(self, page_lists: Sequence[Sequence[int]],
@@ -518,11 +781,20 @@ class PagedKVCache:
             out[i, :len(pages)] = pages
         return jnp.asarray(out)
 
-    def layer_views(self, page_table: jnp.ndarray) -> list:
-        """Per-layer views of the pool's kind (`PagedLayerCache` or
-        `LatentLayerCache`) in the shape the models expect for their
-        `caches` argument."""
-        return views_from_pools(self.pools, page_table)
+    def layer_views(self, page_table: jnp.ndarray, slots=None) -> list:
+        """Per-layer views, each of its pool's kind (`PagedLayerCache`,
+        `LatentLayerCache`, `StateLayerCache`), in the shape the models
+        expect for their `caches` argument; `slots` as
+        `views_from_pools` takes it."""
+        return views_from_pools(self.pools, page_table, slots=slots)
+
+    def slot_array(self, slots: Sequence[Optional[int]]) -> jnp.ndarray:
+        """(B,) int32 device slot table from host slot ids, `None`
+        (padding) as the null slot."""
+        import numpy as np
+
+        return jnp.asarray(np.asarray(
+            [NULL_SLOT if s is None else s for s in slots], np.int32))
 
     def update(self, new_views: Sequence) -> None:
         """Adopt the pools a jitted step returned (the step's new_caches)."""

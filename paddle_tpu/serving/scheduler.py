@@ -64,7 +64,7 @@ import itertools
 import time
 from typing import List, Optional, Sequence, Tuple
 
-from .kv_cache import NULL_PAGE, BlockAllocator, pages_for
+from .kv_cache import NULL_PAGE, BlockAllocator, SlotAllocator, pages_for
 from .resilience import (EngineOverloaded, InjectedFault,
                          TERMINAL_STATUSES)
 
@@ -109,6 +109,10 @@ class Request:
     status: str = "waiting"
     generated: List[int] = dataclasses.field(default_factory=list)
     pages: List[int] = dataclasses.field(default_factory=list)
+    # the state slot of a model with recurrent layers (kv_cache.
+    # SlotAllocator): held from admission to finish or preemption, as
+    # the pages are; None for every other model and while waiting
+    state_slot: Optional[int] = None
     preemptions: int = 0
     # absolute perf_counter deadline (arrival_t + deadline_s); None =
     # no deadline. Expired waiting requests are shed before admission;
@@ -233,8 +237,12 @@ class Scheduler:
                  prefill_chunk_tokens: Optional[int] = None,
                  max_num_batched_tokens: Optional[int] = None,
                  ragged_steps: bool = False,
-                 spec_lookahead: int = 0):
+                 spec_lookahead: int = 0,
+                 slot_allocator: Optional[SlotAllocator] = None):
         self.allocator = allocator
+        # a model with recurrent layers: a request is admitted when a
+        # state slot AND its pages are free, and gives both back together
+        self.slot_allocator = slot_allocator
         self.page_size = page_size
         self.max_batch_size = max_batch_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -316,12 +324,24 @@ class Scheduler:
         if self.obs is not None:
             self.obs.enqueued(req)
 
+    def _release(self, req: Request) -> None:
+        """Give back what `req` holds of the sequence state: its page
+        references and, over a model with recurrent layers, its slot.
+        Nothing is cleared on the device: the next owner's prefill
+        writes the whole slot."""
+        self.allocator.free_all(req.pages)
+        req.pages = []
+        if req.state_slot is not None:
+            self.slot_allocator.free(req.state_slot)
+            req.state_slot = None
+            if self.obs is not None:
+                self.obs.state_slots(self.slot_allocator)
+
     def finish(self, req: Request) -> None:
         """Drop a completed request's page references; a page returns to
         the pool once no other sequence (and no cached prefix) holds it."""
         req.status = "finished"
-        self.allocator.free_all(req.pages)
-        req.pages = []
+        self._release(req)
         if req in self.running:
             self.running.remove(req)
         if self.obs is not None:
@@ -349,8 +369,7 @@ class Scheduler:
         req.error = error
         req.inflight = 0
         req.finish_t = time.perf_counter()
-        self.allocator.free_all(req.pages)
-        req.pages = []
+        self._release(req)
         if req in self.running:
             self.running.remove(req)
         if req in self.waiting:
@@ -445,6 +464,9 @@ class Scheduler:
     def _try_admit(self) -> Optional[Request]:
         if not self.waiting or len(self.running) >= self.max_batch_size:
             return None
+        # a running request holds one slot and there are as many slots as
+        # rows, so a free row is a free slot: pages alone can run out
+        slots = self.slot_allocator
         req = self.waiting[0]
         cached: List[int] = []
         if self.prefix_cache is not None:
@@ -467,9 +489,15 @@ class Scheduler:
                 cached = []
                 pages = self._alloc_n(self._admission_pages(req))
             if pages is None:
+                if slots is not None and self.obs is not None:
+                    self.obs.admission_blocked_on_pages()
                 return None
         self.waiting.pop(0)
         req.pages = cached + pages
+        if slots is not None:
+            req.state_slot = slots.alloc()
+            if self.obs is not None:
+                self.obs.state_slots(slots, allocated=True)
         req.cached_tokens = len(cached) * self.page_size
         # the engine advances the cursor to len(prompt) once the (whole-
         # prompt) prefill dispatch succeeds
@@ -508,8 +536,8 @@ class Scheduler:
                 "re-prefill after requeue would be impossible. "
                 "prefill_buckets must cover max_seq_len")
         self.running.remove(victim)
-        self.allocator.free_all(victim.pages)
-        victim.pages = []
+        # the slot goes with the pages: the re-prefill rebuilds the state
+        self._release(victim)
         victim.cached_tokens = 0
         victim.num_computed_tokens = 0   # re-prefill from scratch
         victim.inflight = 0     # drain_hook ran first: nothing undrained
@@ -791,6 +819,16 @@ class Scheduler:
         itself sound (`BlockAllocator.check_consistency`). Raises
         RuntimeError on the first violation."""
         self.allocator.check_consistency()
+        if self.slot_allocator is not None:
+            self.slot_allocator.check_consistency()
+            held = [r.state_slot for r in self.running]
+            if None in held or len(set(held)) != len(held) \
+                    or len(held) != self.slot_allocator.num_used \
+                    or any(r.state_slot is not None for r in self.waiting):
+                raise RuntimeError(
+                    "scheduler corrupt: the running requests' state slots "
+                    f"{held} are not the {self.slot_allocator.num_used} "
+                    "in use, one each")
         if self.prefix_cache is not None:
             self.prefix_cache.check_consistency()
         if set(map(id, self.waiting)) & set(map(id, self.running)):

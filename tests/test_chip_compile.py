@@ -361,14 +361,21 @@ def _compile_serve(eng, device, rows, bucket):
 
     state = (abstract(eng.params), abstract(eng.buffers))
     pools, pages = abstract(eng.cache.pools), eng.max_pages_per_seq
+
+    def slots(b):
+        # a model with recurrent layers is told each row's state slot
+        return ({"slots": sds((b,), jnp.int32)}
+                if eng.cache.slot_allocator is not None else {})
+
     decode = eng._decode_block_jit(8).lower(
         *state, sds((rows,), jnp.int32), pools,
         sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
         *knobs(rows), sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32)).compile()
+        sds((rows,), jnp.int32), **slots(rows)).compile()
     prefill = eng._prefill_jit(bucket).lower(
         *state, sds((1, bucket), jnp.int32), pools,
-        sds((1, pages), jnp.int32), sds((), jnp.int32), *knobs(1)).compile()
+        sds((1, pages), jnp.int32), sds((), jnp.int32), *knobs(1),
+        **slots(1)).compile()
     return {"decode_block": decode, "prefill": prefill}
 
 
@@ -547,12 +554,13 @@ def serve_pool_compiled(topo, kernel_paths):
     return _compile_serve(eng, topo.devices[0], 16, 2048)
 
 
-def _pool_sized_moves(text: str) -> list:
-    """The `copy` and `transpose` instructions whose result has as many
-    elements as a pool: the pool itself or any view of it."""
-    size = int(np.prod(_CELL_POOL))
+def _pool_sized_moves(text: str, pool=_CELL_POOL,
+                      ops: str = "copy|transpose") -> list:
+    """The `copy` and `transpose` (or `ops`) instructions whose result
+    has as many elements as `pool`: the pool itself or any view of it."""
+    size = int(np.prod(pool))
     moves = re.findall(r"\n\s*(?:ROOT )?(%\S+ = \w+\[([\d,]+)\]\S* "
-                       r"(?:copy|transpose)\([^\n]*)", text)
+                       r"(?:" + ops + r")\([^\n]*)", text)
     return [line[:240] for line, dims in moves
             if np.prod([int(d) for d in dims.split(",")]) == size]
 
@@ -636,24 +644,189 @@ def test_serve_scope_reaches_the_latent_model_s_step(mla_moe_hlo, scope):
     assert _scoped(mla_moe_hlo[program], scope)
 
 
+# each sub-scope's serving scope and the programs it is found in; the
+# Mamba-2 mixer's are the hybrid decoder's, the others the latent one's
+_SUBSCOPE_HOME = {
+    scopes.MLA_ABSORB: (scopes.PAGED_ATTENTION, ["decode_block"]),
+    scopes.SSM_IN_PROJ: (scopes.ATTN_QKV, ["decode_block", "prefill"]),
+    scopes.SSM_CONV: (scopes.ATTN_QKV, ["decode_block", "prefill"]),
+    scopes.SSM_STATE_UPDATE: (scopes.PAGED_ATTENTION, ["decode_block"]),
+    scopes.SSM_CHUNK_SCAN: (scopes.PREFILL_ATTENTION, ["prefill"]),
+    scopes.SSM_GATE_OUT: (scopes.ATTN_OUT, ["decode_block", "prefill"]),
+}
+
+
 @pytest.mark.parametrize("scope", scopes.SERVE_SUBSCOPES)
-def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, scope):
-    parent = (scopes.PAGED_ATTENTION if scope == scopes.MLA_ABSORB
-              else scopes.MLP)
-    programs = (["decode_block"] if scope == scopes.MLA_ABSORB
-                else ["decode_block", "prefill"])
+def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, hybrid_hlo, scope):
+    parent, programs = _SUBSCOPE_HOME.get(
+        scope, (scopes.MLP, ["decode_block", "prefill"]))
+    hlo = hybrid_hlo if scope.startswith("ssm_") else mla_moe_hlo
     for program in programs:
-        names = _scoped(mla_moe_hlo[program], scope)
+        names = _scoped(hlo[program], scope)
         assert names, (program, scope)
         # nested inside the serving scope the benchmark's list holds
         assert all(re.search(parent + r"/(?:[^/]+/)*" + scope, n)
                    for n in names)
 
 
+# ------------------ the hybrid (Mamba-2 + attention) decoder's state
+
+# one layer's pools behind the small engine below: 4 rows + the null
+# slot of 8 heads of 64 with a state of 128, two heads a 128-lane row;
+# 2 kv heads of 64 packed into one row block, 4 x 32 + 1 pages of 16
+_SSM_POOL = (5, 4, 128, 128)
+_HYBRID_KV_POOL = (1, 129, 16, 128)
+
+
+@pytest.fixture(scope="module")
+def hybrid_compiled(topo, kernel_paths):
+    """The decode block and a bucketed prefill of a small hybrid decoder
+    (one Mamba-2 layer at the published head width and state, one
+    attention layer of 64-wide heads) behind a default engine."""
+    from paddle_tpu.models import HybridSsmConfig, HybridSsmForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = HybridSsmConfig(
+        vocab_size=_VOCAB, hidden_size=256, num_hidden_layers=2,
+        layer_types=("mamba", "attention"), num_attention_heads=4,
+        num_key_value_heads=2, shared_intermediate_size=768,
+        mamba_n_heads=8, max_position_embeddings=_SEQ, dtype="bfloat16",
+        deferred_weights=True)
+    model = HybridSsmForCausalLM(cfg)
+    model.eval()
+    eng = ServingEngine(model, page_size=16, max_batch_size=4,
+                        max_seq_len=_SEQ, kv_dtype="bf16")
+    ssm_pool, conv_pool = eng.cache.pools[0]
+    assert ssm_pool.shape == _SSM_POOL and ssm_pool.dtype == jnp.float32
+    assert eng.cache.pools[1][0].shape == _HYBRID_KV_POOL
+    return _compile_serve(eng, topo.devices[0], 4, _SEQ)
+
+
+@pytest.fixture(scope="module")
+def hybrid_hlo(hybrid_compiled):
+    return {name: c.as_text() for name, c in hybrid_compiled.items()}
+
+
+def test_ssm_decode_kernel_is_named_where_it_is_created(hybrid_hlo):
+    calls = _custom_calls(hybrid_hlo["decode_block"])
+    assert scopes.SSM_DECODE_KERNEL in calls
+    # the attention layer of the same step takes the K/V pools' kernel
+    assert scopes.PAGED_DECODE_KERNEL in calls
+    assert scopes.SSM_DECODE_KERNEL not in _custom_calls(
+        hybrid_hlo["prefill"])
+
+
+def _ssm_pool_faults(text: str, pool=_SSM_POOL) -> list:
+    """What is wrong with the state pools of a decode step's text: a
+    pool that is not float32, or an `ssm_decode` call that does not
+    write its pool operand (operand 5) in place."""
+    dims = ",".join(str(d) for d in pool)
+    faults = [f"{t}[{dims}]" for t in set(re.findall(
+        r"\b(\w+)\[" + dims + r"\]", text)) if t != "f32"]
+    calls = re.findall(r"%ssm_decode[.\d]* = [^\n]*custom-call\([^\n]*",
+                       text)
+    faults += ["not aliased: " + c[:80] for c in calls
+               if not re.search(r"output_to_operand_aliasing=\{[^\n]*"
+                                r"\{1\}: \(5, \{\}\)", c)]
+    return faults + ([] if calls else ["no ssm_decode call"])
+
+
+def test_ssm_pools_are_float32_and_updated_in_place(hybrid_hlo):
+    text = hybrid_hlo["decode_block"]
+    assert "f32[5,4,128,128]" in text
+    assert _ssm_pool_faults(text) == []
+
+
+def test_an_ssm_pool_that_is_copied_or_narrowed_is_caught(topo,
+                                                          monkeypatch):
+    """The same checks on a kernel broken on purpose: a pool in bf16,
+    and the call without `input_output_aliases`, which hands back a
+    second pool instead of the first one updated."""
+    from jax.experimental import pallas as pl
+    from paddle_tpu.serving import ssm
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def lowered(pool_dtype):
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        b, hk, n, lanes = 4, *_SSM_POOL[1:]
+        # a function of its own each time: nothing traced before the
+        # kernel was broken is found again
+        return jax.jit(lambda *a: ssm._ssm_decode_pallas.__wrapped__(
+            *a)).lower(
+            sds((b, hk, lanes), jnp.float32),
+            sds((b, hk, lanes), jnp.float32), sds((b, n, 1), jnp.float32),
+            sds((b, n, 1), jnp.float32), sds(_SSM_POOL, pool_dtype),
+            sds((b,), jnp.int32)).compile().as_text()
+
+    sound = lowered(jnp.float32)
+    assert _ssm_pool_faults(sound) == []
+    # the kernel writes float32 and refuses a narrower pool outright; a
+    # text that held one all the same is told
+    with pytest.raises(ValueError, match="bfloat16"):
+        lowered(jnp.bfloat16)
+    assert "bf16[5,4,128,128]" in _ssm_pool_faults(
+        sound.replace("f32[5,4,128,128]", "bf16[5,4,128,128]"))
+    real = pl.pallas_call
+
+    def unaliased(*args, input_output_aliases=None, **kwargs):
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", unaliased)
+    assert any(f.startswith("not aliased")
+               for f in _ssm_pool_faults(lowered(jnp.float32)))
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill"])
+def test_no_move_of_a_state_pool_or_a_kv_pool(hybrid_hlo, program):
+    """Neither kind of sequence state is copied, padded or transposed
+    whole: the state kernel and the K/V write update donated pools in
+    place, and a row of two packed 64-wide heads is a whole tile that
+    the decode kernel takes as it lies."""
+    text = hybrid_hlo[program]
+    assert "[5,4,128,128]" in text and "[1,129,16,128]" in text
+    for pool in (_SSM_POOL, _HYBRID_KV_POOL):
+        assert _pool_sized_moves(text, pool, "copy|transpose|pad") == []
+
+
+def test_a_padded_kv_pool_is_caught(topo):
+    """The same check on the path 64-wide heads took before their pool
+    was packed: one head a row, and `_paged_decode_pallas` pads the
+    whole pool to 128 columns at every call."""
+    unpacked = (2, 129, 16, 64)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def text(pool, pack):
+        step = functools.partial(paged._paged_decode_pallas.__wrapped__,
+                                 pack=pack)
+        return jax.jit(step).lower(
+            sds((4, 1, 4, 64), jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds((4, 32), jnp.int32),
+            sds((4,), jnp.int32)).compile().as_text()
+
+    moved = "copy|transpose|pad|fusion"
+    assert _pool_sized_moves(text(_HYBRID_KV_POOL, 2), _HYBRID_KV_POOL,
+                             moved) == []
+    # the one-head rows padded to a tile: as many elements as two rows
+    assert _pool_sized_moves(text(unpacked, 1), (2, 129, 16, 128),
+                             moved) != []
+
+
+@pytest.mark.parametrize("scope", scopes.SERVE_SCOPES)
+def test_serve_scope_reaches_the_hybrid_model_s_step(hybrid_hlo, scope):
+    program = ("prefill" if scope == scopes.PREFILL_ATTENTION
+               else "decode_block")
+    assert _scoped(hybrid_hlo[program], scope)
+
+
 # --------- what the measured programs must not hold (no chip: the text)
 
 @pytest.fixture(scope="module")
-def programs(train_hlo, serve_hlo, mla_moe_hlo):
+def programs(train_hlo, serve_hlo, mla_moe_hlo, hybrid_hlo):
     """The compiler's text of the programs the benchmark's cells run, by
     name: `ZeroTrainStep` over ERNIE with the fused loss, and the decode
     block and bucketed prefill of a default engine over GPT and over the
@@ -661,7 +834,9 @@ def programs(train_hlo, serve_hlo, mla_moe_hlo):
     return {"train": train_hlo,
             **{f"gpt_{name}": text for name, text in serve_hlo.items()},
             **{f"mla_moe_{name}": text
-               for name, text in mla_moe_hlo.items()}}
+               for name, text in mla_moe_hlo.items()},
+            **{f"hybrid_{name}": text
+               for name, text in hybrid_hlo.items()}}
 
 
 def _arrays(text: str) -> set:
@@ -672,7 +847,8 @@ def _arrays(text: str) -> set:
 
 
 @pytest.mark.parametrize("program,rows", [
-    ("train", _TRAIN_ROWS), ("gpt_prefill", 1), ("mla_moe_prefill", 1)])
+    ("train", _TRAIN_ROWS), ("gpt_prefill", 1), ("mla_moe_prefill", 1),
+    ("hybrid_prefill", 1)])
 def test_no_whole_attention_matrix(programs, program, rows):
     """Flash keeps the scores in VMEM a block at a time: no array has
     two dimensions of the sequence and room for every row's every head
@@ -684,7 +860,8 @@ def test_no_whole_attention_matrix(programs, program, rows):
 
 
 @pytest.mark.parametrize("program,tokens", [
-    ("train", _TRAIN_ROWS * _SEQ), ("mla_moe_prefill", _SEQ)])
+    ("train", _TRAIN_ROWS * _SEQ), ("mla_moe_prefill", _SEQ),
+    ("hybrid_prefill", _SEQ)])
 def test_no_whole_logits(programs, program, tokens):
     """The fused linear + cross-entropy of the train step sees the
     vocabulary a chunk of 2,048 tokens at a time, and the latent model's
@@ -710,7 +887,7 @@ def _matmul_operands(text: str) -> list:
 
 @pytest.mark.parametrize("program", [
     "train", "gpt_decode_block", "gpt_prefill", "mla_moe_decode_block",
-    "mla_moe_prefill"])
+    "mla_moe_prefill", "hybrid_decode_block", "hybrid_prefill"])
 def test_every_matmul_takes_bf16_operands(programs, program):
     """One float32 operand forfeits the MXU's bf16 rate. No exception is
     needed: where the design computes in float32 (the latent model's
@@ -763,7 +940,7 @@ def _sampler_sorts(body: str) -> list:
 
 @pytest.mark.parametrize("program", [
     "gpt_decode_block", "gpt_prefill", "mla_moe_decode_block",
-    "mla_moe_prefill"])
+    "mla_moe_prefill", "hybrid_decode_block", "hybrid_prefill"])
 def test_sampler_sorts_only_inside_a_conditional_s_branch(programs, program):
     """`_sample_batch` sorts the vocabulary (twice) only where some row
     of the batch samples: in the compiler's text both sorts sit in a

@@ -971,6 +971,44 @@ class TestServingSampling:
         eng.run()
         assert eng.compile_counts()["sample"] <= 2
 
+    def test_keys_are_host_rows_and_a_seeded_stream_ignores_its_batch(self):
+        """A row's key comes back with its tokens (a prefill's, a
+        drained block's) and is kept as host memory, so taking a batch's
+        keys apart and stacking a fresh block's costs no device
+        operation a row; the chain itself is untouched: a seeded
+        request samples the same tokens alone and in a batch whose
+        composition changes under it (every change is a drain, a
+        restack on the host and a fresh block)."""
+        def engine():
+            return ServingEngine(_llama(), page_size=8, max_batch_size=4,
+                                 max_seq_len=32, prefill_buckets=(16, 32),
+                                 decode_horizon=4)
+
+        kw = dict(max_new_tokens=17, temperature=0.8, top_k=6, seed=21)
+        alone = engine()
+        rid = alone.add_request([3, 1, 4, 1, 5], **kw)
+        assert not isinstance(alone._key_state[rid], np.ndarray)
+        alone.step()                                    # the prefill
+        assert isinstance(alone._key_state[rid], np.ndarray)
+        alone.run()
+        want = alone.output(rid)
+        assert len(want) == 5 + 17      # prompt and sampled tokens
+
+        eng = engine()
+        rid = eng.add_request([3, 1, 4, 1, 5], **kw)
+        eng.add_request([9, 2], max_new_tokens=3, temperature=0.9, seed=4)
+        for _ in range(3):
+            eng.step()
+        eng.add_request([7, 7, 7], max_new_tokens=6, temperature=0.0)
+        for _ in range(4):
+            eng.step()
+        eng.add_request([5], max_new_tokens=2, temperature=0.5, seed=8)
+        eng.run()
+        assert eng.output(rid) == want
+        keys = list(eng._key_state.values())
+        assert keys and all(isinstance(k, np.ndarray) and k.shape == (2,)
+                            and k.dtype == np.uint32 for k in keys)
+
 
 def _sample_batch_before(logits, keys, temps, top_ks, top_ps):
     """`engine._sample_batch` as it stood before a batch of greedy rows

@@ -102,15 +102,48 @@ def _sds(shape, dtype, vma):
 
 # ---------------------------------------------------------------- forward
 
-def _qkv_layout(qt, kt, *, heads, block_q, block_k, kv_major, vma):
+def _causal_block_live(qi, ki, block_q, block_k, offs=None):
+    """Whether block (qi, ki) holds a score the causal mask lets through:
+    its last row against its first column, by the in-block mask's own
+    `rows >= cols` rule. A block that is not live is all -inf: it leaves
+    m, l and the accumulators as they were, so the three kernels run
+    their bodies under this predicate alone. `offs` holds a ring step's
+    global (q, k) offsets (the SMEM ref) or is None; Python integers work
+    as well as grid ids."""
+    q_off, k_off = (0, 0) if offs is None else (offs[0], offs[1])
+    return q_off + (qi + 1) * block_q - 1 >= k_off + ki * block_k
+
+
+def flash_block_steps(sq_p, sk_p, block_q, block_k, is_causal):
+    """(computed, total) grid steps a (batch, head) of one flash call over
+    padded lengths `sq_p` x `sk_p`: what the kernels' predicate keeps, for
+    the tests and for PERF.md's reckoning."""
+    n_q, n_k = sq_p // block_q, sk_p // block_k
+    if not is_causal:
+        return n_q * n_k, n_q * n_k
+    live = sum(bool(_causal_block_live(qi, ki, block_q, block_k))
+               for qi in range(n_q) for ki in range(n_k))
+    return live, n_q * n_k
+
+
+def _qkv_layout(qt, kt, *, heads, block_q, block_k, kv_major, vma,
+                clamp_causal=False):
     """Shared layout selection for the three flash kernels.
 
-    Returns (b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like) where
-    `blk` slices a grid block out of a q/k/v/do ref, `q_spec`/`k_spec`
-    are the BlockSpecs for row/col operands, and `sds_like(rows_p, dt)`
-    builds an output ShapeDtypeStruct in the active layout. `kv_major`
+    Returns (b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like,
+    coords) where `blk` slices a grid block out of a q/k/v/do ref,
+    `q_spec`/`k_spec` are the BlockSpecs for row/col operands,
+    `sds_like(rows_p, dt)` builds an output ShapeDtypeStruct in the active
+    layout, and `coords(i2, i3)` gives the (qi, ki) of the blocks a grid
+    step brings, for the specs the callers build themselves. `kv_major`
     flips the grid's (qi, ki) order to (ki, qi) — the dkv kernel
-    accumulates over q, so its k index comes third."""
+    accumulates over q, so its k index comes third.
+
+    With `clamp_causal` (the static causal mask: no ring offsets) a step
+    that `_causal_block_live` rules out names the nearest live block of
+    its row instead of its own — the one already in VMEM — so the
+    pipeline brings nothing for a step that computes nothing: the k side
+    is clamped from above, or with `kv_major` the q side from below."""
     from jax.experimental import pallas as pl
 
     packed = heads is not None
@@ -134,12 +167,22 @@ def _qkv_layout(qt, kt, *, heads, block_q, block_k, kv_major, vma):
             (1, 1, block, d_p),
             lambda b_, h_, i2, i3: (b_, h_, pick(i2, i3), 0))
 
-    if kv_major:   # grid (b, h, ki, qi)
-        q_spec = spec(block_q, lambda ki, qi: qi)
-        k_spec = spec(block_k, lambda ki, qi: ki)
-    else:          # grid (b, h, qi, ki)
-        q_spec = spec(block_q, lambda qi, ki: qi)
-        k_spec = spec(block_k, lambda qi, ki: ki)
+    n_q = sq_p // block_q
+
+    def coords(i2, i3):
+        # grid (b, h, ki, qi) when kv_major, else (b, h, qi, ki)
+        qi, ki = (i3, i2) if kv_major else (i2, i3)
+        if clamp_causal and kv_major:
+            # first live q block of this k column; a column past every
+            # row (sk > sq) has none and stays on the last block
+            qi = jnp.minimum(jnp.maximum(qi, ki * block_k // block_q),
+                             n_q - 1)
+        elif clamp_causal:
+            ki = jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_k)
+        return qi, ki
+
+    q_spec = spec(block_q, lambda i2, i3: coords(i2, i3)[0])
+    k_spec = spec(block_k, lambda i2, i3: coords(i2, i3)[1])
 
     def sds_like(rows_p, dtype):
         if packed:
@@ -147,7 +190,7 @@ def _qkv_layout(qt, kt, *, heads, block_q, block_k, kv_major, vma):
         return _sds((b, h, rows_p, d_p), dtype, vma)
 
     blk = (lambda ref: ref[0]) if packed else (lambda ref: ref[0, 0])
-    return b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like
+    return b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like, coords
 
 
 def _blk_store(packed, ref, value):
@@ -155,6 +198,19 @@ def _blk_store(packed, ref, value):
         ref[0] = value
     else:
         ref[0, 0] = value
+
+
+def _mask_spec(coords, block_q, block_k, b_is_one, h_is_one, q_is_one):
+    """BlockSpec of the additive mask: broadcast (size-1) dims stay on
+    block 0, the others follow the step's (qi, ki) of `_qkv_layout`."""
+    from jax.experimental import pallas as pl
+
+    def index(b_, h_, i2, i3):
+        qi, ki = coords(i2, i3)
+        return (0 if b_is_one else b_, 0 if h_is_one else h_,
+                0 if q_is_one else qi, ki)
+
+    return pl.BlockSpec((1, 1, 1 if q_is_one else block_q, block_k), index)
 
 
 def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
@@ -176,13 +232,15 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
     from jax.experimental.pallas import tpu as pltpu
 
     packed = heads is not None
-    b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like = _qkv_layout(
+    dyn_offsets = offs is not None
+    (b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like,
+     coords) = _qkv_layout(
         qt, kt, heads=heads, block_q=block_q, block_k=block_k,
-        kv_major=False, vma=vma)
+        kv_major=False, vma=vma,
+        clamp_causal=is_causal and not dyn_offsets)
     n_q, n_k = sq_p // block_q, sk_p // block_k
     need_k_mask = sk_p != sk
     has_dropout = dropout_p > 0.0
-    dyn_offsets = offs is not None
 
     def kernel(*refs):
         refs = list(refs)
@@ -192,6 +250,8 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
         seed_ref = refs.pop(0) if has_dropout else None
         offs_ref = refs.pop(0) if dyn_offsets else None
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+        # grid ids are read here: the body below may run under pl.when
+        b_, h_ = pl.program_id(0), pl.program_id(1)
         qi = pl.program_id(2)
         ki = pl.program_id(3)
 
@@ -245,8 +305,7 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
             # uses UNdropped p
             p_acc = p
             if has_dropout:
-                keep = _keep_mask(pltpu, seed_ref, pl.program_id(0),
-                                  pl.program_id(1), qi, ki,
+                keep = _keep_mask(pltpu, seed_ref, b_, h_, qi, ki,
                                   (block_q, block_k), dropout_p, interpret)
                 p_acc = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
             # p cast to V's dtype: bf16 inputs keep the PV matmul on the
@@ -256,14 +315,13 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
                 preferred_element_type=jnp.float32,
                 precision=jax.lax.Precision.DEFAULT)
 
-        if is_causal and dyn_offsets:
-            # splash-style whole-block skip: a causal ring step whose k
-            # block lies entirely in the future contributes nothing — skip
-            # its MXU work (the uniform grid still visits the block, so the
+        if is_causal:
+            # splash-style whole-block skip: a k block that lies entirely
+            # in the future of its q block contributes nothing — skip its
+            # work (the uniform grid still visits the step, so a ring's
             # SPMD program stays identical on every rank)
-            q_hi = offs_ref[0] + (qi + 1) * block_q - 1   # max global row
-            k_lo = offs_ref[1] + ki * block_k             # min global col
-            pl.when(q_hi >= k_lo)(_compute)
+            pl.when(_causal_block_live(qi, ki, block_q, block_k,
+                                       offs_ref))(_compute)
         else:
             _compute()
 
@@ -283,11 +341,8 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
     in_specs = [q_spec, k_spec, k_spec]
     operands = [qt, kt, vt]
     if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, 1 if mask_q_is_one else block_q, block_k),
-            lambda b_, h_, qi, ki: (0 if mask_b_is_one else b_,
-                                    0 if mask_h_is_one else h_,
-                                    0 if mask_q_is_one else qi, ki)))
+        in_specs.append(_mask_spec(coords, block_q, block_k, mask_b_is_one,
+                                   mask_h_is_one, mask_q_is_one))
         operands.append(mask)
     if dropout_p > 0.0:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -358,13 +413,15 @@ def _bwd_dq_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
     from jax.experimental.pallas import tpu as pltpu
 
     packed = heads is not None
-    b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like = _qkv_layout(
+    dyn_offsets = offs is not None
+    (b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like,
+     coords) = _qkv_layout(
         qt, kt, heads=heads, block_q=block_q, block_k=block_k,
-        kv_major=False, vma=vma)
+        kv_major=False, vma=vma,
+        clamp_causal=is_causal and not dyn_offsets)
     n_q, n_k = sq_p // block_q, sk_p // block_k
     need_k_mask = sk_p != sk
     has_dropout = dropout_p > 0.0
-    dyn_offsets = offs is not None
 
     def kernel(*refs):
         refs = list(refs)
@@ -379,6 +436,8 @@ def _bwd_dq_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
             dq_ref, dmask_ref, acc_ref = outs
         else:
             dq_ref, acc_ref = outs
+        # grid ids are read here: the body below may run under pl.when
+        b_, h_ = pl.program_id(0), pl.program_id(1)
         qi = pl.program_id(2)
         ki = pl.program_id(3)
 
@@ -399,8 +458,7 @@ def _bwd_dq_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
                 precision=jax.lax.Precision.DEFAULT)
             if has_dropout:
                 # dP = M/(1-r) ∘ dP_dropped — same mask as fwd (same seeds)
-                keep = _keep_mask(pltpu, seed_ref, pl.program_id(0),
-                                  pl.program_id(1), qi, ki,
+                keep = _keep_mask(pltpu, seed_ref, b_, h_, qi, ki,
                                   (block_q, block_k), dropout_p, interpret)
                 dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
             ds = p * (dp - delta_ref[0, 0, 0][:, None])
@@ -415,10 +473,15 @@ def _bwd_dq_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
                 preferred_element_type=jnp.float32,
                 precision=jax.lax.Precision.DEFAULT) * scale
 
-        if is_causal and dyn_offsets:
-            q_hi = offs_ref[0] + (qi + 1) * block_q - 1
-            k_lo = offs_ref[1] + ki * block_k
-            pl.when(q_hi >= k_lo)(_compute)
+        if is_causal:
+            live = _causal_block_live(qi, ki, block_q, block_k, offs_ref)
+            pl.when(live)(_compute)
+            if want_dmask:
+                # every (qi, ki) block of d(mask) is its own output
+                # block: one the mask rules out has ds = 0
+                @pl.when(jnp.logical_not(live))
+                def _no_dmask():
+                    dmask_ref[0, 0] = jnp.zeros_like(dmask_ref[0, 0])
         else:
             _compute()
 
@@ -433,11 +496,8 @@ def _bwd_dq_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
     in_specs = [q_spec, k_spec, k_spec]
     operands = [qt, kt, vt]
     if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, 1 if mask_q_is_one else block_q, block_k),
-            lambda b_, h_, qi, ki: (0 if mask_b_is_one else b_,
-                                    0 if mask_h_is_one else h_,
-                                    0 if mask_q_is_one else qi, ki)))
+        in_specs.append(_mask_spec(coords, block_q, block_k, mask_b_is_one,
+                                   mask_h_is_one, mask_q_is_one))
         operands.append(mask)
     if has_dropout:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -475,13 +535,15 @@ def _bwd_dkv_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
     from jax.experimental.pallas import tpu as pltpu
 
     packed = heads is not None
-    b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like = _qkv_layout(
+    dyn_offsets = offs is not None
+    (b, h, sq_p, sk_p, d_p, blk, q_spec, k_spec, sds_like,
+     coords) = _qkv_layout(
         qt, kt, heads=heads, block_q=block_q, block_k=block_k,
-        kv_major=True, vma=vma)
+        kv_major=True, vma=vma,
+        clamp_causal=is_causal and not dyn_offsets)
     n_q, n_k = sq_p // block_q, sk_p // block_k
     need_k_mask = sk_p != sk
     has_dropout = dropout_p > 0.0
-    dyn_offsets = offs is not None
 
     def kernel(*refs):
         refs = list(refs)
@@ -491,6 +553,7 @@ def _bwd_dkv_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
         seed_ref = refs.pop(0) if has_dropout else None
         offs_ref = refs.pop(0) if dyn_offsets else None
         do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
+        b_, h_ = pl.program_id(0), pl.program_id(1)
         ki = pl.program_id(2)
         qi = pl.program_id(3)   # q innermost: it is the accumulated dim here
 
@@ -510,8 +573,7 @@ def _bwd_dkv_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
             if has_dropout:
                 # seed args in (b, h, qi, ki) order — identical to fwd/dq
                 # even though this kernel's grid iterates (ki, qi)
-                keep = _keep_mask(pltpu, seed_ref, pl.program_id(0),
-                                  pl.program_id(1), qi, ki,
+                keep = _keep_mask(pltpu, seed_ref, b_, h_, qi, ki,
                                   (block_q, block_k), dropout_p, interpret)
                 p_d = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
             else:
@@ -533,10 +595,9 @@ def _bwd_dkv_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
                 preferred_element_type=jnp.float32,
                 precision=jax.lax.Precision.DEFAULT) * scale  # ds^T @ Q
 
-        if is_causal and dyn_offsets:
-            q_hi = offs_ref[0] + (qi + 1) * block_q - 1
-            k_lo = offs_ref[1] + ki * block_k
-            pl.when(q_hi >= k_lo)(_compute)
+        if is_causal:
+            pl.when(_causal_block_live(qi, ki, block_q, block_k,
+                                       offs_ref))(_compute)
         else:
             _compute()
 
@@ -545,16 +606,14 @@ def _bwd_dkv_call(qt, kt, vt, mask, seed, dot, lse, delta, *, scale, sk,
             _blk_store(packed, dk_ref, dk_acc[...].astype(dk_ref.dtype))
             _blk_store(packed, dv_ref, dv_acc[...].astype(dv_ref.dtype))
 
-    row_spec = pl.BlockSpec((1, 1, 8, block_q),
-                            lambda b_, h_, ki, qi: (b_, h_, 0, qi))
+    row_spec = pl.BlockSpec(
+        (1, 1, 8, block_q),
+        lambda b_, h_, i2, i3: (b_, h_, 0, coords(i2, i3)[0]))
     in_specs = [q_spec, k_spec, k_spec]
     operands = [qt, kt, vt]
     if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, 1 if mask_q_is_one else block_q, block_k),
-            lambda b_, h_, ki, qi: (0 if mask_b_is_one else b_,
-                                    0 if mask_h_is_one else h_,
-                                    0 if mask_q_is_one else qi, ki)))
+        in_specs.append(_mask_spec(coords, block_q, block_k, mask_b_is_one,
+                                   mask_h_is_one, mask_q_is_one))
         operands.append(mask)
     if has_dropout:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))

@@ -262,8 +262,7 @@ def paged_attend(q, k, v, cache: PagedLayerCache, start_pos, rep,
             ctx = paged_decode_attention(q, new_cache, pos[:, 0], rep,
                                          bias=bias)
         elif static_zero and not cache.quantized:
-            _count_dispatch("prefill")
-            ctx = _prefill_attention(q, kd, vd, pos, rep, bias=bias)
+            ctx = _prefill_attention(q, kd, vd, rep, bias=bias)
         elif static_zero:
             # quantized pools route EVERY multi-token prefill through the
             # paged gather: the exact path would read the un-quantized fresh
@@ -348,23 +347,28 @@ def _crop_bias(bias, length: int) -> jnp.ndarray:
                    + ((0, length - have),))
 
 
-def _prefill_attention(q, kd, vd, pos, rep, bias=None):
-    """Prefill attends over this step's own K/V block (the sequence starts
-    at position 0, so the block IS the cache) — same mask arithmetic as
-    the static-cache path for exact parity."""
+def _prefill_attention(q, kd, vd, rep, bias=None):
+    """The prefill from position 0 and nothing else: the step's own K/V
+    block IS the cache and every row sits at its own index, so the mask is
+    the plain causal one and the call says so: the flash kernel then skips
+    the blocks above the diagonal and no (s, s) array exists. A bias (one
+    head's additive term a score) cannot ride the flag: it goes in as a
+    dense mask with the causal -1e9 folded in, the static-cache path's
+    arithmetic."""
     from ..nn import functional as F
 
+    kf = Tensor(_expand_kv(kd, rep))
+    vf = Tensor(_expand_kv(vd, rep))
+    if bias is None:
+        _count_dispatch("prefill")
+        return F.scaled_dot_product_attention(q, kf, vf, is_causal=True)
+    _count_dispatch("prefill_masked")
     s = kd.shape[1]
-    kf = _expand_kv(kd, rep)
-    vf = _expand_kv(vd, rep)
-    # query at global pos[i, r] sees keys at pos[i, c] <= pos[i, r]; with
-    # a shared offset this is plain causal, kept per-row for generality
-    allowed = pos[:, None, :] <= pos[:, :, None]          # (b, s, s)
-    mask = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)[:, None]
-    if bias is not None:
-        mask = mask + _crop_bias(bias, s).astype(jnp.float32)
+    allowed = jnp.tril(jnp.ones((s, s), bool))
+    mask = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)[None, None]
+    mask = mask + _crop_bias(bias, s).astype(jnp.float32)
     return F.scaled_dot_product_attention(
-        q, Tensor(kf), Tensor(vf), attn_mask=Tensor(mask), is_causal=False)
+        q, kf, vf, attn_mask=Tensor(mask), is_causal=False)
 
 
 def _prefill_attention_paged(q, cache: PagedLayerCache, pos, rep,

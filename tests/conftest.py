@@ -72,3 +72,34 @@ def host_drawn_weights(monkeypatch):
 
     monkeypatch.setattr(initializer.Normal, "__call__", normal)
     monkeypatch.setattr(initializer.Uniform, "__call__", uniform)
+
+
+@pytest.fixture
+def attention_dispatches():
+    """`serving_attention_dispatch_total` by path, as counted since the
+    test began: the counter is process-global and counts while tracing."""
+    import collections
+
+    import chip_smoke
+
+    before = chip_smoke._dispatch_counts()
+    return lambda: collections.Counter(chip_smoke._dispatch_delta(before))
+
+
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """The flash kernel itself, interpreted, where the CPU would take the
+    XLA reference op; gives the (is_causal, had a mask) of each call."""
+    from paddle_tpu.ops import pallas_kernels
+
+    calls, real = [], pallas_kernels.flash_attention
+
+    def flash(q, k, v, attn_mask=None, is_causal=False, **kw):
+        calls.append((is_causal, attn_mask is not None))
+        return real(q, k, v, attn_mask, is_causal=is_causal,
+                    **{**kw, "interpret": True})
+
+    monkeypatch.setattr(pallas_kernels, "flash_attention_available",
+                        lambda *a, **k: True)
+    monkeypatch.setattr(pallas_kernels, "flash_attention", flash)
+    return calls

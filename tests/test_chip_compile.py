@@ -859,6 +859,66 @@ def test_no_whole_attention_matrix(programs, program, rows):
     assert whole == []
 
 
+def _sequence_squares(text: str, rank: int = 2) -> list:
+    """Arrays of at least `rank` dimensions, two of them the sequence's,
+    of any dtype and any size: a dense `(1, 1, s, s)` mask is one, 16.8
+    MB in float32 at the 2,048 bucket, which the gate above is too
+    coarse to refuse."""
+    return sorted(a for a in _arrays(text)
+                  if a[1].count(_SEQ) >= 2 and len(a[1]) >= rank)
+
+
+@pytest.mark.parametrize("program,rank", [("gpt_prefill", 2),
+                                          ("hybrid_prefill", 4)])
+def test_prefill_holds_no_sequence_by_sequence_array(programs, program,
+                                                     rank):
+    """The exact prefill tells flash that it is causal and builds no
+    mask: nothing in the program is `[.., s, s]`. The small hybrid
+    model's Mamba-2 mixer is as wide as the sequence is long (512), so
+    its `[1, s, 512]` activations stand and the check there is on what
+    flash would be handed, the mask's own four dimensions."""
+    assert _sequence_squares(programs[program], rank) == []
+
+
+def test_a_dense_prefill_mask_is_caught(topo, kernel_paths, monkeypatch):
+    """The same check with `_prefill_attention` put back as it was: the
+    mask built from the positions and handed to flash, `is_causal` off."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.serving.kv_cache import PagedLayerCache
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def text():
+        # a function of its own each time: nothing traced before the
+        # patch is found again
+        def prefill(q, k, v, k_pool, v_pool, page_table):
+            view = PagedLayerCache(k_pool, v_pool, page_table)
+            return paged.paged_attend(Tensor(q), Tensor(k), Tensor(v),
+                                      view, 0, 1)[0]._data
+        row = sds((1, _SEQ, _HEADS, 128), jnp.bfloat16)
+        pool = sds((_HEADS, 33, 16, 128), jnp.bfloat16)
+        return jax.jit(prefill).lower(
+            row, row, row, pool, pool,
+            sds((1, _SEQ // 16), jnp.int32)).compile().as_text()
+
+    def dense_mask(q, kd, vd, rep, bias=None):
+        pos = jnp.arange(_SEQ, dtype=jnp.int32)[None]
+        allowed = pos[:, None, :] <= pos[:, :, None]
+        mask = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)[:, None]
+        return F.scaled_dot_product_attention(
+            q, Tensor(kd), Tensor(vd), attn_mask=Tensor(mask),
+            is_causal=False)
+
+    sound = text()
+    assert "tpu_custom_call" in sound and _sequence_squares(sound) == []
+    monkeypatch.setattr(paged, "_prefill_attention", dense_mask)
+    assert ("f32", (1, 1, _SEQ, _SEQ)) in _sequence_squares(text())
+
+
 @pytest.mark.parametrize("program,tokens", [
     ("train", _TRAIN_ROWS * _SEQ), ("mla_moe_prefill", _SEQ),
     ("hybrid_prefill", _SEQ)])

@@ -402,6 +402,30 @@ def test_engine_serves_the_reference_s_tokens_over_several_blocks(
         _engine(_seeded(CFG)[0]).compile_counts())
 
 
+def test_the_attention_layer_s_prefill_says_causal(
+        seeded, flash_interpreted, attention_dispatches):
+    """With the flash kernel itself interpreted: the attention layer of
+    each prefill executable takes the causal flag (dispatch path
+    `prefill`, never `prefill_masked`: the model has no bias) and the
+    served tokens are still the reference's."""
+    model, leaves, cfg = seeded
+    prompt = np.random.default_rng(9).integers(0, CFG.vocab_size, 19)
+    eng = _engine(model)
+    rid = eng.add_request(prompt.tolist(), max_new_tokens=5,
+                          temperature=0.0, seed=0)
+    eng.run()
+    model.__dict__.pop("_serving_jit_cache", None)
+    attention_layers = CFG.layer_types.count("attention")
+    counts = attention_dispatches()
+    assert counts["prefill"] == attention_layers
+    assert not counts["prefill_masked"]
+    assert flash_interpreted.count((True, False)) == attention_layers
+    ids = prompt.tolist() + eng.requests[rid].generated
+    want = np.asarray(reference.logits(
+        leaves, ids, np.arange(len(prompt) - 1, len(ids) - 1), cfg))
+    assert want.argmax(-1).tolist() == eng.requests[rid].generated
+
+
 def test_a_reused_slot_starts_from_zero(seeded, kernel_mode):
     """One row, so that the second request takes the slot the first
     left full: its stream is the one it has on a fresh engine."""

@@ -13,7 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.pallas_kernels import _flash_attention_data
+from paddle_tpu.ops.pallas_kernels import (_BLOCK_K, _BLOCK_Q,
+                                           _causal_block_live,
+                                           _flash_attention_data,
+                                           _fwd_call, _keep_mask,
+                                           _qkv_layout, _round_up,
+                                           flash_block_steps)
 
 
 def _ref_attention(q, k, v, mask=None, is_causal=False):
@@ -45,7 +50,212 @@ CASES = [
     (96, 96, 1, 80, False),      # d not a power of two
     (128, 128, 4, 128, True),    # d=128: PACKED (b, S, h*d) layout
     (200, 200, 2, 128, False),   # packed + ragged seq padding
+    # several 512-blocks a side: the causal grid skips whole blocks
+    (1536, 1536, 1, 64, True),
+    (640, 1536, 2, 128, True),   # packed, sk > sq: k columns past every row
+    (1536, 640, 1, 192, True),   # d padded to 256, sq > sk, ragged blocks
 ]
+
+
+# ------------------------------------------------- the causal block skip
+GRIDS = [
+    # (sq, sk, block_q, block_k): real lengths, ragged last blocks
+    (16384, 16384, 512, 512),
+    (2048, 2048, 512, 512),
+    (1024, 1024, 512, 512),
+    (1536, 640, 512, 512),
+    (640, 1536, 512, 512),
+    (1000, 1900, 256, 512),
+    (1900, 1000, 512, 128),
+    (700, 700, 128, 384),
+    (300, 300, 384, 384),
+]
+
+
+def _padded(sq, sk, block_q, block_k):
+    return _round_up(sq, block_q), _round_up(sk, block_k)
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k", GRIDS)
+def test_block_predicate_matches_tril(sq, sk, block_q, block_k):
+    """No block with an allowed score is skipped, no wholly masked block
+    is computed: the predicate against numpy's own lower triangle."""
+    sq_p, sk_p = _padded(sq, sk, block_q, block_k)
+    n_q, n_k = sq_p // block_q, sk_p // block_k
+    allowed = np.tril(np.ones((sq_p, sk_p), bool))          # rows >= cols
+    by_block = allowed.reshape(n_q, block_q, n_k, block_k).any(axis=(1, 3))
+    live = np.array([[bool(_causal_block_live(qi, ki, block_q, block_k))
+                      for ki in range(n_k)] for qi in range(n_q)])
+    np.testing.assert_array_equal(live, by_block)
+    assert flash_block_steps(sq_p, sk_p, block_q, block_k, True) == (
+        int(by_block.sum()), n_q * n_k)
+    assert flash_block_steps(sq_p, sk_p, block_q, block_k, False) == (
+        n_q * n_k, n_q * n_k)
+    # a ring step's offsets shift the same rule
+    q_off, k_off = 3 * sq, 2 * sk
+    shifted = (np.arange(sq_p)[:, None] + q_off
+               >= np.arange(sk_p)[None, :] + k_off)
+    np.testing.assert_array_equal(
+        np.array([[bool(_causal_block_live(qi, ki, block_q, block_k,
+                                           (q_off, k_off)))
+                   for ki in range(n_k)] for qi in range(n_q)]),
+        shifted.reshape(n_q, block_q, n_k, block_k).any(axis=(1, 3)))
+
+
+def test_block_steps_of_the_serving_buckets():
+    assert flash_block_steps(16384, 16384, _BLOCK_Q, _BLOCK_K, True) == (
+        528, 1024)
+    assert flash_block_steps(8192, 8192, _BLOCK_Q, _BLOCK_K, True) == (
+        136, 256)
+    assert flash_block_steps(2048, 2048, _BLOCK_Q, _BLOCK_K, True) == (10, 16)
+    assert flash_block_steps(1024, 1024, _BLOCK_Q, _BLOCK_K, True) == (3, 4)
+    assert flash_block_steps(512, 512, _BLOCK_Q, _BLOCK_K, True) == (1, 1)
+
+
+@pytest.mark.parametrize("kv_major", [False, True])
+@pytest.mark.parametrize("sq,sk,block_q,block_k", GRIDS[3:])
+def test_skipped_step_names_a_block_in_vmem(sq, sk, block_q, block_k,
+                                            kv_major):
+    """The clamped index maps: a live step names its own blocks; a
+    skipped one names the block of the nearest live step of its row of
+    the grid (the one the pipeline holds), or any block in range where
+    the row has no live step at all."""
+    sq_p, sk_p = _padded(sq, sk, block_q, block_k)
+    n_q, n_k = sq_p // block_q, sk_p // block_k
+    coords = _qkv_layout(
+        jax.ShapeDtypeStruct((1, 1, sq_p, 64), jnp.float32),
+        jax.ShapeDtypeStruct((1, 1, sk_p, 64), jnp.float32),
+        heads=None, block_q=block_q, block_k=block_k, kv_major=kv_major,
+        vma=None, clamp_causal=True)[-1]
+    for qi in range(n_q):
+        for ki in range(n_k):
+            got = tuple(int(c) for c in (coords(ki, qi) if kv_major
+                                         else coords(qi, ki)))
+            assert 0 <= got[0] < n_q and 0 <= got[1] < n_k
+            if _causal_block_live(qi, ki, block_q, block_k):
+                assert got == (qi, ki)
+                continue
+            if kv_major:   # q runs innermost, live steps come last
+                row = [q for q in range(n_q)
+                       if _causal_block_live(q, ki, block_q, block_k)]
+                assert got == ((row[0] if row else n_q - 1), ki)
+            else:          # k runs innermost, live steps come first
+                row = [k for k in range(n_k)
+                       if _causal_block_live(qi, k, block_q, block_k)]
+                assert got == (qi, row[-1])
+
+
+def _dropout_reference(q, k, v, seed, dropout_p, mask=None):
+    """Causal attention with the keep mask the interpret-mode kernels
+    draw: one block of bits a (b, h, qi, ki), by `_keep_mask` itself."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    block_q, block_k = min(_BLOCK_Q, sq), min(_BLOCK_K, sk)
+    keep = np.zeros((b, h, sq, sk), bool)
+    for b_ in range(b):
+        for h_ in range(h):
+            for qi in range(sq // block_q):
+                for ki in range(sk // block_k):
+                    keep[b_, h_, qi * block_q:(qi + 1) * block_q,
+                         ki * block_k:(ki + 1) * block_k] = np.asarray(
+                        _keep_mask(None, seed, b_, h_, qi, ki,
+                                   (block_q, block_k), dropout_p, True))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    if mask is not None:
+        s = s + mask
+    s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool)), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(jnp.asarray(keep), p / (1.0 - dropout_p), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("sq,sk,h,d", [(1536, 1536, 1, 64),
+                                       (1024, 1536, 2, 128)])
+def test_causal_dropout_over_several_blocks(sq, sk, h, d):
+    """Skipped blocks draw no bits and the computed ones keep theirs:
+    forward and gradients against the same keep mask, block by block."""
+    rng = np.random.RandomState(41)
+    q, k, v = _rand_qkv(rng, 1, sq, sk, h, d)
+    seed = jnp.asarray([2024], jnp.int32)
+
+    def loss_pallas(q, k, v):
+        out = _flash_attention_data(q, k, v, seed=seed, dropout_p=0.25,
+                                    is_causal=True, interpret=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    def loss_ref(q, k, v):
+        out = _dropout_reference(q, k, v, seed, 0.25)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    gp, out = jax.grad(loss_pallas, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    gr, ref = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    for a, b, name in zip(gp, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("sq,sk,h,d,mask_shape", [
+    (1536, 1536, 1, 64, (1, 1, 1536, 1536)),
+    (640, 1536, 2, 128, (1, 2, 640, 1536)),    # per head, packed
+    (1536, 640, 1, 192, (1, 1, 1, 640)),       # broadcast over q
+])
+def test_causal_with_additive_mask_over_several_blocks(sq, sk, h, d,
+                                                       mask_shape):
+    """Causal + a trainable additive mask: the mask's blocks follow the
+    clamped K blocks, and d(mask) is zero, not unwritten memory, on the
+    blocks the causal mask rules out."""
+    rng = np.random.RandomState(42)
+    q, k, v = _rand_qkv(rng, 1, sq, sk, h, d)
+    mask = jnp.asarray(rng.randn(*mask_shape).astype("float32"))
+
+    def loss_pallas(q, k, v, m):
+        out = _flash_attention_data(q, k, v, m, has_mask=True,
+                                    mask_needs_grad=True, is_causal=True,
+                                    interpret=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    def loss_ref(q, k, v, m):
+        out = _ref_attention(q, k, v, mask=m, is_causal=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    gp, out = jax.grad(loss_pallas, argnums=(0, 1, 2, 3),
+                       has_aux=True)(q, k, v, mask)
+    gr, ref = jax.grad(loss_ref, argnums=(0, 1, 2, 3),
+                       has_aux=True)(q, k, v, mask)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    for a, b, name in zip(gp, gr, ("q", "k", "v", "mask")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("sq,sk,d", [(1536, 1536, 64), (1536, 640, 128),
+                                     (640, 1536, 256)])
+def test_causal_lse_finite_on_every_row(sq, sk, d):
+    """Every row of a causal call sees column 0, so no row's logsumexp
+    is the clamp's 0.0 or -inf, skipped blocks or not."""
+    rng = np.random.RandomState(43)
+    block_q, block_k = min(_BLOCK_Q, sq), min(_BLOCK_K, sk)
+    sq_p, sk_p = _padded(sq, sk, block_q, block_k)
+    q = jnp.asarray(rng.randn(1, 2, sq_p, d).astype("float32"))
+    k = jnp.asarray(rng.randn(1, 2, sk_p, d).astype("float32"))
+    v = jnp.asarray(rng.randn(1, 2, sk_p, d).astype("float32"))
+    _, lse = _fwd_call(
+        q, k, v, jnp.zeros((1, 1, 1, 1), jnp.float32),
+        jnp.zeros((1,), jnp.int32), scale=d ** -0.5, sk=sk, is_causal=True,
+        has_mask=False, mask_b_is_one=True, mask_h_is_one=True,
+        mask_q_is_one=True, block_q=block_q, block_k=block_k,
+        dropout_p=0.0, interpret=True, keep_neg_inf_lse=True)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k[:, :, :sk]) * d ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((sq_p, sk), bool)), s, -jnp.inf)
+    ref = jax.scipy.special.logsumexp(s, axis=-1)
+    got = np.asarray(lse[:, :, 0, :])
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("sq,sk,h,d,causal", CASES)
@@ -90,6 +300,9 @@ def test_forward_per_head_mask():
     (128, 128, 1, 64, True),
     (200, 200, 1, 32, True),
     (128, 128, 2, 128, True),    # d=128: PACKED layout backward
+    (1536, 1536, 1, 64, True),   # skipped blocks in dq and in dk/dv
+    (640, 1536, 2, 128, True),
+    (1536, 640, 1, 192, True),
 ])
 def test_backward_matches_reference(sq, sk, h, d, causal):
     rng = np.random.RandomState(3)

@@ -830,6 +830,84 @@ class TestWritePages:
             np.testing.assert_array_equal(np.asarray(got), want)
 
 
+# ------------------------------------------- the exact prefill says causal
+
+class TestExactPrefillIsCausal:
+    # (heads, kv heads, head width) of GPTConfig.tiny() and of
+    # HybridSsmConfig.tiny()'s attention layers; 1,040 tokens are three
+    # 512-blocks a side with a ragged last one
+    SHAPES = {"gpt": (4, 4, 32), "hybrid": (4, 2, 32)}
+
+    def _case(self, rng, family, s=1040, ps=8):
+        heads, kvh, hd = self.SHAPES[family]
+        pool = PagedKVCache(1, s // ps + 2, ps, kvh, hd)
+        pages = [pool.allocator.alloc() for _ in range(s // ps)]
+        view = pool.layer_views(pool.page_table_array([pages], s // ps))[0]
+        q, k, v = (Tensor(jnp.asarray(rng.standard_normal((1, s, n, hd)),
+                                      jnp.float32))
+                   for n in (heads, kvh, kvh))
+        return q, k, v, view, heads // kvh
+
+    @staticmethod
+    def _dense_reference(q, k, v, rep, bias=None):
+        qd, kd, vd = (np.asarray(x._data, np.float64) for x in (q, k, v))
+        kd, vd = np.repeat(kd, rep, axis=2), np.repeat(vd, rep, axis=2)
+        s = qd.shape[1]
+        scores = np.einsum("bqhd,bkhd->bhqk", qd, kd) / np.sqrt(qd.shape[-1])
+        if bias is not None:
+            scores = scores + np.asarray(bias, np.float64)[..., :s]
+        scores = np.where(np.tril(np.ones((s, s), bool)), scores, -np.inf)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        return np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), vd)
+
+    @pytest.mark.parametrize("family", ["gpt", "hybrid"])
+    def test_no_bias_takes_the_flag_and_matches_the_dense_mask(
+            self, rng, flash_interpreted, attention_dispatches,
+            family):
+        q, k, v, view, rep = self._case(rng, family)
+        ctx, _ = satt.paged_attend(q, k, v, view, 0, rep)
+        assert flash_interpreted == [(True, False)]     # causal, no mask
+        assert attention_dispatches() == {"prefill": 1}
+        np.testing.assert_allclose(
+            ctx.numpy(), self._dense_reference(q, k, v, rep), atol=1e-5)
+
+    @pytest.mark.parametrize("family", ["gpt", "hybrid"])
+    def test_a_bias_still_takes_the_mask(self, rng, flash_interpreted,
+                                         attention_dispatches, family):
+        q, k, v, view, rep = self._case(rng, family)
+        s, heads = q.shape[1], q.shape[2]
+        # the bias builder's key axis is its own max_len: cropped to s
+        bias = jnp.asarray(rng.standard_normal((1, heads, s, s + 24)),
+                           jnp.float32)
+        ctx, _ = satt.paged_attend(q, k, v, view, 0, rep, bias=bias)
+        assert flash_interpreted == [(False, True)]     # the dense mask
+        assert attention_dispatches() == {"prefill_masked": 1}
+        np.testing.assert_allclose(
+            ctx.numpy(), self._dense_reference(q, k, v, rep, bias),
+            atol=1e-5)
+
+    def test_the_engine_s_prefill_counts_a_layer_a_causal_call(
+            self, flash_interpreted, attention_dispatches):
+        """Tiny GPT through the engine with flash interpreted: every
+        attention layer of the one prefill executable takes the flag, and
+        the tokens are the sequential `generate`'s."""
+        model = _gpt()
+        model.__dict__.pop("_serving_jit_cache", None)   # trace afresh
+        prompt = np.random.RandomState(3).randint(
+            0, GPTConfig.tiny().vocab_size, (11,))
+        ref = _sequential_reference(model, [prompt], max_new_tokens=4)[0]
+        eng = ServingEngine(model, page_size=8, max_batch_size=2,
+                            max_seq_len=32, prefill_buckets=(16, 32))
+        rid = eng.add_request(prompt, max_new_tokens=4, temperature=0.0)
+        out = eng.run()[rid]
+        model.__dict__.pop("_serving_jit_cache", None)
+        layers = GPTConfig.tiny().num_hidden_layers
+        counts = attention_dispatches()
+        assert counts["prefill"] == layers and not counts["prefill_masked"]
+        assert flash_interpreted.count((True, False)) == layers
+        assert out == ref
+
+
 # -------------------------------------------------- continuous batching
 
 class TestContinuousBatching:

@@ -73,7 +73,8 @@ def init_caches(model, batch, max_len, dtype=jnp.float32):
     cfg = _config_of(model)
     if getattr(cfg, "latent_cache_dim", None) is not None:
         raise NotImplementedError(
-            f"{type(model).__name__} caches a latent row a token, which "
+            f"{type(model).__name__} caches a latent row a token (and, "
+            "where its attention is sparse, an index key), which "
             "the static (k, v) cache of models.generation cannot hold: "
             "serve it through paddle_tpu.serving.ServingEngine")
     if getattr(cfg, "state_cache_spec", None) is not None:

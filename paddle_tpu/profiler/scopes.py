@@ -55,9 +55,20 @@ SSM_CONV = "ssm_conv"
 SSM_STATE_UPDATE = "ssm_state_update"
 SSM_CHUNK_SCAN = "ssm_chunk_scan"
 SSM_GATE_OUT = "ssm_gate_out"
+# serving/attention.py and models/mla_moe.py, the parts of sparse latent
+# attention (a lightning indexer beside MLA): the indexer's projections
+# (under `attn_qkv`) and its scores of a query against the cached or the
+# step's index keys; the choice of the `index_topk` highest; and the
+# attention over the chosen rows alone (a decode step's gather and
+# absorbed attention, a prefill's masked flash). The last three under
+# `paged_attention` in a decode step, `prefill_attention` in a prefill
+DSA_INDEX = "dsa_index"
+DSA_SELECT = "dsa_select"
+DSA_ATTEND = "dsa_attend"
 SERVE_SUBSCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
                    MOE_SHARED, MLA_ABSORB, SSM_IN_PROJ, SSM_CONV,
-                   SSM_STATE_UPDATE, SSM_CHUNK_SCAN, SSM_GATE_OUT)
+                   SSM_STATE_UPDATE, SSM_CHUNK_SCAN, SSM_GATE_OUT,
+                   DSA_INDEX, DSA_SELECT, DSA_ATTEND)
 TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
                 OPTIMIZER_UPDATE)
 
@@ -69,6 +80,11 @@ PAGED_RAGGED_KERNEL = "paged_ragged"
 MLA_DECODE_KERNEL = "mla_decode"
 # the in-place state update of serving/ssm.py, `%ssm_decode.N`
 SSM_DECODE_KERNEL = "ssm_decode"
+# sparse latent attention's two: a row's index query against its own
+# pages of index keys, `%dsa_index.N`, and the absorbed attention over
+# the chosen rows, gathered by token index, `%mla_sparse_decode.N`
+DSA_INDEX_KERNEL = "dsa_index"
+MLA_SPARSE_DECODE_KERNEL = "mla_sparse_decode"
 
 # jitted executables: the trace's `XLA Modules` line reads
 # `jit_<name>`; a family is its first word

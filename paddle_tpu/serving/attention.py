@@ -55,7 +55,8 @@ __all__ = ["paged_attend", "paged_decode_attention",
            "paged_decode_available", "ragged_paged_attention",
            "ragged_attention_available", "advance_positions", "KERNEL_MODE",
            "latent_write", "latent_prefill_attention",
-           "latent_decode_attention"]
+           "latent_decode_attention", "dsa_index_scores", "dsa_select",
+           "sparse_latent_decode_attention", "sparse_prefill_mask"]
 
 # "auto": Pallas kernel on TPU, jnp reference elsewhere; "off": always the
 # reference; "interpret": run the Pallas kernel in interpret mode (hermetic
@@ -811,20 +812,28 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, pos,
 # scores taken against the cached rows themselves and the latent summed
 # under the softmax (`latent_decode_attention`, the `mla_decode` kernel).
 
-def latent_write(rows, cache: LatentLayerCache, start_pos):
+def latent_write(rows, cache: LatentLayerCache, start_pos, index_keys=None):
     """Write (b, s, width) rows into the latent pool at each token's own
-    position, zero-padded to the pool's whole tiles. Returns (new cache
-    view, (b, s) positions)."""
+    position, zero-padded to the pool's whole tiles, and, over a pool
+    that holds them, the tokens' (b, s, index width) index keys into the
+    same pages and slots. Returns (new cache view, (b, s) positions)."""
     b, s, width = rows.shape
+    if (index_keys is None) != (cache.index_pool is None):
+        raise ValueError("a latent pool with index keys is written with "
+                         "them, one without is not")
     with jax.named_scope(scopes.KV_WRITE):
         pos = _positions(start_pos, b, s)
         entries, slots = _write_targets(cache.page_table, pos,
                                         cache.page_size)
+        at = (entries.reshape(-1), slots.reshape(-1))
         rows = jnp.pad(rows.reshape(b * s, width).astype(cache.pool.dtype),
                        ((0, 0), (0, cache.pool.shape[-1] - width)))
-        pool = cache.pool.at[entries.reshape(-1), slots.reshape(-1)].set(
-            rows)
-    return LatentLayerCache(pool, cache.page_table), pos
+        pool = cache.pool.at[at].set(rows)
+        index_pool = cache.index_pool
+        if index_keys is not None:
+            index_pool = index_pool.at[at].set(index_keys.reshape(
+                b * s, index_keys.shape[-1]).astype(index_pool.dtype))
+    return LatentLayerCache(pool, cache.page_table, index_pool), pos
 
 
 def latent_prefill_attention(q, k, v, scale: float):
@@ -846,6 +855,18 @@ def latent_prefill_attention(q, k, v, scale: float):
         return ctx._data[..., :dv]
 
 
+def _latent_kernel(name: str, page_size: int):
+    """Which path a latent pool's kernel `name` takes, counted under it
+    in `serving_attention_dispatch_total`: None for the jnp path
+    (`<name>_reference`), else the kernel's `interpret` flag."""
+    interpret = KERNEL_MODE == "interpret"
+    if KERNEL_MODE == "off" or page_size % 8 or not (interpret or _on_tpu()):
+        _count_dispatch(name + "_reference")
+        return None
+    _count_dispatch(name + ("_pallas_interpret" if interpret else "_pallas"))
+    return interpret
+
+
 def latent_decode_attention(q, cache: LatentLayerCache, pos, scale: float,
                             latent: int):
     """One query a row over the row's cached latent rows, absorbed form.
@@ -856,18 +877,12 @@ def latent_decode_attention(q, cache: LatentLayerCache, pos, scale: float,
     the softmax-weighted sum of the rows' first `latent` columns:
     (b, heads, latent), for the model to take through W_uv."""
     with jax.named_scope(scopes.PAGED_ATTENTION):
-        ps = cache.page_size
-        use_kernel = (KERNEL_MODE != "off" and ps % 8 == 0
-                      and (KERNEL_MODE == "interpret" or _on_tpu()))
-        if use_kernel:
-            _count_dispatch("mla_decode_pallas_interpret"
-                            if KERNEL_MODE == "interpret"
-                            else "mla_decode_pallas")
-            return _mla_decode_pallas(
-                q, cache.pool, cache.page_table, pos, scale=float(scale),
-                latent=latent, interpret=KERNEL_MODE == "interpret")
-        _count_dispatch("mla_decode_reference")
-        return _mla_decode_reference(q, cache, pos, scale, latent)
+        interpret = _latent_kernel("mla_decode", cache.page_size)
+        if interpret is None:
+            return _mla_decode_reference(q, cache, pos, scale, latent)
+        return _mla_decode_pallas(
+            q, cache.pool, cache.page_table, pos, scale=float(scale),
+            latent=latent, interpret=interpret)
 
 
 def _mla_decode_reference(q, cache, pos, scale, latent):
@@ -1022,6 +1037,439 @@ def _mla_decode_pallas(q, pool, page_table, pos, *, scale, latent,
         interpret=interpret,
         name=scopes.MLA_DECODE_KERNEL,
     )(table, pos.astype(jnp.int32), qg, pool)
+    return out[:, :heads]
+
+
+# ------------------------------------------- indexed (sparse) latent pools
+#
+# A latent pool whose layers also hold one index key a token
+# (`LatentLayerCache.index_pool`, DeepSeek sparse attention): a query
+# attends the `topk` cached positions its indexer scores highest, and no
+# others. A decode step scores a row's whole context of index keys in
+# place (`dsa_index`, 256 B a cached token), chooses, and gathers the
+# chosen latent rows alone by token index (`mla_sparse_decode`, 1,280 B a
+# chosen token): the row's whole context of latent rows is never read,
+# copied or gathered. A prefill from position 0 has every key in the
+# step: it scores, chooses and masks a block of queries at a time.
+
+def dsa_index_scores(q, w, cache: LatentLayerCache, pos):
+    """Index scores of one query a row against the row's cached index
+    keys: I[b, s] = sum_j w[b, j] * relu(q[b, j] . key[b, s]) for s <=
+    pos[b], -inf past it, in float32.
+
+    q: (b, heads, width) rotated index queries; w: (b, heads) float32
+    head weights, already scaled; pos: (b,) int32. Returns (b, L) float32
+    with L at least the table's capacity in tokens."""
+    with jax.named_scope(scopes.DSA_INDEX):
+        interpret = _latent_kernel("dsa_index", cache.page_size)
+        if interpret is None:
+            return _dsa_index_reference(q, w, cache, pos)
+        return _dsa_index_pallas(q, w, cache.index_pool, cache.page_table,
+                                 pos, interpret=interpret)
+
+
+def _dsa_index_reference(q, w, cache, pos):
+    """Gather each row's pages of index keys into a contiguous view: the
+    kernel's test reference and the `KERNEL_MODE="off"` path."""
+    pool, page_table = cache.index_pool, cache.page_table
+    b, length = page_table.shape[0], page_table.shape[1] * cache.page_size
+    keys = pool[page_table].reshape(b, length, pool.shape[-1])
+    s = jnp.einsum("bhw,blw->bhl", q, keys,
+                   preferred_element_type=jnp.float32)
+    s = jnp.sum(jnp.maximum(s, 0.0) * w.astype(jnp.float32)[..., None], 1)
+    allowed = jnp.arange(length, dtype=jnp.int32)[None, :] <= pos[:, None]
+    return jnp.where(allowed, s, -jnp.inf)
+
+
+# positions a block of the choice's compaction holds: a 128-lane row
+_SELECT_BLOCK = 128
+
+
+def dsa_select(scores, pos, topk: int):
+    """The positions a decode row attends: the `topk` highest scores, the
+    lower position first among equals. Returns (b, topk) int32 positions
+    in ascending order, of which the first n[b] = min(pos[b] + 1, topk)
+    are the choice (a parked row chooses none), and n, (b,) int32.
+
+    Neither a sort nor a scatter, both of which the chip does slowly (a
+    `jax.lax.top_k` of 2,048 from 40,960 is a full variadic sort, 3.8 ms
+    for 32 rows inside the decode block; PERF.md section 6, PR 35): the
+    chosen set is a threshold (`_highest`), and its members' positions
+    are read off running counts. Within a block of 128 positions the
+    running count is a product with a triangle of ones; the block that
+    holds the m-th member is the number of blocks that end before it;
+    that block's counts come to the member by a one-hot product (exact:
+    every value is an integer under 256 in bf16, sums in float32), and
+    its place in the block is how many counts lie under its rank."""
+    with jax.named_scope(scopes.DSA_SELECT):
+        b, length = scores.shape
+        k = min(topk, length)
+        live = pos < length
+        n = jnp.where(live, jnp.minimum(pos + 1, k), 0).astype(jnp.int32)
+        blk = _SELECT_BLOCK
+        padded = -(-length // blk) * blk
+        scores = jnp.pad(scores, ((0, 0), (0, padded - length)),
+                         constant_values=-jnp.inf)
+        cols = jnp.arange(padded, dtype=jnp.int32)[None]
+        chosen = _highest(scores, k) & (cols <= pos[:, None])
+        nb = padded // blk
+        triangle = (jnp.arange(blk)[:, None] <= jnp.arange(blk)[None, :]
+                    ).astype(jnp.bfloat16)
+        # (b, nb, blk): members among a block's positions 0 .. j
+        within = jnp.einsum(
+            "bnp,pq->bnq", chosen.reshape(b, nb, blk).astype(jnp.bfloat16),
+            triangle, preferred_element_type=jnp.float32)
+        counts = within[..., -1]
+        ends = jnp.cumsum(counts, axis=1)                       # (b, nb)
+        rank = jnp.arange(k, dtype=jnp.float32)[None, :, None]  # m
+        before = ends[:, None, :] <= rank                       # (b, k, nb)
+        block = jnp.sum(before, -1).astype(jnp.int32)   # nb: past the set
+        start = jnp.sum(jnp.where(before, counts[:, None, :], 0.0), -1)
+        onehot = (block[..., None] == jnp.arange(nb, dtype=jnp.int32)
+                  ).astype(jnp.bfloat16)
+        mine = jnp.einsum("bkn,bnq->bkq", onehot,
+                          within.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+        place = jnp.sum(mine < (rank[..., 0] - start + 1.0)[..., None],
+                        -1).astype(jnp.int32)
+        where = jnp.minimum(block * blk + place, length - 1)
+        return where.astype(jnp.int32), n
+
+
+def _page_lookup(page_table, index):
+    """page_table[b, index[b, k]] without a gather (of scalars the chip
+    brings one at a time: 2.1 ms for 65,536 inside the decode block):
+    the table in rows of 64, a row chosen by a one-hot product, an entry
+    of it by a comparison. A page id rides as two bytes, each exact in
+    bf16, sums in float32: exact for ids under 65,536, which is all an
+    indexed pool may hold (`kv_cache.INDEXED_POOL_MAX_PAGES`)."""
+    b, max_pages = page_table.shape
+    row = 64
+    rows = -(-max_pages // row)
+    table = jnp.pad(page_table.astype(jnp.int32),
+                    ((0, 0), (0, rows * row - max_pages))).reshape(b, rows,
+                                                                   row)
+    index = jnp.clip(index, 0, max_pages - 1)
+    onehot = (index[..., None] // row == jnp.arange(rows, dtype=jnp.int32)
+              ).astype(jnp.bfloat16)
+    both = jnp.concatenate([table >> 8, table & 255], -1).astype(
+        jnp.bfloat16)                                   # (b, rows, 2 * row)
+    mine = jnp.einsum("bkr,brc->bkc", onehot, both,
+                      preferred_element_type=jnp.float32)
+    entry = (index[..., None] % row == jnp.arange(row, dtype=jnp.int32))
+    high = jnp.sum(jnp.where(entry, mine[..., :row], 0.0), -1)
+    low = jnp.sum(jnp.where(entry, mine[..., row:], 0.0), -1)
+    return (high * 256.0 + low).astype(jnp.int32)
+
+
+def sparse_latent_decode_attention(q, cache: LatentLayerCache, chosen, n,
+                                   scale: float, latent: int):
+    """`latent_decode_attention` over the chosen positions alone.
+
+    q: (b, heads, width) absorbed queries; chosen: (b, k) int32 token
+    positions of which the first n[b] count. Returns (b, heads, latent)."""
+    with jax.named_scope(scopes.DSA_ATTEND):
+        interpret = _latent_kernel("mla_sparse_decode", cache.page_size)
+        if interpret is None:
+            return _mla_sparse_decode_reference(q, cache, chosen, n, scale,
+                                                latent)
+        return _mla_sparse_decode_pallas(
+            q, cache.pool, cache.page_table, chosen, n, scale=float(scale),
+            latent=latent, interpret=interpret)
+
+
+def _mla_sparse_decode_reference(q, cache, chosen, n, scale, latent):
+    """Gather the chosen rows through the page table and take the softmax
+    in float32: the kernel's test reference."""
+    pool, page_table, ps = cache.pool, cache.page_table, cache.page_size
+    pages = jnp.take_along_axis(
+        page_table, jnp.clip(chosen // ps, 0, page_table.shape[1] - 1), 1)
+    rows = pool[pages, chosen % ps][..., :q.shape[-1]]      # (b, k, width)
+    allowed = jnp.arange(chosen.shape[1], dtype=jnp.int32)[None] < n[:, None]
+    rows = jnp.where(allowed[..., None], rows, jnp.zeros_like(rows))
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(allowed[:, None, :], s, -1e30), -1)
+    return jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
+                      rows[..., :latent],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+# keys a prefill's index scores are taken against at a time: the scores
+# of a block of queries are (queries, heads, keys) float32 before the
+# heads are summed, 128 MB at 512 x 64 x 1,024
+_DSA_PREFILL_KEY_BLOCK = 1024
+
+
+def sparse_prefill_mask(q, w, keys, first, topk: int):
+    """The additive mask of a block of a prefill's queries: 0 where query
+    t attends key s, -inf elsewhere. Query t attends the min(topk, t + 1)
+    keys s <= t of largest I[t, s] = sum_j w[t, j] relu(q[t, j] . key[s]),
+    the lower position first among equals; scores, ReLU, sum and choice
+    in float32.
+
+    q: (bq, heads, width) rotated index queries at positions first ..
+    first + bq - 1; w: (bq, heads) float32; keys: (L, width), every key
+    of the step. Returns (bq, L) float32."""
+    bq, length = q.shape[0], keys.shape[0]
+    kb = min(_DSA_PREFILL_KEY_BLOCK, length)
+    # whole blocks of keys: those added lie past every query
+    padded = -(-length // kb) * kb
+    keys = jnp.pad(keys, ((0, padded - length), (0, 0)))
+    with jax.named_scope(scopes.DSA_INDEX):
+        def scores(block):
+            s = jnp.einsum("qhw,kw->qhk", q, block,
+                           preferred_element_type=jnp.float32)
+            return jnp.sum(jnp.maximum(s, 0.0)
+                           * w.astype(jnp.float32)[..., None], 1)
+
+        index = jax.lax.map(scores, keys.reshape(padded // kb, kb, -1))
+        index = jnp.moveaxis(index, 0, 1).reshape(bq, padded)[:, :length]
+        at = first + jnp.arange(bq, dtype=jnp.int32)[:, None]
+        cols = jnp.arange(length, dtype=jnp.int32)[None, :]
+        index = jnp.where(cols <= at, index, -jnp.inf)
+    with jax.named_scope(scopes.DSA_SELECT):
+        chosen = _highest(index, min(topk, length)) & (cols <= at)
+        return jnp.where(chosen, 0.0, -jnp.inf).astype(jnp.float32)
+
+
+def _highest(scores, k: int):
+    """(rows, n) bool: each row's k highest scores, the lower position
+    first among equals. Without a sort, which the chip does slowly: the
+    k-th highest value of a row is found a bit at a time, from the top
+    bit down, each a comparison and a count over the row (32 passes of
+    elementwise work; a sort of n is some log2(n)^2 / 2 passes of
+    exchanges); everything above it is in, and of those that equal it the
+    first few in position order, which takes a running count only where
+    some row has equals at the edge."""
+    # float32 to integers of the same order; -0.0 counts as 0.0
+    scores = jnp.where(scores == 0, 0.0, scores.astype(jnp.float32))
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def refine(i, least):
+        trial = least | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+            jnp.uint32)))
+        enough = jnp.sum(keys >= trial, -1, keepdims=True) >= k
+        return jnp.where(enough, trial, least)
+
+    least = jax.lax.fori_loop(
+        0, 32, refine, jnp.zeros((scores.shape[0], 1), jnp.uint32))
+    above = keys > least
+    spare = k - jnp.sum(above, -1, keepdims=True)    # >= 1 equals are in
+
+    def by_position(_):
+        equal = keys == least
+        return above | (equal & (jnp.cumsum(equal, -1) <= spare))
+
+    exact = jnp.all(jnp.sum(keys >= least, -1, keepdims=True) == k)
+    return jax.lax.cond(exact, lambda _: keys >= least, by_position, None)
+
+
+# tokens of index keys one compute block of `dsa_index` holds: a key is
+# 256 B and a page of 16 one 4 KB copy, so a block is 128 pages, 512 KB a
+# slot
+_DSA_INDEX_BLOCK_TOKENS = 2048
+
+
+def _dsa_index_kernel(pt_ref, pos_ref, q_ref, w_ref, keys_hbm, o_ref, buf,
+                      sems, *, ps, ppb, n_pages):
+    """Grid (batch,): built as `_mla_decode_kernel` is. The key pool stays
+    in HBM; a row's own pages are walked in `cdiv(pos + 1, ppb * ps)`
+    blocks, block i + 1 in flight while block i is scored. All heads
+    share the one key a token: scores (heads, block) on the MXU, then
+    ReLU, the heads' weights and their sum in float32. Blocks past the
+    row's position are never read and stay -inf."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b_ = pl.program_id(0)
+    pos = pos_ref[b_]
+    bk = ppb * ps
+    width = q_ref.shape[2]
+    length = jnp.where(pos < n_pages * ps, pos + 1, 0)
+    n_blocks = (length + bk - 1) // bk
+
+    def block_copies(i, slot):
+        return [pltpu.make_async_copy(
+            keys_hbm.at[0 if i is None else pt_ref[b_, i * ppb + j]],
+            buf.at[slot, j], sems.at[slot]) for j in range(ppb)]
+
+    def start(i, slot):
+        for c in block_copies(i, slot):
+            c.start()
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    def block(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch():
+            start(i + 1, 1 - slot)
+
+        for c in block_copies(None, slot):
+            c.wait()
+        keys = buf[slot].reshape(bk, width)
+        s = jax.lax.dot_general(
+            q_ref[0], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
+        s = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+        cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        # past the row's position: stale slots and null pages, whatever
+        # they hold
+        o_ref[0, pl.ds(i, 1), :] = jnp.where(cols <= pos, s, -jnp.inf)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dsa_index_pallas(q, w, keys, page_table, pos, *, interpret=False):
+    """q: (b, heads, width); w: (b, heads) f32; keys: (P, ps, width);
+    page_table: (b, maxP) i32; pos: (b,) i32. Returns (b, L) f32, L the
+    table's capacity in whole blocks."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, width = q.shape
+    _, ps, _ = keys.shape
+    max_pages = page_table.shape[1]
+    g_p = _round_up(heads, 8)
+    ppb = min(max(1, _DSA_INDEX_BLOCK_TOKENS // ps), max_pages)
+    n_blk = -(-max_pages // ppb)
+    qg = jnp.pad(q, ((0, 0), (0, g_p - heads), (0, 0)))
+    wg = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, g_p - heads)))[..., None]
+    table = jnp.pad(page_table.astype(jnp.int32),
+                    ((0, 0), (0, n_blk * ppb - max_pages)),
+                    constant_values=NULL_PAGE)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, g_p, width), lambda b_, pt, ps_: (b_, 0, 0)),
+                  pl.BlockSpec((1, g_p, 1), lambda b_, pt, ps_: (b_, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_blk, ppb * ps),
+                               lambda b_, pt, ps_: (b_, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, ppb, ps, width), keys.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    out = pl.pallas_call(
+        functools.partial(_dsa_index_kernel, ps=ps, ppb=ppb,
+                          n_pages=max_pages),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_blk, ppb * ps), jnp.float32),
+        interpret=interpret,
+        name=scopes.DSA_INDEX_KERNEL,
+    )(table, pos.astype(jnp.int32), qg, wg, keys)
+    return out.reshape(b, n_blk * ppb * ps)
+
+
+# chosen rows one grid step of `mla_sparse_decode` attends: 640 KB a
+# block of 1,280 B rows
+_DSA_ATTEND_TOKENS = 512
+
+
+def _gather_chosen(pool, page_table, chosen):
+    """The chosen tokens' cached rows, (b, k, width), through the page
+    table. The pool's tiles are 8 tokens deep and a kernel's copy cannot
+    start inside one (Mosaic: "slice shape must be aligned to tiling"),
+    so the rows are brought by XLA's gather, which can."""
+    ps = pool.shape[1]
+    return pool[_page_lookup(page_table, chosen // ps), chosen % ps]
+
+
+def _mla_sparse_decode_kernel(n_ref, q_ref, rows_ref, o_ref, m_ref, l_ref,
+                              acc_ref, *, bk, scale, latent):
+    """Grid (batch, blocks of chosen rows), the blocks innermost. As
+    `_mla_decode_kernel` over the gathered rows: scores over the row's
+    whole width for all heads at once, online softmax in float32, the sum
+    of the rows' first `latent` columns. A block past the row's count is
+    skipped; in the last one the rows past it are kept out of scores and
+    sums whatever they hold."""
+    from jax.experimental import pallas as pl
+
+    b_, j = pl.program_id(0), pl.program_id(1)
+    n = n_ref[b_]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, m_ref.dtype)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * bk < n)
+    def _block():
+        at = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        rows = rows_ref[0]
+        rows = jnp.where(at < n, rows, jnp.zeros_like(rows))
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT) * scale
+        cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(cols < n, s, -jnp.inf)
+        # the block holds a chosen row, so its maximum is finite
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_cur)
+        alpha = jnp.exp(m_prev - m_cur)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :latent],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
+        m_ref[...] = m_cur
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "latent", "interpret"))
+def _mla_sparse_decode_pallas(q, pool, page_table, chosen, n, *, scale,
+                              latent, interpret=False):
+    """q: (b, heads, row width); pool: (P, ps, width); page_table: (b,
+    maxP) i32; chosen: (b, k) i32 positions; n: (b,) i32. Returns (b,
+    heads, latent)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, _ = q.shape
+    width = pool.shape[2]
+    g_p = _round_up(heads, 8)
+    bk = min(_DSA_ATTEND_TOKENS, _round_up(chosen.shape[1], 16))
+    k = _round_up(chosen.shape[1], bk)
+    chosen = jnp.pad(chosen, ((0, 0), (0, k - chosen.shape[1])))
+    rows = _gather_chosen(pool, page_table, chosen)
+    # the pool's columns past the row are zero, and so are q's
+    qg = jnp.pad(q, ((0, 0), (0, g_p - heads), (0, width - q.shape[2])))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, k // bk),
+        in_specs=[pl.BlockSpec((1, g_p, width), lambda b_, j, n_: (b_, 0, 0)),
+                  pl.BlockSpec((1, bk, width), lambda b_, j, n_: (b_, j, 0))],
+        out_specs=pl.BlockSpec((1, g_p, latent),
+                               lambda b_, j, n_: (b_, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((g_p, 1), jnp.float32),
+                        pltpu.VMEM((g_p, 1), jnp.float32),
+                        pltpu.VMEM((g_p, latent), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_sparse_decode_kernel, bk=bk, scale=scale,
+                          latent=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, g_p, latent), q.dtype),
+        interpret=interpret,
+        name=scopes.MLA_SPARSE_DECODE_KERNEL,
+    )(n.astype(jnp.int32), qg, rows)
     return out[:, :heads]
 
 

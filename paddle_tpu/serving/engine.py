@@ -302,6 +302,9 @@ class ServingObs:
         # state-slot handles, bound by bind_state_slots() only for a
         # model with recurrent layers
         self.slots_in_use = None
+        # sparse-attention handles, bound by bind_dsa() only for a model
+        # whose queries attend the keys an indexer chooses
+        self.dsa_keys_selected = None
 
     def dispatched(self, greedy: bool) -> None:
         """One device program launched; `greedy` is the host's reading of
@@ -331,6 +334,38 @@ class ServingObs:
         self.moe_max_expert_tokens = c(
             "serving_moe_max_expert_tokens_total",
             "tokens of the fullest expert, summed over layer dispatches")
+
+    def bind_dsa(self, layers: int, topk: int) -> None:
+        """Sparse-attention observability of a model with an indexer in
+        each of its `layers` attention layers: the keys a decode row had
+        in its context and the keys it attended, min(context, `topk`),
+        summed over rows, layers and the steps of a block, from the
+        positions the host dispatches with (no sync)."""
+        c = self.registry.counter
+        self._dsa = (int(layers), int(topk))
+        self.dsa_keys_in_context = c(
+            "serving_dsa_keys_in_context_total",
+            "cached keys in a decode row's context, summed over rows, "
+            "layers and steps")
+        self.dsa_keys_selected = c(
+            "serving_dsa_keys_selected_total",
+            "keys a decode row attended: min(context, index_topk), "
+            "summed over rows, layers and steps")
+
+    def dsa_block(self, contexts, steps) -> int:
+        """Count one dispatched decode block: `contexts[i]` keys in row
+        i's context at its first step, one more at each of its
+        `steps[i]` steps. Returns the keys attended."""
+        layers, topk = self._dsa
+        in_context = selected = 0
+        for c0, n in zip(contexts, steps):
+            in_context += n * c0 + n * (n - 1) // 2
+            under = min(max(topk - c0, 0), n)   # steps still under topk
+            selected += (under * c0 + under * (under - 1) // 2
+                         + (n - under) * topk)
+        self.dsa_keys_in_context.inc(layers * in_context)
+        self.dsa_keys_selected.inc(layers * selected)
+        return layers * selected
 
     def bind_state_slots(self) -> None:
         """State-slot observability of a model with recurrent layers:
@@ -734,6 +769,9 @@ class ServingEngine:
             self._obs.bind_moe()
         if self._obs is not None and self._has_state:
             self._obs.bind_state_slots()
+        if self._obs is not None \
+                and getattr(cfg, "index_cache_dim", None) is not None:
+            self._obs.bind_dsa(cfg.num_hidden_layers, cfg.index_topk)
         # SLO accounting (ISSUE 13): per-request-class TTFT/TPOT targets
         # feeding windowed attainment gauges + a goodput counter. Rides
         # on the metrics registry, so it requires one; with no classes
@@ -1985,6 +2023,9 @@ class ServingEngine:
                                   rows=len(reqs), horizon=h)
         attrs = ({"state_slots": self.cache.slot_allocator.num_used}
                  if self._has_state else {})
+        if self._obs is not None and self._obs.dsa_keys_selected is not None:
+            attrs["keys_selected"] = self._obs.dsa_block(
+                [r.num_tokens + r.inflight for r in reqs], incr)
         with RecordEvent("serving.decode_block", rows=live,
                          rows_dispatched=b, horizon=h, **attrs):
             out, err = self._guarded_call("dispatch", dispatch)

@@ -309,8 +309,13 @@ class LatentLayerCache:
     """One layer's view of a latent pool (the pool kind of a model with
     latent attention, MLA): one row a token, the normed compressed
     latent followed by the one rotated rope key all heads share, and no
-    V. `serving.attention.latent_write` writes it and
-    `latent_decode_attention` reads it; the allocator, the page tables and the null page are the K/V kind's.
+    V; for a model whose attention is sparse (a lightning indexer beside
+    MLA) also one index key a token, in a second array under the same
+    page table: a page is handed out, written and freed for both at
+    once. `serving.attention.latent_write` writes them,
+    `latent_decode_attention` reads the rows, `dsa_index_scores` the
+    keys and `sparse_latent_decode_attention` the rows it is told; the
+    allocator, the page tables and the null page are the K/V kind's.
 
     pool:       (num_pages, page_size, width) — a page is one contiguous
                 (page_size, width) slab, which the decode kernel brings
@@ -319,17 +324,21 @@ class LatentLayerCache:
                 anyway (a kernel cannot copy part of a tile out of HBM);
                 the columns past the row stay zero
     page_table: (B, max_pages) int32, as `PagedLayerCache`'s
+    index_pool: (num_pages, page_size, index width) or None
     """
 
     pool: jnp.ndarray
     page_table: jnp.ndarray
+    index_pool: Optional[jnp.ndarray] = None
 
     @property
     def page_size(self) -> int:
         return self.pool.shape[1]
 
     def tree_flatten(self):
-        return (self.pool, self.page_table), None
+        if self.index_pool is None:
+            return (self.pool, self.page_table), False
+        return (self.pool, self.page_table, self.index_pool), True
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -454,7 +463,8 @@ class StateLayerCache:
 @jax.tree_util.register_pytree_node_class
 class LayerPool(tuple):
     """One layer's pool arrays, tagged with what they hold: "kv" (k, v),
-    "kv_quant" (k, v, k_scale, v_scale), "latent" (pool,) or "state"
+    "kv_quant" (k, v, k_scale, v_scale), "latent" (rows,) or (rows,
+    index keys), or "state"
     (ssm, conv), and for a "kv" pool with how many kv heads lie side by
     side in a row (`head_pack`). Both are static under `jax.jit` (the
     tree's auxiliary data); the leaves and their order are the bare
@@ -497,7 +507,7 @@ def views_from_pools(pools, page_table, row_ids=None, slots=None):
             if row_ids is not None:
                 raise NotImplementedError(
                     "a latent pool has no flat ragged step (row_ids)")
-            views.append(LatentLayerCache(p[0], page_table))
+            views.append(LatentLayerCache(p[0], page_table, *p[1:]))
         elif p.kind == "state":
             if row_ids is not None or slots is None:
                 raise NotImplementedError(
@@ -516,7 +526,8 @@ def views_from_pools(pools, page_table, row_ids=None, slots=None):
 def pools_from_views(views):
     """Inverse of `views_from_pools`: tagged pools from the new caches a
     step returned."""
-    return [LayerPool("latent", (v.pool,))
+    return [LayerPool("latent", (v.pool,) if v.index_pool is None
+                      else (v.pool, v.index_pool))
             if isinstance(v, LatentLayerCache)
             else LayerPool("state", (v.ssm_pool, v.conv_pool))
             if isinstance(v, StateLayerCache)
@@ -527,6 +538,11 @@ def pools_from_views(views):
             for v in views]
 
 
+# pages an indexed latent pool may hold: `attention._page_lookup` carries
+# a page id as two bytes, each exact in bf16
+INDEXED_POOL_MAX_PAGES = 1 << 16
+
+
 class PagedKVCache:
     """The per-layer pools plus the allocator. Pools are plain jax arrays
     so the engine can thread (and donate) them through jitted steps."""
@@ -535,6 +551,7 @@ class PagedKVCache:
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
                  kv_dtype: Optional[str] = None,
                  latent_dim: Optional[int] = None,
+                 index_dim: Optional[int] = None,
                  head_pack: int = 1,
                  state_spec: Optional[StateSpec] = None,
                  state_slots: int = 0):
@@ -542,7 +559,9 @@ class PagedKVCache:
         `num_kv_heads` heads of `head_dim`; a row's width for the latent
         kind, one (num_pages, page_size, latent_dim rounded up to whole
         128-lane tiles) pool a layer (`num_kv_heads` and `head_dim` are
-        then not read).
+        then not read), and with `index_dim` (a model whose attention
+        chooses its keys by an indexer) a second array a layer beside
+        it, (num_pages, page_size, index_dim), for the index keys.
 
         `head_pack` > 1 (plain K/V pools only) holds that many kv heads
         side by side in one row: pools of (num_kv_heads / head_pack,
@@ -562,6 +581,16 @@ class PagedKVCache:
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.latent_dim = latent_dim
+        self.index_dim = index_dim if latent_dim is not None else None
+        if index_dim is not None and latent_dim is None:
+            raise ValueError("index keys are cached beside latent rows: "
+                             "index_dim needs latent_dim")
+        if index_dim is not None and num_pages > INDEXED_POOL_MAX_PAGES:
+            raise ValueError(
+                f"num_pages={num_pages}: the sparse decode step looks a "
+                "chosen token's page up as two bytes "
+                "(`attention._page_lookup`), so an indexed latent pool "
+                f"holds at most {INDEXED_POOL_MAX_PAGES} pages")
         self.state_spec = state_spec
         self.state_slots = int(state_slots) if state_spec is not None else 0
         if kv_dtype is not None and kv_dtype in _PLAIN_KV_DTYPES:
@@ -598,8 +627,11 @@ class PagedKVCache:
                     "bf16 rows; quantized latent pages are not written yet")
 
             def paged():
-                return LayerPool("latent", (jnp.zeros(
-                    (num_pages, page_size, self.slot_elems), dtype),))
+                shape = (num_pages, page_size)
+                arrays = (jnp.zeros(shape + (self._latent_width,), dtype),)
+                if index_dim is not None:
+                    arrays += (jnp.zeros(shape + (index_dim,), dtype),)
+                return LayerPool("latent", arrays)
         elif kv_dtype is not None:
             # quantized pools ONLY: the fp32/bf16 constructor path above
             # must never import serving.quant
@@ -659,11 +691,17 @@ class PagedKVCache:
         return "kv" if self.latent_dim is None else "latent"
 
     @property
+    def _latent_width(self) -> int:
+        """The latent row in whole 128-lane tiles."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
     def slot_elems(self) -> int:
         """Stored elements of one token in one paged layer: the latent
-        row in whole 128-lane tiles, or K and V of every kv head."""
+        row in whole 128-lane tiles and, where the layer holds one, the
+        index key; or K and V of every kv head."""
         if self.latent_dim is not None:
-            return -(-self.latent_dim // 128) * 128
+            return self._latent_width + (self.index_dim or 0)
         return 2 * self.num_kv_heads * self.head_dim
 
     @property
@@ -714,6 +752,7 @@ class PagedKVCache:
         # a model with latent attention says how wide its cached row is,
         # one with recurrent layers which they are and how large a state
         latent_dim = getattr(cfg, "latent_cache_dim", None)
+        index_dim = getattr(cfg, "index_cache_dim", None)
         state_spec = getattr(cfg, "state_cache_spec", None)
         # validate the model's compute dtype against the requested pool
         # format up front — the old code silently assumed fp32 pools and
@@ -741,7 +780,8 @@ class PagedKVCache:
         pack = _lane_pack(head_dim, kv_heads) if pack_heads and plain else 1
         return cls(cfg.num_hidden_layers, num_pages, page_size, kv_heads,
                    head_dim, dtype, kv_dtype=kv_dtype,
-                   latent_dim=latent_dim, head_pack=pack,
+                   latent_dim=latent_dim, index_dim=index_dim,
+                   head_pack=pack,
                    state_spec=state_spec, state_slots=state_slots)
 
     def shard_pools(self, mesh, spec) -> None:

@@ -145,6 +145,31 @@ def _mla_decode(rows=32):
                 ((rows, 1088), jnp.int32), ((rows,), jnp.int32)]
 
 
+def _dsa_index(rows=32):
+    """The sparse cell's own index call: 64 index heads of 128 over one
+    key of 128 a token, pages of 16, 2,496 pages a row, the
+    configuration's pool of 47,800 pages, bf16."""
+    return paged._dsa_index_pallas, [
+        ((rows, 64, 128), jnp.bfloat16), ((rows, 64), jnp.float32),
+        ((47800, 16, 128), jnp.bfloat16), ((rows, 2496), jnp.int32),
+        ((rows,), jnp.int32)]
+
+
+def _mla_sparse_decode(rows=32):
+    """The sparse cell's own attention call: 128 heads over the 2,048
+    chosen rows of 512 + 64 held in 640 columns, gathered from the same
+    pool by token index."""
+    def fn(q, pool, table, chosen, n):
+        return paged._mla_sparse_decode_pallas(
+            q, pool, table, chosen, n, scale=192 ** -0.5 * 1.87386,
+            latent=512)
+
+    return fn, [((rows, 128, 576), jnp.bfloat16),
+                ((47800, 16, 640), jnp.bfloat16),
+                ((rows, 2496), jnp.int32), ((rows, 2048), jnp.int32),
+                ((rows,), jnp.int32)]
+
+
 def _moe_grouped_matmul(pairs, down=False):
     """One projection of the 256 experts of 2048 x 768 over the sorted
     pairs of a decode step (256, or 8 for one row) or of a prefill's
@@ -189,6 +214,10 @@ _ONE_CHIP = {
     "layer_norm_decode": _layer_norm_decode,
     "mla_decode": _mla_decode,
     "mla_decode_rows1": functools.partial(_mla_decode, rows=1),
+    "dsa_index": _dsa_index,
+    "dsa_index_rows1": functools.partial(_dsa_index, rows=1),
+    "mla_sparse_decode": _mla_sparse_decode,
+    "mla_sparse_decode_rows1": functools.partial(_mla_sparse_decode, rows=1),
     "moe_grouped_matmul_decode": functools.partial(_moe_grouped_matmul, 256),
     "moe_grouped_matmul_one_row": functools.partial(_moe_grouped_matmul, 8),
     "moe_grouped_matmul_prefill": functools.partial(_moe_grouped_matmul,
@@ -644,8 +673,96 @@ def test_serve_scope_reaches_the_latent_model_s_step(mla_moe_hlo, scope):
     assert _scoped(mla_moe_hlo[program], scope)
 
 
+# ------------------------------- the same decoder with sparse attention
+
+# rows a decode block of the small sparse engine below is compiled for,
+# the most tokens a row of its table holds, and its latent row in whole
+# tiles: no array of the decode block may be (rows, context, row)
+_SPARSE_ROWS, _SPARSE_CONTEXT, _SPARSE_ROW = 4, _SEQ, 640
+
+
+@pytest.fixture(scope="module")
+def mla_sparse_hlo(topo, kernel_paths):
+    """As `mla_moe_hlo` with the indexer on (4 index heads of 128, 64 of
+    512 positions attended), two groups of experts of which one stays,
+    YaRN, and experts 2-5 of the router's 8 held."""
+    from paddle_tpu.models import MlaMoeConfig, MlaMoeForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = MlaMoeConfig(vocab_size=_VOCAB, hidden_size=256,
+                       num_hidden_layers=2, num_attention_heads=_HEADS,
+                       q_lora_rank=128, intermediate_size=768,
+                       moe_intermediate_size=128, n_routed_experts=4,
+                       router_experts=8, expert_offset=2,
+                       num_experts_per_tok=2, n_group=2, topk_group=1,
+                       index_topk=64, index_n_heads=4, index_head_dim=128,
+                       rope_scaling={"type": "yarn", "factor": 4.0,
+                                     "original_max_position_embeddings": 128,
+                                     "beta_fast": 32, "beta_slow": 1,
+                                     "mscale": 1.0, "mscale_all_dim": 1.0},
+                       dtype="bfloat16", deferred_weights=True)
+    model = MlaMoeForCausalLM(cfg)
+    model.eval()
+    eng = ServingEngine(model, page_size=16, max_batch_size=_SPARSE_ROWS,
+                        max_seq_len=_SPARSE_CONTEXT, kv_dtype="bf16")
+    compiled = _compile_serve(eng, topo.devices[0], _SPARSE_ROWS, _SEQ)
+    return {name: c.as_text() for name, c in compiled.items()}
+
+
+def test_sparse_decode_kernels_are_named_where_they_are_created(
+        mla_sparse_hlo):
+    calls = _custom_calls(mla_sparse_hlo["decode_block"])
+    assert {scopes.DSA_INDEX_KERNEL,
+            scopes.MLA_SPARSE_DECODE_KERNEL} <= calls
+    # the dense latent kernel is off the sparse model's path
+    assert scopes.MLA_DECODE_KERNEL not in calls
+    assert not {scopes.DSA_INDEX_KERNEL, scopes.MLA_SPARSE_DECODE_KERNEL
+                } & _custom_calls(mla_sparse_hlo["prefill"])
+
+
+def _whole_context_arrays(text: str) -> list:
+    """Arrays of the program's text that hold a cached row of every
+    position of every row's context: (rows, context, row width), the rows
+    and the context in either order, whole or folded into one
+    dimension."""
+    rows, ctx, row = _SPARSE_ROWS, _SPARSE_CONTEXT, _SPARSE_ROW
+    shapes = {f"{rows},{ctx},{row}", f"{ctx},{rows},{row}",
+              f"{rows * ctx},{row}", f"{rows},{ctx // 16},16,{row}"}
+    return sorted({m.group(0) for m in re.finditer(
+        r"(?:bf16|f32)\[([\d,]+)\]", text) if m.group(1) in shapes})
+
+
+def test_no_decode_executable_holds_a_row_s_whole_context(mla_sparse_hlo,
+                                                          mla_moe_hlo):
+    """Sparse attention reads the chosen rows alone: the decode block
+    has no array of (rows, max context, 640). The same search finds
+    nothing in the dense latent model's block either (its kernel walks
+    the pages in place), and is shown to see: the jnp path of the dense
+    decode, compiled for the same sizes, has the array."""
+    assert _whole_context_arrays(mla_sparse_hlo["decode_block"]) == []
+    assert _whole_context_arrays(mla_moe_hlo["decode_block"]) == []
+    from paddle_tpu.serving.kv_cache import LatentLayerCache
+
+    def gathers(q, pool, table, pos):
+        return paged._mla_decode_reference(
+            q, LatentLayerCache(pool, table), pos, 0.1, 512)
+
+    text = jax.jit(gathers).lower(
+        jax.ShapeDtypeStruct((_SPARSE_ROWS, _HEADS, 576), jnp.bfloat16),
+        jax.ShapeDtypeStruct((200, 16, _SPARSE_ROW), jnp.bfloat16),
+        jax.ShapeDtypeStruct((_SPARSE_ROWS, _SPARSE_CONTEXT // 16),
+                             jnp.int32),
+        jax.ShapeDtypeStruct((_SPARSE_ROWS,), jnp.int32)).as_text()
+    assert re.search(
+        rf"tensor<{_SPARSE_ROWS}x{_SPARSE_CONTEXT // 16}x16x{_SPARSE_ROW}x"
+        rf"|tensor<{_SPARSE_ROWS}x{_SPARSE_CONTEXT}x{_SPARSE_ROW}x", text)
+
+
 # each sub-scope's serving scope and the programs it is found in; the
-# Mamba-2 mixer's are the hybrid decoder's, the others the latent one's
+# Mamba-2 mixer's are the hybrid decoder's, sparse attention's the sparse
+# latent decoder's, the others the latent one's
+_DSA_HOME = {"decode_block": scopes.PAGED_ATTENTION,
+             "prefill": scopes.PREFILL_ATTENTION}
 _SUBSCOPE_HOME = {
     scopes.MLA_ABSORB: (scopes.PAGED_ATTENTION, ["decode_block"]),
     scopes.SSM_IN_PROJ: (scopes.ATTN_QKV, ["decode_block", "prefill"]),
@@ -657,16 +774,29 @@ _SUBSCOPE_HOME = {
 
 
 @pytest.mark.parametrize("scope", scopes.SERVE_SUBSCOPES)
-def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, hybrid_hlo, scope):
+def test_sub_scope_reaches_the_compiled_step(mla_moe_hlo, hybrid_hlo,
+                                             mla_sparse_hlo, scope):
     parent, programs = _SUBSCOPE_HOME.get(
         scope, (scopes.MLP, ["decode_block", "prefill"]))
-    hlo = hybrid_hlo if scope.startswith("ssm_") else mla_moe_hlo
+    hlo = (hybrid_hlo if scope.startswith("ssm_")
+           else mla_sparse_hlo if scope.startswith("dsa_") else mla_moe_hlo)
     for program in programs:
         names = _scoped(hlo[program], scope)
         assert names, (program, scope)
+        if scope.startswith("dsa_"):
+            # the indexer's projections lie under `attn_qkv`, its scores,
+            # the choice and the attention under the step's attention
+            parent = "(?:" + _DSA_HOME[program] + (
+                "|" + scopes.ATTN_QKV if scope == scopes.DSA_INDEX else ""
+            ) + ")"
+            assert any(re.search(_DSA_HOME[program] + r"/(?:[^/]+/)*"
+                                 + scope, n) for n in names)
         # nested inside the serving scope the benchmark's list holds
         assert all(re.search(parent + r"/(?:[^/]+/)*" + scope, n)
                    for n in names)
+    if scope.startswith("dsa_"):
+        # and the model without an indexer has none of it
+        assert not _scoped(mla_moe_hlo["decode_block"], scope)
 
 
 # ------------------ the hybrid (Mamba-2 + attention) decoder's state

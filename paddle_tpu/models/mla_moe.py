@@ -457,7 +457,7 @@ class MlaAttention(nn.Layer):
                 freq, scale, s if live is None else live)
                 for i in range(b)]), new_cache
         else:
-            ctx = att.latent_prefill_attention(q, k, v, scale)
+            ctx = att.latent_prefill_attention(q, k, v, scale, live)
         with jax.named_scope(scopes.ATTN_OUT):
             out = ctx.reshape(b, s, heads * vd) @ self.o_proj.weight._data
         return out, new_cache
@@ -926,6 +926,13 @@ class MlaMoeForCausalLM(nn.Layer):
     # (logits, caches, aux)
     serving_logits_at = True
     serving_aux = True
+
+    @property
+    def serving_prefill_live(self):
+        """Whether a prefill's flash walks the prompt's own blocks alone
+        (`latent_prefill_attention` given the prompt's length): the dense
+        latent kind; the sparse kind runs its own masked blocks."""
+        return self.config.index_topk is None
 
     def __init__(self, cfg: Optional[MlaMoeConfig] = None):
         super().__init__()

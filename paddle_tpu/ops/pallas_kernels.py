@@ -114,16 +114,19 @@ def _causal_block_live(qi, ki, block_q, block_k, offs=None):
     return q_off + (qi + 1) * block_q - 1 >= k_off + ki * block_k
 
 
-def flash_block_steps(sq_p, sk_p, block_q, block_k, is_causal):
+def flash_block_steps(sq_p, sk_p, block_q, block_k, is_causal, live=None):
     """(computed, total) grid steps a (batch, head) of one flash call over
     padded lengths `sq_p` x `sk_p`: what the kernels' predicate keeps, for
-    the tests and for PERF.md's reckoning."""
+    the tests, the serving counters and PERF.md's reckoning. With `live`
+    (causal only: `flash_prefill`) the q blocks past the first `live`
+    rows compute nothing either."""
     n_q, n_k = sq_p // block_q, sk_p // block_k
     if not is_causal:
         return n_q * n_k, n_q * n_k
-    live = sum(bool(_causal_block_live(qi, ki, block_q, block_k))
-               for qi in range(n_q) for ki in range(n_k))
-    return live, n_q * n_k
+    rows = n_q if live is None else min(-(-live // block_q), n_q)
+    steps = sum(bool(_causal_block_live(qi, ki, block_q, block_k))
+                for qi in range(rows) for ki in range(n_k))
+    return steps, n_q * n_k
 
 
 def _qkv_layout(qt, kt, *, heads, block_q, block_k, kv_major, vma,
@@ -213,6 +216,27 @@ def _mask_spec(coords, block_q, block_k, b_is_one, h_is_one, q_is_one):
     return pl.BlockSpec((1, 1, 1 if q_is_one else block_q, block_k), index)
 
 
+def _online_softmax(s, m_ref, l_ref):
+    """One k block's step of the forward's running softmax: folds the
+    block's (block_q, block_k) scores `s` into the row maxima `m_ref`
+    and sums `l_ref`. Returns the block's unnormalized weights and the
+    factor the accumulator is rescaled by."""
+    m_prev = m_ref[...]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # fully-masked rows keep m=-inf; clamp so exp(-inf--inf) != nan.
+    # In-kernel values are finite or -inf by construction, and the
+    # is_finite primitive has no Mosaic lowering on this jax — the
+    # != -inf test is the same guard and compiles
+    m_safe = jnp.where(m_cur != -jnp.inf, m_cur, 0.0)
+    p = jnp.exp(jnp.where(s != -jnp.inf, s - m_safe, -jnp.inf))
+    alpha = jnp.where(m_prev != -jnp.inf,
+                      jnp.exp(m_prev - m_safe), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                              keepdims=True)
+    m_ref[...] = m_cur
+    return p, alpha
+
+
 def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
               mask_b_is_one, mask_h_is_one, mask_q_is_one, block_q, block_k,
               dropout_p, interpret, offs=None, keep_neg_inf_lse=False,
@@ -286,19 +310,7 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
                     s = jnp.where(rows >= cols, s, -jnp.inf)
             if need_k_mask:
                 s = jnp.where(cols < sk, s, -jnp.inf)
-            m_prev = m_ref[...]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            # fully-masked rows keep m=-inf; clamp so exp(-inf--inf) != nan.
-            # In-kernel values are finite or -inf by construction, and the
-            # is_finite primitive has no Mosaic lowering on this jax — the
-            # != -inf test is the same guard and compiles
-            m_safe = jnp.where(m_cur != -jnp.inf, m_cur, 0.0)
-            p = jnp.exp(jnp.where(s != -jnp.inf, s - m_safe, -jnp.inf))
-            alpha = jnp.where(m_prev != -jnp.inf,
-                              jnp.exp(m_prev - m_safe), 0.0)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-            m_ref[...] = m_cur
+            p, alpha = _online_softmax(s, m_ref, l_ref)
             vblk = blk(v_ref)
             # attention dropout (upscale_in_train): drop unnormalized
             # weights in the value accumulation; the softmax denominator l
@@ -371,6 +383,140 @@ def _fwd_call(qt, kt, vt, mask, seed, *, scale, sk, is_causal, has_mask,
         ],
         interpret=interpret,
     )(*operands)
+    return out, lse
+
+
+def _live_grid(live, n_q, n_k, block_q, block_k):
+    """The flattened grid of a causal forward over a prompt of `live`
+    (traced int32) rows: the causal steps (qi, ki) of the first
+    ceil(live / block_q) q blocks, q-major, then one step a q block past
+    the prompt. Returns the step count and three (steps,) int32 tables
+    sized for the whole bucket: the q block a step writes, and the q and
+    k blocks it reads. A step past the prompt reads the blocks of the
+    last computed step, which the pipeline holds, so it brings nothing."""
+    kept = [[ki for ki in range(n_k)
+             if _causal_block_live(qi, ki, block_q, block_k)]
+            for qi in range(n_q)]
+    q_all = jnp.asarray(np.repeat(np.arange(n_q), [len(r) for r in kept]),
+                        jnp.int32)
+    k_all = jnp.asarray(np.concatenate(kept), jnp.int32)
+    first = jnp.asarray(np.cumsum([0] + [len(r) for r in kept]), jnp.int32)
+    rows = jnp.clip((live + block_q - 1) // block_q, 0, n_q)
+    computed = first[rows]
+    t = jnp.arange(q_all.shape[0], dtype=jnp.int32)
+    past = t >= computed
+    last = jnp.maximum(computed - 1, 0)
+    q_out = jnp.where(past, jnp.minimum(rows + t - computed, n_q - 1), q_all)
+    q_in = jnp.where(past, q_all[last], q_all)
+    k_in = jnp.where(past, k_all[last], k_all)
+    return computed + n_q - rows, q_out, q_in, k_in
+
+
+def _fwd_live_call(qt, kt, vt, live, *, scale, block_q, block_k, interpret,
+                   heads=None):
+    """The causal forward of a prefill whose prompt is the first `live`
+    rows of a longer bucket (qt/kt/vt as `_fwd_call`'s, padded alike;
+    `live` an int32 (1,) array). Its grid is (b, h, steps) with `steps`
+    traced (`_live_grid`): the prompt's causal blocks alone, then one
+    step a q block past the prompt that writes zeros. Rows at or past
+    `live` come out zero with a logsumexp of 0, whatever the padding
+    holds; keys past it are zeroed before P.V so that 0 x NaN cannot
+    reach a row of the prompt. No mask, dropout or ring offsets: the
+    exact prefill from position 0 needs none. Returns (out_padded,
+    logsumexp)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    packed = heads is not None
+    (b, h, sq_p, sk_p, d_p, blk, _, _, sds_like,
+     _) = _qkv_layout(qt, kt, heads=heads, block_q=block_q, block_k=block_k,
+                      kv_major=False, vma=None)
+    n_q, n_k = sq_p // block_q, sk_p // block_k
+    steps, q_out, q_in, k_in = _live_grid(live[0], n_q, n_k, block_q,
+                                          block_k)
+
+    def kernel(q_out_ref, q_in_ref, k_in_ref, live_ref, q_ref, k_ref, v_ref,
+               o_ref, lse_ref, acc_ref, m_ref, l_ref):
+        t = pl.program_id(2)
+        qi, ki, n = q_out_ref[t], k_in_ref[t], live_ref[0]
+        computes = qi * block_q < n           # a row of the block is there
+
+        @pl.when(ki == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        @pl.when(computes)
+        def _compute():
+            s = jax.lax.dot_general(
+                blk(q_ref), blk(k_ref), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT) * scale
+            rows = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            cols = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            p, alpha = _online_softmax(jnp.where(rows >= cols, s, -jnp.inf),
+                                       m_ref, l_ref)
+            vblk = blk(v_ref)
+            keys = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, vblk.shape, 0)
+            vblk = jnp.where(keys < n, vblk, jnp.zeros_like(vblk))
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
+
+        last_k = jnp.minimum(((qi + 1) * block_q - 1) // block_k, n_k - 1)
+
+        @pl.when(jnp.logical_and(computes, ki == last_k))
+        def _done():
+            there = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0) < n
+            l_fin = jnp.maximum(l_ref[...], 1e-30)
+            _blk_store(packed, o_ref, jnp.where(
+                there, acc_ref[...] / l_fin, 0.0).astype(o_ref.dtype))
+            lse = jnp.where(there, m_ref[...] + jnp.log(l_fin), 0.0)[:, 0]
+            lse_ref[0, 0] = jnp.broadcast_to(lse[None, :], (8, block_q))
+
+        @pl.when(jnp.logical_not(computes))
+        def _zeros():
+            _blk_store(packed, o_ref, jnp.zeros((block_q, d_p), o_ref.dtype))
+            lse_ref[0, 0] = jnp.zeros((8, block_q), jnp.float32)
+
+    def spec(block, pick):
+        if packed:
+            return pl.BlockSpec((1, block, d_p),
+                                lambda b_, h_, t, *tabs: (b_, pick(t, *tabs),
+                                                          h_))
+        return pl.BlockSpec((1, 1, block, d_p),
+                            lambda b_, h_, t, *tabs: (b_, h_,
+                                                      pick(t, *tabs), 0))
+
+    k_spec = spec(block_k, lambda t, q_out, q_in, k_in, n: k_in[t])
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, h, steps),
+            in_specs=[spec(block_q, lambda t, q_out, q_in, k_in, n: q_in[t]),
+                      k_spec, k_spec],
+            out_specs=[
+                spec(block_q, lambda t, q_out, q_in, k_in, n: q_out[t]),
+                pl.BlockSpec((1, 1, 8, block_q),
+                             lambda b_, h_, t, q_out, q_in, k_in, n:
+                             (b_, h_, 0, q_out[t])),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d_p), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ]),
+        out_shape=[sds_like(sq_p, qt.dtype),
+                   _sds((b, h, 8, sq_p), jnp.float32, None)],
+        interpret=interpret,
+    )(q_out, q_in, k_in, live, qt, kt, vt)
     return out, lse
 
 
@@ -715,13 +861,12 @@ def _flash_vjp(is_causal: bool, has_mask: bool, mask_b_is_one: bool,
     return f
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("is_causal", "has_mask", "mask_needs_grad",
-                     "dropout_p", "interpret"))
-def _flash_attention_data(q, k, v, mask=None, seed=None, is_causal=False,
-                          has_mask=False, mask_needs_grad=False,
-                          dropout_p=0.0, interpret=False):
+def _flash_layout(q, k, v):
+    """The kernels' operands of (b, s, h, d) q, k, v: blocks picked,
+    lengths and head width padded, heads packed or transposed. Returns
+    (qt, kt, vt, heads, block_q, block_k, sq_p, sk_p, back), `heads` the
+    kernels' `heads=` and `back` the map of their output to (b, sq, h,
+    d)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = _pick_block(sq, _BLOCK_Q)
@@ -745,13 +890,32 @@ def _flash_attention_data(q, k, v, mask=None, seed=None, is_causal=False,
         def prep(x, s_target):
             x = x.reshape(x.shape[0], x.shape[1], h * d)
             return jnp.pad(x, ((0, 0), (0, s_target - x.shape[1]), (0, 0)))
+
+        def back(out):
+            return out[:, :sq, :].reshape(b, sq, h, d)
     else:
         def prep(x, s_target):
             x = jnp.einsum("bshd->bhsd", x)
             return jnp.pad(x, ((0, 0), (0, 0), (0, s_target - x.shape[2]),
                                (0, d_p - d)))
 
-    qt, kt, vt = prep(q, sq_p), prep(k, sk_p), prep(v, sk_p)
+        def back(out):
+            return jnp.einsum("bhsd->bshd", out[:, :, :sq, :d])
+
+    return (prep(q, sq_p), prep(k, sk_p), prep(v, sk_p),
+            h if packed else None, block_q, block_k, sq_p, sk_p, back)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("is_causal", "has_mask", "mask_needs_grad",
+                     "dropout_p", "interpret"))
+def _flash_attention_data(q, k, v, mask=None, seed=None, is_causal=False,
+                          has_mask=False, mask_needs_grad=False,
+                          dropout_p=0.0, interpret=False):
+    sq, d = q.shape[1], q.shape[3]
+    sk = k.shape[1]
+    qt, kt, vt, heads, _, _, sq_p, sk_p, back = _flash_layout(q, k, v)
     mask_b_is_one = mask_h_is_one = mask_q_is_one = True
     if has_mask:
         # keep broadcast (size-1) batch/head/q dims at 1 — the BlockSpec
@@ -774,11 +938,33 @@ def _flash_attention_data(q, k, v, mask=None, seed=None, is_causal=False,
 
     f = _flash_vjp(is_causal, has_mask, mask_b_is_one, mask_h_is_one,
                    mask_q_is_one, sk, d, mask_needs_grad, float(dropout_p),
-                   interpret, heads=h if packed else None)
-    out = f(qt, kt, vt, mask, seed.astype(jnp.int32).reshape((1,)))
-    if packed:
-        return out[:, :sq, :].reshape(b, sq, h, d)
-    return jnp.einsum("bhsd->bshd", out[:, :, :sq, :d])
+                   interpret, heads=heads)
+    return back(f(qt, kt, vt, mask, seed.astype(jnp.int32).reshape((1,))))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def flash_prefill(q, k, v, live, interpret=False):
+    """Causal self-attention of a prefill from position 0 whose prompt is
+    the first `live` (traced int32) of the s rows of q, k, v (b, s, h,
+    d): the forward alone, over the prompt's own causal blocks
+    (`_fwd_live_call`). Rows at or past `live` come out zero."""
+    qt, kt, vt, heads, block_q, block_k, _, _, back = _flash_layout(q, k, v)
+    out, _ = _fwd_live_call(
+        qt, kt, vt, jnp.asarray(live, jnp.int32).reshape((1,)),
+        scale=1.0 / math.sqrt(q.shape[3]), block_q=block_q, block_k=block_k,
+        interpret=interpret, heads=heads)
+    return back(out)
+
+
+@functools.lru_cache(maxsize=None)
+def flash_prefill_steps(s, live):
+    """(computed, the whole bucket's causal) grid steps a (batch, head) of
+    `flash_prefill` over `s` rows of which the first `live` are the
+    prompt's."""
+    block = _pick_block(s, _BLOCK_Q)
+    s_p = _round_up(s, block)
+    return (flash_block_steps(s_p, s_p, block, block, True, live)[0],
+            flash_block_steps(s_p, s_p, block, block, True)[0])
 
 
 def flash_attention(q, k, v, attn_mask=None, is_causal=False,

@@ -836,13 +836,17 @@ def latent_write(rows, cache: LatentLayerCache, start_pos, index_keys=None):
     return LatentLayerCache(pool, cache.page_table, index_pool), pos
 
 
-def latent_prefill_attention(q, k, v, scale: float):
+def latent_prefill_attention(q, k, v, scale: float, live=None):
     """Exact causal attention of a prefill that starts at position 0 over
     the step's own expanded K/V. q, k: (b, s, heads, dk); v: (b, s, heads,
     dv) with dv <= dk. The flash helper takes one width, so V rides padded
     to dk and the output is cut back; `scale` must be dk ** -0.5, which is
-    what the helper applies."""
+    what the helper applies. `live` (a traced int32, or None for all):
+    how many of the s positions are the prompt; given, the kernel walks
+    the prompt's own blocks alone (`pallas_kernels.flash_prefill`) and
+    the rows past it come out zero, on every path."""
     from ..nn import functional as F
+    from ..ops import pallas_kernels
 
     dk, dv = q.shape[-1], v.shape[-1]
     if abs(scale * math.sqrt(dk) - 1.0) > 1e-6:
@@ -850,9 +854,15 @@ def latent_prefill_attention(q, k, v, scale: float):
     with jax.named_scope(scopes.PREFILL_ATTENTION):
         _count_dispatch("mla_prefill")
         vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dk - dv)))
+        if live is not None and pallas_kernels.flash_attention_available(
+                q, k, vp):
+            return pallas_kernels.flash_prefill(q, k, vp, live)[..., :dv]
         ctx = F.scaled_dot_product_attention(
-            Tensor(q), Tensor(k), Tensor(vp), is_causal=True)
-        return ctx._data[..., :dv]
+            Tensor(q), Tensor(k), Tensor(vp), is_causal=True)._data
+        if live is not None:
+            there = jnp.arange(q.shape[1])[None, :, None, None] < live
+            ctx = jnp.where(there, ctx, jnp.zeros_like(ctx))
+        return ctx[..., :dv]
 
 
 def _latent_kernel(name: str, page_size: int):

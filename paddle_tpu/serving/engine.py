@@ -73,6 +73,7 @@ from ..observability import Histogram, LifecycleTracker, MetricsRegistry
 from ..observability.flight_recorder import (
     build_postmortem as _build_bundle, dump_postmortem as _dump_bundle)
 from ..observability.slo import SloTracker
+from ..ops.pallas_kernels import flash_prefill_steps
 from ..profiler import RecordEvent, scopes
 from .attention import advance_positions
 from .kv_cache import (PagedKVCache, PagedLayerCache, overflow_position,
@@ -305,6 +306,9 @@ class ServingObs:
         # sparse-attention handles, bound by bind_dsa() only for a model
         # whose queries attend the keys an indexer chooses
         self.dsa_keys_selected = None
+        # prefill-flash handles, bound by bind_prefill_flash() only for a
+        # model whose prefill hands flash the prompt's length
+        self.prefill_flash_steps = None
 
     def dispatched(self, greedy: bool) -> None:
         """One device program launched; `greedy` is the host's reading of
@@ -366,6 +370,28 @@ class ServingObs:
         self.dsa_keys_in_context.inc(layers * in_context)
         self.dsa_keys_selected.inc(layers * selected)
         return layers * selected
+
+    def bind_prefill_flash(self) -> None:
+        """Prefill-flash observability of a model whose prefill hands the
+        flash kernel the prompt's length: the grid steps a (head, layer)
+        computed over the prompt's own causal blocks, and those the
+        whole bucket's causal triangle holds. Their ratio is the share of
+        the bucket's steps computed; host integers at dispatch, no
+        sync."""
+        c = self.registry.counter
+        self.prefill_flash_steps = c(
+            "serving_prefill_flash_steps_total",
+            "causal flash steps a prefill computed over its prompt's own "
+            "blocks, a head a layer")
+        self.prefill_flash_bucket_steps = c(
+            "serving_prefill_flash_bucket_steps_total",
+            "causal flash steps of the prefill's whole bucket, a head a "
+            "layer")
+
+    def prefill_flash(self, bucket: int, prompt: int) -> None:
+        computed, whole = flash_prefill_steps(bucket, prompt)
+        self.prefill_flash_steps.inc(computed)
+        self.prefill_flash_bucket_steps.inc(whole)
 
     def bind_state_slots(self) -> None:
         """State-slot observability of a model with recurrent layers:
@@ -611,12 +637,15 @@ class ServingEngine:
                     "(tensor parallelism, quantized pages, and any "
                     "prefill at an offset: prefix cache, chunked prefill "
                     "and the ragged step, speculative verify)")
-        # the serving protocol's two optional parts (models/mla_moe.py):
-        # `logits_at` makes a prefill return one position's logits, and
-        # the cache path returns a third value whose expert histogram
-        # feeds the serving_moe_* counters
+        # the serving protocol's optional parts (models/mla_moe.py):
+        # `logits_at` makes a prefill return one position's logits, the
+        # cache path returns a third value whose expert histogram feeds
+        # the serving_moe_* counters, and a prefill's flash walks the
+        # prompt's own blocks, which the serving_prefill_flash_* count
         self._logits_at = bool(getattr(model, "serving_logits_at", False))
         self._has_aux = bool(getattr(model, "serving_aux", False))
+        self._prefill_live = bool(getattr(model, "serving_prefill_live",
+                                          False))
         self.tp_quantized_allreduce = bool(tp_quantized_allreduce)
         if self.tp_quantized_allreduce and int(tp_size) < 2:
             raise ValueError(
@@ -767,6 +796,8 @@ class ServingEngine:
             self._obs.bind_spec()
         if self._obs is not None and self._has_aux:
             self._obs.bind_moe()
+        if self._obs is not None and self._prefill_live:
+            self._obs.bind_prefill_flash()
         if self._obs is not None and self._has_state:
             self._obs.bind_state_slots()
         if self._obs is not None \
@@ -1474,6 +1505,8 @@ class ServingEngine:
         prev_t = req.last_token_t            # set => this is a re-prefill
         if o is not None:
             o.prefill_steps.inc()
+            if o.prefill_flash_steps is not None:
+                o.prefill_flash(bucket, len(suffix))
             o.dispatched(np.float32(sp.temperature) == 0.0)
             o.host_syncs.inc()
             o.prefill_seconds.inc(now - t0)

@@ -372,10 +372,10 @@ def engine():
             "spec": ServingEngine(model, spec_config=SpecConfig(), **kw)}
 
 
-def _compile_serve(eng, device, rows, bucket):
-    """The engine's decode block (horizon 8, `rows` rows) and its
-    prefill of `bucket` tokens, compiled for one described chip from
-    shapes alone."""
+def _compile_serve(eng, device, rows, bucket, decode=True):
+    """The engine's decode block (horizon 8, `rows` rows; unless not
+    `decode`) and its prefill of `bucket` tokens, compiled for one
+    described chip from shapes alone."""
     one_chip = SingleDeviceSharding(device)
 
     def sds(shape, dtype):
@@ -396,16 +396,17 @@ def _compile_serve(eng, device, rows, bucket):
         return ({"slots": sds((b,), jnp.int32)}
                 if eng.cache.slot_allocator is not None else {})
 
-    decode = eng._decode_block_jit(8).lower(
-        *state, sds((rows,), jnp.int32), pools,
-        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
-        *knobs(rows), sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32), **slots(rows)).compile()
-    prefill = eng._prefill_jit(bucket).lower(
+    compiled = {"prefill": eng._prefill_jit(bucket).lower(
         *state, sds((1, bucket), jnp.int32), pools,
         sds((1, pages), jnp.int32), sds((), jnp.int32), *knobs(1),
-        **slots(1)).compile()
-    return {"decode_block": decode, "prefill": prefill}
+        **slots(1)).compile()}
+    if decode:
+        compiled["decode_block"] = eng._decode_block_jit(8).lower(
+            *state, sds((rows,), jnp.int32), pools,
+            sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
+            *knobs(rows), sds((rows,), jnp.int32),
+            sds((rows,), jnp.int32), **slots(rows)).compile()
+    return compiled
 
 
 @pytest.fixture(scope="module")
@@ -671,6 +672,91 @@ def test_serve_scope_reaches_the_latent_model_s_step(mla_moe_hlo, scope):
     program = ("prefill" if scope == scopes.PREFILL_ATTENTION
                else "decode_block")
     assert _scoped(mla_moe_hlo[program], scope)
+
+
+def _kernel_grids(text: str, name: str) -> list:
+    """The grid of each Mosaic kernel of the program whose custom call's
+    name holds `name`, read from its serialized body; a dimension the
+    program sizes at run time reads None."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir, passmanager
+
+    grids = []
+    for line in text.splitlines():
+        call = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = [^\n]*custom-call\(",
+                        line)
+        if not call or name not in call.group(1):
+            continue
+        body = base64.b64decode(re.search(r'"body":"([^"]+)"',
+                                          line).group(1))
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(body)
+            passmanager.PassManager.parse(
+                "builtin.module(mosaic-serde{serialize=false})").run(
+                    module.operation)
+            bounds = re.search(r"iteration_bounds = array<i64: ([^>]*)>",
+                               str(module)).group(1)
+        grids.append(tuple(None if int(b) < 0 else int(b)
+                           for b in bounds.split(",")))
+    return grids
+
+
+_LONG_BUCKET = 16384
+
+
+@pytest.fixture(scope="module")
+def mla_moe_long_prefill(topo, kernel_paths):
+    """The compiler's text of the prefill of the latent cell's largest
+    power-of-two bucket, 16,384 tokens, for a small MlaMoe model at the
+    published head widths (192 for scores, 128 for values)."""
+    from paddle_tpu.models import MlaMoeConfig, MlaMoeForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = MlaMoeConfig(vocab_size=_VOCAB, hidden_size=256,
+                       num_hidden_layers=2, num_attention_heads=_HEADS,
+                       q_lora_rank=128, intermediate_size=768,
+                       moe_intermediate_size=128, n_routed_experts=8,
+                       num_experts_per_tok=2, dtype="bfloat16",
+                       deferred_weights=True)
+    model = MlaMoeForCausalLM(cfg)
+    model.eval()
+    eng = ServingEngine(model, page_size=16, max_batch_size=1,
+                        max_seq_len=_LONG_BUCKET, kv_dtype="bf16",
+                        num_pages=8)
+    return _compile_serve(eng, topo.devices[0], 1, _LONG_BUCKET,
+                          decode=False)["prefill"].as_text()
+
+
+def test_latent_prefill_flash_walks_the_prompt_s_blocks(mla_moe_long_prefill):
+    """Each layer's flash over the 16,384 bucket is the prompt-length
+    kernel: a grid of (batch, heads, steps) whose steps the program
+    sizes from the prompt's length, fed by the bucket's step tables
+    (528 causal steps of 512-blocks); the whole-bucket kernel is not in
+    the program."""
+    text = mla_moe_long_prefill
+    assert _kernel_grids(text, "flash_prefill") == [(1, _HEADS, None)] * 2
+    assert not _kernel_grids(text, "_flash_attention_data")
+    calls = re.findall(r"%flash_prefill[.\d]* = [^\n]*custom-call[^\n]*",
+                       text)
+    operands = ("operand_layout_constraints={s32[], s32[528]{0}, "
+                "s32[528]{0}, s32[528]{0}, s32[1]{0}, bf16[1,2,16384,256]")
+    assert len(calls) == 2
+    assert all(operands in c for c in calls), [c[:400] for c in calls]
+
+
+def test_train_flash_grids_are_static(train_hlo):
+    """The train step's flash kernels keep their (batch, heads, q blocks,
+    k blocks) grids, every dimension fixed at compile time."""
+    for name in ("jvp_jit__flash_attention_data__",
+                 "transpose_jvp_jit__flash_attention_data___"):
+        grids = _kernel_grids(train_hlo, name)
+        assert grids and all(g == (_TRAIN_ROWS, _HEADS, 1, 1)
+                             for g in grids), (name, grids)
+    assert "flash_prefill" not in train_hlo
 
 
 # ------------------------------- the same decoder with sparse attention

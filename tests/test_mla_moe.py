@@ -110,6 +110,80 @@ def test_prefill_then_decode_through_the_latent_pool(seeded, kernel_mode,
             seqs[i].append(int(np.argmax(want)))
 
 
+def test_prefill_over_the_prompt_s_blocks_equals_the_whole_bucket(
+        seeded, kernel_mode, monkeypatch):
+    """The flash kernels themselves, interpreted: a prompt of 300 tokens
+    in a bucket of 2,048 (one q block of 512 computed, three past the
+    prompt) through the latent pool, then three decode steps. Logits and
+    the pool's rows equal those of the whole-bucket kernel the prefill
+    took before it was told the prompt's length, and no NaN reaches the
+    pool, though its null page takes the padding's rows."""
+    from paddle_tpu.ops import pallas_kernels
+
+    model, leaves, cfg = seeded
+    kernel_mode("interpret")
+    monkeypatch.setattr(pallas_kernels, "flash_attention_available",
+                        lambda *a, **k: True)
+    whole_kernel, live_kernel = (pallas_kernels.flash_attention,
+                                 pallas_kernels.flash_prefill)
+    lives = []
+
+    def flash_prefill(q, k, v, live):
+        lives.append(live)
+        return live_kernel(q, k, v, live, interpret=True)
+
+    monkeypatch.setattr(pallas_kernels, "flash_attention",
+                        lambda *a, **kw: whole_kernel(*a, **kw,
+                                                      interpret=True))
+    monkeypatch.setattr(pallas_kernels, "flash_prefill", flash_prefill)
+    told = attention.latent_prefill_attention
+
+    def untold(q, k, v, scale, live=None):
+        return told(q, k, v, scale)
+
+    ps, bucket, n = 8, 2048, 300
+    prompt = np.random.default_rng(6).integers(0, CFG.vocab_size, n)
+    table = np.zeros((1, bucket // ps), np.int32)
+    table[0, :40] = np.arange(1, 41)
+
+    def serve(prefill_attention):
+        monkeypatch.setattr(attention, "latent_prefill_attention",
+                            prefill_attention)
+        cache = PagedKVCache.for_model(model, 42, ps)
+        views = cache.layer_views(jnp.asarray(table))
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = prompt
+        logits, new, _ = model(jnp.asarray(ids), caches=views, start_pos=0,
+                               logits_at=jnp.int32(n - 1))
+        cache.update(new)
+        got, seq = [np.asarray(logits._data)[0, 0]], list(prompt)
+        for step in range(3):
+            seq.append(int(np.argmax(got[-1])))
+            logits, new, _ = model(
+                jnp.asarray([[seq[-1]]], np.int32),
+                caches=cache.layer_views(jnp.asarray(table)),
+                start_pos=jnp.asarray([len(seq) - 1], np.int32))
+            cache.update(new)
+            got.append(np.asarray(logits._data)[0, 0])
+        pools = np.stack([np.asarray(p[0]) for p in cache.pools])
+        return np.stack(got), pools, seq
+
+    whole_logits, whole_pools, whole_seq = serve(untold)
+    assert not lives
+    live_logits, live_pools, live_seq = serve(told)
+    assert len(lives) == CFG.num_hidden_layers
+    assert live_seq == whole_seq
+    assert np.abs(live_logits - whole_logits).max() < 1e-5
+    want = np.asarray(reference.logits(leaves, prompt, [n - 1], cfg))[0]
+    assert np.abs(live_logits[0] - want).max() < 1e-4
+    assert np.isfinite(live_pools).all()
+    # the prompt's and the decoded tokens' rows: pages 1-38
+    rows = live_pools[:, 1:39].reshape(CFG.num_hidden_layers, -1, 128)
+    assert np.abs(rows[:, :n + 3]
+                  - whole_pools[:, 1:39].reshape(rows.shape)[:, :n + 3]
+                  ).max() < 1e-5
+
+
 def _latent_case(dtype=jnp.float32):
     """Five rows over a pool of 30 pages of 8: ragged lengths, one row
     exactly on a block's edge, one parked (no block), and tables whose

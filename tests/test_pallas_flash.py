@@ -16,9 +16,12 @@ import pytest
 from paddle_tpu.ops.pallas_kernels import (_BLOCK_K, _BLOCK_Q,
                                            _causal_block_live,
                                            _flash_attention_data,
-                                           _fwd_call, _keep_mask,
-                                           _qkv_layout, _round_up,
-                                           flash_block_steps)
+                                           _flash_layout, _fwd_call,
+                                           _fwd_live_call, _keep_mask,
+                                           _live_grid, _qkv_layout,
+                                           _round_up, flash_block_steps,
+                                           flash_prefill,
+                                           flash_prefill_steps)
 
 
 def _ref_attention(q, k, v, mask=None, is_causal=False):
@@ -143,6 +146,95 @@ def test_skipped_step_names_a_block_in_vmem(sq, sk, block_q, block_k,
                 row = [k for k in range(n_k)
                        if _causal_block_live(qi, k, block_q, block_k)]
                 assert got == (qi, row[-1])
+
+
+# ------------------------------------------------ the prompt-length grid
+def _latent_cell_prompts():
+    """The latent serving cell's 32 prompt lengths (the mid-quantiles of a
+    log-uniform law over 2,048-16,384) and their power-of-two buckets."""
+    lens = [int(round(np.exp(np.log(2048) + (i + 0.5) / 32 * np.log(8))))
+            for i in range(32)]
+    return [(n, 1 << (n - 1).bit_length()) for n in lens]
+
+
+def test_prompt_steps_of_the_latent_cell():
+    """The reckoning the prompt-length grid rests on: an 8,400-token
+    prompt in the 16,384 bucket computes 17 q blocks' causal steps, and
+    the cell's 32 prompts 4,330 of their buckets' 7,564."""
+    assert flash_block_steps(16384, 16384, 512, 512, True, live=8400) == (
+        153, 1024)
+    assert flash_block_steps(16384, 16384, 512, 512, True, live=16384) == (
+        528, 1024)
+    assert flash_block_steps(2048, 2048, 512, 512, True, live=1) == (1, 16)
+    prompts = _latent_cell_prompts()
+    assert sum(n for n, _ in prompts) / 32 == pytest.approx(6893, abs=1)
+    assert {b for _, b in prompts} == {4096, 8192, 16384}
+    computed = sum(flash_prefill_steps(b, n)[0] for n, b in prompts)
+    whole = sum(flash_prefill_steps(b, n)[1] for n, b in prompts)
+    assert (computed, whole) == (4330, 7564)
+
+
+@pytest.mark.parametrize("n_q", [1, 4, 8, 34])
+def test_a_step_past_the_prompt_names_a_block_in_vmem(n_q):
+    """The flattened grid: the prompt's causal blocks q-major, each once,
+    then one step a q block past the prompt, which writes that block and
+    reads the blocks of the last computed step (the pipeline holds them,
+    so it brings nothing)."""
+    for live in sorted({1, 511, 512, 513, n_q * 256 + 3, n_q * 512 - 1,
+                        n_q * 512} & set(range(1, n_q * 512 + 1))):
+        steps, q_out, q_in, k_in = (np.asarray(a) for a in _live_grid(
+            jnp.int32(live), n_q, n_q, _BLOCK_Q, _BLOCK_K))
+        rows = -(-live // _BLOCK_Q)
+        want = [(qi, ki) for qi in range(rows) for ki in range(qi + 1)]
+        computed = len(want)
+        assert int(steps) == computed + n_q - rows <= q_out.shape[0]
+        got = list(zip(q_out[:computed].tolist(), k_in[:computed].tolist()))
+        assert got == want
+        assert (q_in[:computed] == q_out[:computed]).all()
+        assert q_out[computed:int(steps)].tolist() == list(range(rows, n_q))
+        assert (q_in[computed:int(steps)] == q_in[computed - 1]).all()
+        assert (k_in[computed:int(steps)] == k_in[computed - 1]).all()
+
+
+@pytest.mark.parametrize("nan_padding", [False, True])
+@pytest.mark.parametrize("blocks,h,d", [(1, 2, 192), (4, 1, 64),
+                                        (8, 2, 128)])
+def test_prefill_over_the_prompt_s_blocks(blocks, h, d, nan_padding):
+    """`flash_prefill` over buckets of 1, 4 and 8 blocks (heads padded
+    to 256, transposed at 64, packed at 128): the prompt's rows are the
+    float32 causal reference's over the prompt alone and the
+    whole-bucket kernel's bit for bit, the rows past it are zero and
+    every row's logsumexp is finite, also where the padding is NaN."""
+    s = blocks * _BLOCK_Q
+    rng = np.random.RandomState(44)
+    q, k, v = _rand_qkv(rng, 1, s, s, h, d)
+    whole = np.asarray(_flash_attention_data(q, k, v, is_causal=True,
+                                             interpret=True))
+
+    @jax.jit
+    def lse_of(q, k, v, live):
+        qt, kt, vt, heads, block_q, block_k, *_ = _flash_layout(q, k, v)
+        return _fwd_live_call(qt, kt, vt, live.reshape((1,)),
+                              scale=d ** -0.5, block_q=block_q,
+                              block_k=block_k, interpret=True,
+                              heads=heads)[1]
+
+    for live in sorted({1, 511, 512, 513, s // 2 + 37, s}
+                       & set(range(1, s + 1))):
+        qq, kk, vv = q, k, v
+        if nan_padding:
+            qq, kk, vv = (x.at[:, live:].set(jnp.nan) for x in (q, k, v))
+        out = np.asarray(flash_prefill(qq, kk, vv, jnp.int32(live),
+                                       interpret=True))
+        ref = _ref_attention(q[:, :live], k[:, :live], v[:, :live],
+                             is_causal=True)
+        np.testing.assert_allclose(out[:, :live], np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(out[:, :live], whole[:, :live])
+        assert not out[:, live:].any()
+        lse = np.asarray(lse_of(qq, kk, vv, jnp.int32(live)))
+        assert np.isfinite(lse).all()
+        assert not lse[..., live:].any()
 
 
 def _dropout_reference(q, k, v, seed, dropout_p, mask=None):

@@ -104,6 +104,7 @@ from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..nn import Layer
+from ..observability import compiles
 from ..profiler import scopes
 from .mesh import (
     DP_AXIS, TP_AXIS, build_mesh, collected_shard_sum, device_order,
@@ -616,6 +617,8 @@ class ZeroTrainStep:
                  loss_scale: float = 2.0 ** 15,
                  scale_growth_interval: int = 200,
                  telemetry=None, enable_telemetry: bool = False):
+        # set-up accounting: what JAX builds for this trainer
+        compiles.install()
         if stage not in (0, 1, 2):
             raise ValueError(
                 f"stage must be 0 (replicated baseline), 1 (ZeRO-1) or 2 "
@@ -720,6 +723,7 @@ class ZeroTrainStep:
         self._chunks: Dict[str, int] = {}
         self._state_spec: Dict[str, Dict[str, P]] = {}
         self._step = None
+        self._batch_shapes: set = set()     # the step's built inputs
         self._probes: Dict[int, object] = {}
         # ---- training observability (ISSUE 19), opt-in. The import is
         # lazy AND conditional: a telemetry-off trainer never imports
@@ -978,9 +982,10 @@ class ZeroTrainStep:
             batch = tuple(batch)
             if self._step is None:
                 self._build(len(batch))
-            return self._step(params, opt_state, batch,
-                              jnp.asarray(lr, jnp.float32),
-                              jnp.asarray(t, jnp.int32))
+            with self._building(batch):
+                return self._step(params, opt_state, batch,
+                                  jnp.asarray(lr, jnp.float32),
+                                  jnp.asarray(t, jnp.int32))
         t_in = tele.clock()
         batch = tuple(batch)
         if self._step is None:
@@ -992,13 +997,25 @@ class ZeroTrainStep:
         tokens = (tele.tokens_per_step if tele.tokens_per_step is not None
                   else int(rows))
         t0 = tele.clock()
-        loss, new_p, new_s, health = self._step(params, opt_state, batch,
-                                                lr_, t_)
+        with self._building(batch):
+            loss, new_p, new_s, health = self._step(params, opt_state,
+                                                    batch, lr_, t_)
         t1 = tele.clock()
         host_loss = tele.record_step(
             health, step=int(t), tokens=tokens,
             batch_build_s=t0 - t_in, dispatch_s=t1 - t0)
         return host_loss, new_p, new_s
+
+    def _building(self, batch):
+        """A `train.compile` span where the batch's shapes are new to
+        the step (its first call with them builds the executable), else
+        a context that does nothing."""
+        shapes = tuple((x.shape, x.dtype) for x in batch)
+        if shapes in self._batch_shapes:
+            return compiles.BUILT
+        self._batch_shapes.add(shapes)
+        return compiles.span("train.compile", "zero_train_step",
+                             rows=int(batch[0].shape[0]))
 
     # -------------------------------------------------------- observability
     @staticmethod

@@ -70,6 +70,7 @@ import numpy as np
 from ..core.tensor import Tensor
 from ..jit.functional import call_functional, extract_state
 from ..observability import Histogram, LifecycleTracker, MetricsRegistry
+from ..observability import compiles
 from ..observability.flight_recorder import (
     build_postmortem as _build_bundle, dump_postmortem as _dump_bundle)
 from ..observability.slo import SloTracker
@@ -167,7 +168,7 @@ class ServingObs:
     (tests/test_serving.py pins that with a raise-on-touch guard)."""
 
     FAMILIES = ("prefill", "prefill_offset", "prefill_chunked", "decode",
-                "ragged", "spec", "sample")
+                "ragged", "spec")
 
     def __init__(self, registry: MetricsRegistry):
         self.registry = registry
@@ -583,6 +584,8 @@ class ServingEngine:
                  postmortem_dir: Optional[str] = None):
         from ..models.generation import _config_of
 
+        # set-up accounting: what JAX builds for this engine, by family
+        compiles.install()
         self.model = model
         model.eval()
         cfg = _config_of(model)
@@ -919,13 +922,9 @@ class ServingEngine:
             "_serving_jit_cache", {})
         # this engine's distinct per-family input avals == its jit cache
         # misses (the shared caches' _cache_size would count OTHER
-        # engines' shapes too); compile_counts() reports these. "sample"
-        # stays for compatibility: sampling is fused into prefill/decode,
-        # so it counts the (now extinct) standalone sampler dispatches
+        # engines' shapes too); compile_counts() reports these
         self._exec_shapes: Dict[str, set] = {
-            "prefill": set(), "prefill_offset": set(),
-            "prefill_chunked": set(), "decode": set(), "ragged": set(),
-            "spec": set(), "sample": set()}
+            fam: set() for fam in ServingObs.FAMILIES}
         # measure this sub-mesh's all-reduce latency ONCE at construction
         # (a few samples of the decode-step payload shape) — blocking on
         # a probe per step would measure device-queue time, not the
@@ -1326,15 +1325,21 @@ class ServingEngine:
         slots += [None] * ((rows or len(slots)) - len(slots))
         return {"slots": self.cache.slot_array(slots)}
 
-    def _note_exec(self, family: str, aval) -> None:
-        """Record one step family's input aval; a NEW aval is a jit-cache
+    def _note_exec(self, family: str, aval):
+        """Record one step family's input aval. A NEW aval is a jit-cache
         miss, counted into the registry's compile-miss counter (the set
-        stays the dedup structure, the registry holds the count)."""
+        stays the dedup structure, the registry holds the count), and
+        the one dispatch that builds an executable: hold what this
+        returns around it, a `serving.compile` span that charges the
+        build to `family` (`key`: the aval's first entry, the bucket or
+        the rows); for a known aval it is a context that does nothing."""
         shapes = self._exec_shapes[family]
-        if aval not in shapes:
-            shapes.add(aval)
-            if self._obs is not None:
-                self._obs.compile_miss[family].inc()
+        if aval in shapes:
+            return compiles.BUILT
+        shapes.add(aval)
+        if self._obs is not None:
+            self._obs.compile_miss[family].inc()
+        return compiles.span("serving.compile", family, key=aval[0])
 
     # -------------------------------------------------------------- prefill
     def _bucket_for(self, n: int) -> int:
@@ -1444,7 +1449,7 @@ class ServingEngine:
         suffix = req.prompt[n_cached:]
         bucket = self._bucket_for(len(suffix))
         family = "prefill_offset" if n_cached else "prefill"
-        self._note_exec(
+        building = self._note_exec(
             family, (bucket, self.cache.num_pages, self.max_pages_per_seq))
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :len(suffix)] = suffix
@@ -1488,7 +1493,8 @@ class ServingEngine:
                                   rid=req.request_id, tokens=len(suffix))
         with RecordEvent("serving.prefill", bucket=bucket,
                          prompt_tokens=len(suffix), rid=req.request_id):
-            token, err = self._guarded_call("dispatch", dispatch)
+            with building:
+                token, err = self._guarded_call("dispatch", dispatch)
         if token is None:
             # isolate THIS request; any pending decode block belongs to
             # other (already-prefilled) requests and keeps flying
@@ -1580,9 +1586,9 @@ class ServingEngine:
         rid = req.request_id
         chunk = self.prefill_chunk_tokens
         final = task.is_final
-        self._note_exec("prefill_chunked",
-                        (chunk, self.cache.num_pages,
-                         self.max_pages_per_seq))
+        building = self._note_exec("prefill_chunked",
+                                   (chunk, self.cache.num_pages,
+                                    self.max_pages_per_seq))
         ids = np.zeros((1, chunk), np.int32)
         ids[0, :n] = req.prompt[start:start + n]
         page_table = self.cache.page_table_array([req.pages],
@@ -1609,7 +1615,8 @@ class ServingEngine:
             self._recorder.record("dispatch", family="prefill_chunk",
                                   rid=rid, tokens=n, final=final)
         with RecordEvent("serving.prefill_chunk"):
-            token, err = self._guarded_call("dispatch", dispatch)
+            with building:
+                token, err = self._guarded_call("dispatch", dispatch)
         if token is None:
             # fault mid-chunk: quarantine ONLY this request — the cursor
             # never advanced, so finalize releases exactly its
@@ -1775,10 +1782,11 @@ class ServingEngine:
             page_size=self.page_size, max_pages=self.max_pages_per_seq)
         if batch is None:
             return events
-        self._note_exec("spec" if spec_on else "ragged",
-                        (batch.t_bucket, self.max_batch_size,
-                         self.decode_horizon, L, self.cache.num_pages,
-                         self.max_pages_per_seq))
+        building = self._note_exec("spec" if spec_on else "ragged",
+                                   (batch.t_bucket, self.max_batch_size,
+                                    self.decode_horizon, L,
+                                    self.cache.num_pages,
+                                    self.max_pages_per_seq))
         page_tables = self.cache.page_table_array(
             batch.page_lists, self.max_pages_per_seq)
         kds = [self._key_state[r.request_id] for r in batch.reqs]
@@ -1837,7 +1845,8 @@ class ServingEngine:
                                   decode=len(decode), chunks=len(chunks),
                                   t_bucket=batch.t_bucket)
         with RecordEvent("serving.ragged_step"):
-            out, err = self._guarded_call("dispatch", dispatch)
+            with building:
+                out, err = self._guarded_call("dispatch", dispatch)
         if out is None:
             # one dispatch carries every row, so a fault implicates the
             # whole step's requests — coarser than the chained path's
@@ -1983,7 +1992,7 @@ class ServingEngine:
             rids = tuple(r.request_id for r in reqs)
             prev = None
         b = self._decode_rows(len(reqs))
-        self._note_exec(
+        building = self._note_exec(
             "decode", (b, h, self.cache.num_pages, self.max_pages_per_seq))
         page_lists: List[Sequence[int]] = [()] * b
         for i, req in enumerate(reqs):
@@ -2061,7 +2070,8 @@ class ServingEngine:
                 [r.num_tokens + r.inflight for r in reqs], incr)
         with RecordEvent("serving.decode_block", rows=live,
                          rows_dispatched=b, horizon=h, **attrs):
-            out, err = self._guarded_call("dispatch", dispatch)
+            with building:
+                out, err = self._guarded_call("dispatch", dispatch)
         if out is None:
             # a decode dispatch implicates the whole batch. Drain the
             # previous block FIRST (its tokens are sound and its writes
@@ -2175,8 +2185,8 @@ class ServingEngine:
         cap_tokens = h * (1 + L)
         rids = tuple(r.request_id for r in reqs)
         b = self._decode_rows(len(reqs))
-        self._note_exec("spec", (b, h, L, self.cache.num_pages,
-                                 self.max_pages_per_seq))
+        building = self._note_exec("spec", (b, h, L, self.cache.num_pages,
+                                            self.max_pages_per_seq))
         page_lists: List[Sequence[int]] = [()] * b
         for i, req in enumerate(reqs):
             page_lists[i] = req.pages
@@ -2229,7 +2239,8 @@ class ServingEngine:
             self._recorder.record("dispatch", family="spec",
                                   rows=len(reqs), horizon=h, lookahead=L)
         with RecordEvent("serving.spec_block"):
-            out, err = self._guarded_call("dispatch", dispatch)
+            with building:
+                out, err = self._guarded_call("dispatch", dispatch)
         if out is None:
             self._quarantine(
                 [r for r in reqs if r.status == "running"], err, "spec")

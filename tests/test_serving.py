@@ -373,7 +373,7 @@ class TestPrefixCaching:
         on, off = eng_on.compile_counts(), eng_off.compile_counts()
         assert on["prefill_offset"] <= len({16, 32})
         assert on["prefill"] <= off["prefill"]
-        assert on["decode"] == 1 and on["sample"] <= 2
+        assert on["decode"] == 1
 
         # zero leaked pages once the cache lets go
         assert eng_on.prefix_cache.flush() == pcs["cached_pages"]
@@ -940,12 +940,11 @@ class TestContinuousBatching:
             assert outs[rid] == ref, f"request {rid} diverged"
 
         # bounded compilation: every prompt fits the 16-bucket -> ONE
-        # prefill executable, ONE decode executable, and the sampler
-        # compiles at most two shapes (prefill b=1, decode b=max_batch)
+        # prefill executable, ONE decode executable; the sampler is fused
+        # into both and builds none of its own
         counts = eng.compile_counts()
         assert counts["prefill"] == 1, counts
         assert counts["decode"] == 1, counts
-        assert counts["sample"] <= 2, counts
         assert counts["total"] <= 4, counts
 
         # metrics populated for every request
@@ -1047,7 +1046,12 @@ class TestServingSampling:
         eng.add_request([6], max_new_tokens=4, temperature=0.7,
                         top_p=0.8, seed=12)
         eng.run()
-        assert eng.compile_counts()["sample"] <= 2
+        greedy = ServingEngine(model, page_size=8, max_batch_size=4,
+                               max_seq_len=32, prefill_buckets=(16, 32))
+        for prompt in ([1, 2, 3], [4, 5], [6]):
+            greedy.add_request(prompt, max_new_tokens=4, temperature=0.0)
+        greedy.run()
+        assert eng.compile_counts() == greedy.compile_counts()
 
     def test_keys_are_host_rows_and_a_seeded_stream_ignores_its_batch(self):
         """A row's key comes back with its tokens (a prefill's, a
@@ -1221,7 +1225,7 @@ class TestSampleBatch:
         streams_before, counts_before = run()
         assert streams == streams_before
         assert counts == counts_before
-        assert counts["sample"] == 0 and counts["decode"] >= 1
+        assert counts["decode"] >= 1
 
 
 # ------------------------------------------------------- decode horizon
@@ -1266,7 +1270,8 @@ class TestDecodeHorizon:
             # max_batch 4), so staggered batch sizes share at most
             # log2(max_batch)+1 executables instead of one per size
             assert 1 <= counts["decode"] <= 3, counts
-            assert counts["sample"] == 0, counts   # sampling is fused
+            assert counts["total"] == counts["prefill"] + counts["decode"], \
+                counts                              # sampling is fused
             assert eng.cache.allocator.num_used == 0
         assert outs_by_h[1] == outs_by_h[4] == outs_by_h[8]
 
@@ -1372,7 +1377,6 @@ class TestDecodeHorizon:
             eng.run()
         counts = eng.compile_counts()
         assert counts["decode"] == 1, counts
-        assert counts["sample"] == 0, counts
 
     def test_seeded_sampling_device_keys_match_host_chain(self):
         """The fused sampler's device-side key evolution reproduces the
@@ -1460,10 +1464,10 @@ class TestServingObservability:
             fam: eng.metrics.get("serving_jit_compile_misses_total",
                                  {"family": fam}).value
             for fam in ("prefill", "prefill_offset", "prefill_chunked",
-                        "decode", "ragged", "spec", "sample")}
+                        "decode", "ragged", "spec")}
         assert counts["prefill"] == reg_counts["prefill"] == 1
         assert counts["decode"] == reg_counts["decode"] == 1
-        assert counts["sample"] == reg_counts["sample"] == 0
+        assert set(counts) == set(reg_counts) | {"total"}
         assert counts["prefill_chunked"] == \
             reg_counts["prefill_chunked"] == 0     # chunking off
         assert counts["ragged"] == reg_counts["ragged"] == 0

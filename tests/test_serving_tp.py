@@ -248,7 +248,7 @@ class TestCompileCounts:
         counts = eng.compile_counts()
         assert counts["prefill"] == 1, counts
         assert counts["decode"] == 1, counts
-        assert counts["sample"] == 0, counts
+        assert counts["total"] == 2, counts
 
     def test_tp1_jit_keys_unchanged_and_disjoint_from_tp(self):
         """tp_size=1 compiles THE SAME executables as before this PR:

@@ -71,6 +71,11 @@ SERVE_SUBSCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
                    DSA_INDEX, DSA_SELECT, DSA_ATTEND)
 TRAIN_SCOPES = (EMBED, ATTENTION, FFN, MLM_HEAD_LOSS, GRAD_REDUCE,
                 OPTIMIZER_UPDATE)
+# sub-scopes, nested inside a training scope: models/ernie.py. Under
+# `mlm_head_loss`, the head over every position of the batch, which runs
+# only where a batch has more labels than the gathered rows hold
+MLM_HEAD_ALL_ROWS = "mlm_head_all_rows"
+TRAIN_SUBSCOPES = (MLM_HEAD_ALL_ROWS,)
 
 # Pallas kernels of serving/attention.py: the trace names their events
 # `%paged_decode.N` / `%paged_ragged.N` whatever encloses the call

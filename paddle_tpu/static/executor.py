@@ -11,6 +11,7 @@ step: forward + jax.grad + functional optimizer update, params donated.
 """
 from __future__ import annotations
 
+import weakref
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -152,7 +153,7 @@ class Executor:
             opt_state = self._opt_state(program, param_arrays)
             fetches, new_params, new_opt_state = compiled(
                 feed_arrays, param_arrays, opt_state, lr, t)
-            self._opt_states[id(program)] = new_opt_state
+            self._opt_states[program] = new_opt_state
             for n in param_names:
                 program.refs[n]._data = new_params[n]
         else:
@@ -162,16 +163,17 @@ class Executor:
             return [np.asarray(f) for f in fetches]
         return [Tensor(f) for f in fetches]
 
-    _opt_states: Dict = {}
+    # by the program itself, not its id: a dead program's id is reused
+    _opt_states = weakref.WeakKeyDictionary()
 
     def _opt_state(self, program, param_arrays):
-        st = self._opt_states.get(id(program))
+        st = self._opt_states.get(program)
         if st is None:
             opt = program._minimize_hooks[0][0]
             names = self._trainable_names(program)
             st = opt.functional_state(
                 {n: param_arrays[n] for n in names})
-            self._opt_states[id(program)] = st
+            self._opt_states[program] = st
         return st
 
     @staticmethod

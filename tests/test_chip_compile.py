@@ -4,8 +4,9 @@ test — and the names the device trace is read by (kernels, scopes,
 executables), read from the compiler's own text of the programs the
 benchmark's cells run, at tiny widths; and on that same text, what
 those programs must not hold: a whole attention matrix, whole logits,
-a matmul operand wider than bf16. A compile that passes is a compile:
-nothing here runs on a chip.
+a matmul operand wider than bf16, the MLM head over every position
+outside its fallback. A compile that passes is a compile: nothing here
+runs on a chip.
 
 Only one process may hold libtpu, so the topology is described inside a
 fixture of this one file and never at import; under xdist only the
@@ -1186,25 +1187,32 @@ def _computations(text: str) -> tuple:
     return {name: body for _, name, body in found}, entry
 
 
-def _reached_outside_branches(text: str) -> dict:
-    """{name: body} of the computations the entry reaches (fusions, loop
-    bodies and conditions, reducers) without entering a branch of a
-    `conditional`: what the device runs whatever the predicates read."""
-    comps, entry = _computations(text)
+def _reached(comps: dict, start: str, enter=lambda line, callee: True):
+    """Names of the computations `start` reaches (fusions, loop bodies
+    and conditions, branches, reducers) through the calls of the lines
+    for which `enter(line, callee)` holds."""
     called = re.compile(r"\w+=\{?(%[\w.\-]+(?:, %[\w.\-]+)*)\}?")
-    seen, todo = set(), [entry]
+    seen, todo = set(), [start]
     while todo:
         name = todo.pop()
         if name in seen:
             continue
         seen.add(name)
         for line in comps[name].splitlines():
-            if " conditional(" in line:
-                continue
             for group in called.findall(line):
-                todo.extend(n.lstrip("%") for n in group.split(", ")
-                            if n.lstrip("%") in comps)
-    return {name: comps[name] for name in seen}
+                todo.extend(n for n in (g.lstrip("%") for g in
+                                        group.split(", "))
+                            if n in comps and enter(line, n))
+    return seen
+
+
+def _reached_outside_branches(text: str) -> dict:
+    """{name: body} of the computations the entry reaches without
+    entering a branch of a `conditional`: what the device runs whatever
+    the predicates read."""
+    comps, entry = _computations(text)
+    return {name: comps[name] for name in _reached(
+        comps, entry, lambda line, _: " conditional(" not in line)}
 
 
 def _sampler_sorts(body: str) -> list:
@@ -1229,3 +1237,63 @@ def test_sampler_sorts_only_inside_a_conditional_s_branch(programs, program):
     assert [(name, line[:120]) for name, body in always.items()
             for line in _sampler_sorts(body)] == []
     assert len(_sampler_sorts(text)) == 2
+
+
+# ------------- the MLM head runs over the labelled rows, not every row
+
+def _vocabulary_matmuls(body: str, shape_of: dict) -> list:
+    """The shapes (result first, then operands) of every `dot` and
+    `convolution` of a computation's lines with the vocabulary in one of
+    them."""
+    found = []
+    for result, operands in re.findall(
+            r"= (\w+\[[\d,]*\])\S* (?:convolution|dot)\(([^)]*)\)", body):
+        shapes = [result] + [shape_of[name] for name in
+                             re.findall(r"%([\w.\-]+)", operands)]
+        dims = [[int(d) for d in re.findall(r"\d+", s.split("[")[1])]
+                for s in shapes]
+        if any(_VOCAB in d for d in dims):
+            found.append(dims)
+    return found
+
+
+def test_the_vocabulary_head_runs_over_the_gathered_rows(train_hlo):
+    """Outside the branch that takes the head over every row (a batch
+    with more labels than the capacity: scope `mlm_head_all_rows`), each
+    matmul against the vocabulary, forward and backward, has at most the
+    capacity's 640 rows of the step's 4,096 positions; the fallback
+    keeps the fused loss's chunks of 2,048."""
+    from paddle_tpu.models.ernie import mlm_capacity
+
+    k = mlm_capacity(_TRAIN_ROWS * _SEQ)
+    comps, entry = _computations(train_hlo)
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])",
+                               train_hlo))
+
+    def fallback(name):
+        return any(_scoped(comps[n], scopes.MLM_HEAD_ALL_ROWS)
+                   for n in _reached(comps, name))
+
+    always = _reached(comps, entry, lambda line, callee: not (
+        " conditional(" in line and fallback(callee)))
+    gathered = [dims for name in always
+                for dims in _vocabulary_matmuls(comps[name], shape_of)]
+    every_row = [dims for name in set(comps) - always
+                 for dims in _vocabulary_matmuls(comps[name], shape_of)]
+    # the logits (and their recomputation in the backward), dx and dw
+    assert len(gathered) >= 3 and len(every_row) >= 3
+    # the scope names nothing the device runs in a batch within the
+    # capacity: its self time in the trace is the fallback's engagement
+    assert [name for name in always
+            if _scoped(comps[name], scopes.MLM_HEAD_ALL_ROWS)] == []
+    assert all(n <= k for dims in gathered for d in dims for n in d
+               if n != _VOCAB), gathered
+    assert all(2048 in sum(dims, []) for dims in every_row), every_row
+
+
+@pytest.mark.parametrize("scope", scopes.TRAIN_SUBSCOPES)
+def test_train_sub_scope_reaches_the_compiled_step(train_hlo, scope):
+    names = _scoped(train_hlo, scope)
+    # forward and backward both carry it
+    assert [n for n in names if "transpose(" not in n]
+    assert [n for n in names if "transpose(" in n]
